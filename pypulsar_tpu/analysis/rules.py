@@ -477,7 +477,7 @@ class RawHeaderReadRule(Rule):
     """``struct.unpack(fmt, f.read(n))`` trusts a short read: at EOF
     ``read`` returns ``b''`` and unpack raises a bare struct.error with
     no path/offset — the exact failure shape PR 8's DataFormatError
-    taxonomy (``io/errors.py``) exists to locate.  Use
+    error hierarchy (``io/errors.py``) exists to locate.  Use
     ``read_exact(f, n, path, what)``.  Same for ``.read(n).decode()``
     header chains."""
 
@@ -502,14 +502,14 @@ class RawHeaderReadRule(Rule):
                         ctx, node,
                         "struct.unpack over a raw .read(): a short read "
                         "at EOF raises an unlocated struct.error — use "
-                        "io.errors.read_exact (PR 8 taxonomy)")
+                        "io.errors.read_exact (PR 8 error hierarchy)")
             elif (isinstance(node.func, ast.Attribute)
                     and node.func.attr == "decode"
                     and self._is_read_call(node.func.value)):
                 yield self.finding(
                     ctx, node,
                     ".read(n).decode() header chain trusts a short "
-                    "read — use io.errors.read_exact (PR 8 taxonomy)")
+                    "read — use io.errors.read_exact (PR 8 error hierarchy)")
 
     @staticmethod
     def _is_read_call(node) -> bool:

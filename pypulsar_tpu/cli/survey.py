@@ -117,7 +117,15 @@ def build_parser():
                         "dead host's in-flight observations are adopted "
                         "by survivors). Each child gets "
                         "PYPULSAR_TPU_PROCESS_ID/NUM_PROCESSES and a "
-                        "hostN id. 0 (default): single-process")
+                        "hostN id. A chip belongs to ONE process, so the "
+                        "children run on the CPU backend unless "
+                        "JAX_PLATFORMS is already set (the launcher "
+                        "says which at start-up). On a machine with "
+                        "chips use one process with --devices N, which "
+                        "drives every chip of the host, or start one "
+                        "`survey --host-id NAME` per chip yourself, "
+                        "each confined to its own chip. 0 (default): "
+                        "single-process")
     g.add_argument("--host-id", default=None, metavar="NAME",
                    help="join the fleet under --outdir as ONE host named "
                         "NAME (what --hosts children do; set it yourself "
@@ -343,7 +351,15 @@ def _launch_hosts(args, argv) -> int:
     so a ``jax.distributed`` coordinator (real multi-machine TPU pods)
     threads through unchanged — on collective-less CPU backends the
     children simply never call initialize() and coordinate purely
-    through the plane files."""
+    through the plane files.
+
+    A chip belongs to one process at a time, and M children of one
+    machine cannot share its chips unless each is confined to its own.
+    The launcher does not partition chips: its children run on the CPU
+    backend unless the operator already set ``JAX_PLATFORMS`` (then
+    they inherit it and the operator owns the one-process-per-chip
+    split). It prints which, so the CPU choice is never silent; it
+    stays off JAX itself."""
     import subprocess
 
     child_argv = []
@@ -358,12 +374,22 @@ def _launch_hosts(args, argv) -> int:
         if a.startswith("--hosts="):
             continue
         child_argv.append(a)
+    platforms = os.environ.get("JAX_PLATFORMS")
+    if platforms:
+        print(f"# survey: --hosts {args.hosts}: children inherit "
+              f"JAX_PLATFORMS={platforms}; a chip belongs to one process "
+              f"— confining each child to its own chip is yours to do")
+    else:
+        platforms = "cpu"
+        print(f"# survey: --hosts {args.hosts}: children run on the CPU "
+              f"backend (JAX_PLATFORMS=cpu). For the chips of this "
+              f"machine use one process with --devices N")
     procs = []
     for rank in range(args.hosts):
         env = dict(os.environ)
         env["PYPULSAR_TPU_NUM_PROCESSES"] = str(args.hosts)
         env["PYPULSAR_TPU_PROCESS_ID"] = str(rank)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = platforms
         procs.append(subprocess.Popen(
             [sys.executable, "-m", "pypulsar_tpu.cli", "survey",
              *child_argv, "--host-id", f"host{rank}"], env=env))
@@ -520,14 +546,20 @@ def _run(args) -> int:
                   f"files only")
         plane = FleetPlane(args.outdir, host_id=host_id,
                            lease_s=args.host_lease)
-    sched = FleetScheduler(
-        obs, cfg, max_host_workers=args.max_host_workers,
-        devices=args.devices, retries=args.retries, resume=args.resume,
-        telemetry_dir=args.telemetry_dir, gang=gang,
-        stall_s=args.stall_timeout, stage_deadline=args.stage_deadline,
-        strike_limit=args.strike_limit, min_free_mb=args.min_free_mb,
-        max_pending=args.max_pending, max_bad_frac=args.max_bad_frac,
-        plane=plane, verbose=True)
+    try:
+        sched = FleetScheduler(
+            obs, cfg, max_host_workers=args.max_host_workers,
+            devices=args.devices, retries=args.retries,
+            resume=args.resume,
+            telemetry_dir=args.telemetry_dir, gang=gang,
+            stall_s=args.stall_timeout,
+            stage_deadline=args.stage_deadline,
+            strike_limit=args.strike_limit, min_free_mb=args.min_free_mb,
+            max_pending=args.max_pending, max_bad_frac=args.max_bad_frac,
+            plane=plane, verbose=True)
+    except ValueError as e:  # e.g. --devices beyond the real chip count
+        print(f"survey: {e}", file=sys.stderr)
+        return 2
     server = None
     status_port = args.status_port
     if status_port is None:

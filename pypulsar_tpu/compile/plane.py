@@ -5,11 +5,16 @@ Every ``jax.jit`` outside ``ops/`` leaf kernels dispatches through
 :func:`plane_jit` (psrlint PL018 enforces it). The wrapper layers
 three caches:
 
-1. **Persistent XLA cache** (``PYPULSAR_TPU_COMPILE_CACHE``, default
-   ``~/.cache/pypulsar_tpu/xla``): ``jax_compilation_cache_dir`` wired
-   fleet-wide, so a geometry compiled by ANY process on ANY host is a
-   disk hit everywhere else. Configured lazily, once per process, the
-   first time the plane compiles anything.
+1. **Persistent XLA cache**: placed from outside. Where
+   ``JAX_COMPILATION_CACHE_DIR`` is set, JAX itself keeps its cache
+   there and the plane sets no directory in code; where it is not, the
+   cache goes to one fixed, git-ignored directory inside the checkout
+   (:data:`DEFAULT_CACHE_DIR` — the path is part of XLA's cache key, so
+   it never carries a temp name, pid or time). A geometry compiled by
+   ANY process sharing that directory is a disk hit everywhere else.
+   Configured lazily, once per process, the first time the plane
+   compiles anything; ``PYPULSAR_TPU_COMPILE_CACHE=0`` is the off
+   switch.
 2. **In-process AOT executable registry**: per-wrapper executables
    from ``jit(f).lower(...).compile()`` keyed by (stage, static
    argument values, dynamic leaf shapes/dtypes, default device, jax
@@ -26,16 +31,19 @@ three caches:
 
 Anything the AOT path cannot key faithfully — tracer inputs (a
 plane-wrapped fn called under an outer trace), variadic signatures,
-multi-device arrays from a mesh context — falls back to the held
-plain ``jax.jit`` and counts ``compile.aot_fallback``; factory sites
-that close over meshes/shardings opt out wholesale with ``aot=False``
-(the plane still owns their telemetry). A bad cache dir or a failed
-AOT dispatch degrades the same way: the plane must never abort work
-that plain jit would have completed.
+multi-device arrays from a mesh context — or that refuses to lower, or
+whose arguments the compiled executable rejects before it runs, goes to
+the held plain ``jax.jit`` and counts ``compile.aot_fallback``; factory
+sites that close over meshes/shardings opt out wholesale with
+``aot=False`` (the plane still owns their telemetry). An exception
+while a compiled program EXECUTES (a device error, an OOM) is not a
+keying problem and propagates; a cache directory that cannot be set up
+raises.
 
 Cross-host accounting: the XLA disk cache is opaque, so on every
 in-process miss the plane probes a sidecar marker
-(``<cache>/plane/<digest>.json``, written atomically after each
+(``<cache>/plane/<digest>.json`` under whichever directory the cache
+lives in, written atomically after each
 compile, digest excludes process-local identity) and counts
 ``compile.persistent_hit`` when another process/host compiled that
 key first — the counter the multi-host test asserts on.
@@ -82,13 +90,40 @@ class _Unkeyable(Exception):
 _cache_lock = threading.Lock()
 _cache_state: Dict[str, Any] = {"configured": False, "dir": None}
 
+# the checkout root: <root>/pypulsar_tpu/compile/plane.py
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+_CACHE_OFF = ("0", "off", "none", "false")
+_CACHE_ON = ("1", "on", "true")
+
+
+def _resolve_cache_dir() -> str:
+    """Where the persistent cache lives, setting the fixed default in
+    JAX only when nothing outside placed it."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        # JAX read the variable when it was imported: follow it, set
+        # nothing
+        path = jax.config.jax_compilation_cache_dir
+        if not path:
+            raise RuntimeError(
+                "JAX_COMPILATION_CACHE_DIR is set but jax holds no cache "
+                "directory: the variable must be in the environment "
+                "before jax is imported")
+        return path
+    os.makedirs(DEFAULT_CACHE_DIR, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    return DEFAULT_CACHE_DIR
+
 
 def configure_persistent_cache() -> Optional[str]:
-    """Wire ``jax_compilation_cache_dir`` to the fleet-shared directory
-    (``PYPULSAR_TPU_COMPILE_CACHE``; ``0``/``off`` disables). Resolved
-    once per process — idempotent, thread-safe, returns the active
-    directory or None. Never raises: an uncreatable directory simply
-    disables persistence (plain in-memory jit still works)."""
+    """Set up the persistent XLA cache (see the module docstring for
+    where it lives; ``PYPULSAR_TPU_COMPILE_CACHE=0`` disables the
+    set-up). Resolved once per process — idempotent, thread-safe,
+    returns the active directory or None when switched off. A
+    directory that cannot be created or a misplaced variable raises:
+    "no cache" is never a silent outcome."""
     with _cache_lock:
         if _cache_state["configured"]:
             return _cache_state["dir"]
@@ -98,13 +133,15 @@ def configure_persistent_cache() -> Optional[str]:
     # latch above already guarantees a single configuring thread (a
     # concurrent caller may briefly observe dir=None, which only skips
     # the accounting sidecar for that one dispatch).
-    raw = knobs.env_str("PYPULSAR_TPU_COMPILE_CACHE")
-    if not raw or str(raw).strip().lower() in ("0", "off", "none"):
-        return None
-    path = os.path.abspath(os.path.expanduser(str(raw)))
     try:
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
+        raw = str(knobs.env_str("PYPULSAR_TPU_COMPILE_CACHE")).strip().lower()
+        if raw in _CACHE_OFF:
+            return None
+        if raw not in _CACHE_ON:
+            raise ValueError(
+                f"PYPULSAR_TPU_COMPILE_CACHE={raw!r}: the knob is an on/off "
+                f"switch; place the cache with JAX_COMPILATION_CACHE_DIR")
+        path = _resolve_cache_dir()
         # cache everything: the CPU-toy geometries tests exercise
         # compile in microseconds, and tiny executables are exactly
         # the ones a mixed-geometry fleet recompiles the most
@@ -114,8 +151,10 @@ def configure_persistent_cache() -> Optional[str]:
             "jax_persistent_cache_min_entry_size_bytes", -1)
         jax.config.update(
             "jax_persistent_cache_enable_xla_caches", "all")
-    except Exception:
-        return None
+    except BaseException:
+        with _cache_lock:
+            _cache_state["configured"] = False  # fail again, loudly
+        raise
     _cache_state["dir"] = path
     return path
 
@@ -184,10 +223,7 @@ def _default_device_str() -> str:
         return str(dd)
     d0 = _kind_cache.get("dev0")
     if d0 is None:
-        try:
-            d0 = str(jax.devices()[0])  # psrlint: ignore[PL002] -- registry-key metadata (jit's implicit placement target), not a compute placement
-        except Exception:
-            d0 = ""
+        d0 = str(jax.devices()[0])  # psrlint: ignore[PL002] -- registry-key metadata (jit's implicit placement target), not a compute placement
         _kind_cache["dev0"] = d0
     return d0
 
@@ -308,9 +344,12 @@ class PlaneJit:
             telemetry.counter("compile.cache_hit")
         try:
             return compiled(*dynamics)
-        except Exception:
-            # shape drift inside a pytree, donation mismatch, a
-            # backend refusing the AOT path — plain jit still works
+        except (TypeError, ValueError):
+            # the executable rejected its ARGUMENTS before running
+            # (shape drift inside a pytree, a sharding or donation
+            # mismatch the key missed): plain jit retraces. Anything
+            # raised while the program executes — a device error, an
+            # OOM — is a real failure and propagates.
             telemetry.counter("compile.aot_fallback")
             return self._jit(*args, **kwargs)
 
@@ -376,10 +415,7 @@ def _device_kind() -> str:
     import would initialize the backend before CLIs pick a platform)."""
     k = _kind_cache.get("kind")
     if k is None:
-        try:
-            k = jax.devices()[0].device_kind  # psrlint: ignore[PL002] -- cache-key metadata (hardware KIND, not a compute placement); no lease involved
-        except Exception:
-            k = "unknown"
+        k = jax.devices()[0].device_kind  # psrlint: ignore[PL002] -- cache-key metadata (hardware KIND, not a compute placement); no lease involved
         _kind_cache["kind"] = k
     return k
 

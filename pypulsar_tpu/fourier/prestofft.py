@@ -213,21 +213,21 @@ class PrestoFFT:
         hundreds = (self.freqs >= 100) & (self.freqs < 1000)
         plt.figure(figsize=(10, 8))
         plt.subplots_adjust(hspace=0.25)
-        axones = plt.subplot(3, 1, 1)
+        ax_ones = plt.subplot(3, 1, 1)
         plt.plot(self.freqs[ones], self.powers[ones], "k-", lw=0.5)
         plt.ylabel("Power")
         plt.xscale("log")
-        plt.subplot(3, 1, 2, sharey=axones)
+        plt.subplot(3, 1, 2, sharey=ax_ones)
         plt.plot(self.freqs[tens], self.powers[tens], "k-", lw=0.5)
         plt.ylabel("Power")
         plt.xscale("log")
-        plt.subplot(3, 1, 3, sharey=axones)
+        plt.subplot(3, 1, 3, sharey=ax_ones)
         plt.plot(self.freqs[hundreds], self.powers[hundreds], "k-", lw=0.5)
         plt.xlabel("Frequency (Hz)")
         plt.ylabel("Power")
         plt.xscale("log")
         maxpwr = np.max(self.powers[(self.freqs >= 1) & (self.freqs < 1000)])
-        axones.set_ylim(0, maxpwr * 1.1)
+        ax_ones.set_ylim(0, maxpwr * 1.1)
         plt.suptitle("Power Spectrum (%s)" % self.fftfn)
 
     def plot_zaplist(self, zapfile, fc="b", ec="none", alpha=0.25, zorder=-1,

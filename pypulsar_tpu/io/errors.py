@@ -1,4 +1,4 @@
-"""The data-format error taxonomy every reader raises.
+"""The data-format error hierarchy every reader raises.
 
 Real telescope recordings arrive truncated, bit-flipped and padded with
 garbage (dropped packets are the NORM for live transient surveys,

@@ -192,9 +192,8 @@ class FilterbankFile:
 
         ``raw`` yields blocks in the file's native dtype instead of
         float32: an 8-bit file then ships 1 byte/sample to the device,
-        where the f32 cast is exact and fused — through a remote-
-        accelerator link the host->device transfer is the streamed
-        sweep's bottleneck, so the 4x matters (BENCHNOTES.md round 4).
+        where the f32 cast is exact and fused — a quarter of the bytes
+        on the host->device link.
         Sub-byte files yield PACKED [time, nchans*nbits//8] uint8 blocks
         when ``raw`` (device-side unpack in parallel/staged._ingest_tc:
         a 4-bit file ships HALF the 8-bit bytes, VERDICT r4 item 2) and

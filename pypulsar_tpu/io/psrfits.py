@@ -145,7 +145,7 @@ class SpectraInfo:
             # and garbage as a zoo of exception types (ValueError,
             # KeyError, struct.error, even AttributeError from a
             # column-less table stub); the reader-fuzz contract is ONE
-            # located taxonomy — the original type survives in the
+            # located error hierarchy — the original type survives in the
             # detail and the chained __cause__
             raise DataFormatError(
                 filenames[0] if filenames else "<none>",
@@ -413,7 +413,7 @@ class PsrfitsFile:
             self._open(psrfitsfn)
         except DataFormatError:
             raise
-        except Exception as e:  # noqa: BLE001 - one taxonomy (see
+        except Exception as e:  # noqa: BLE001 - one error hierarchy (see
             # SpectraInfo.__init__)
             raise DataFormatError(
                 psrfitsfn,
@@ -522,7 +522,7 @@ class PsrfitsFile:
             return self._get_spectra(startsamp, N)
         except DataFormatError:
             raise
-        except Exception as e:  # noqa: BLE001 - one taxonomy (see
+        except Exception as e:  # noqa: BLE001 - one error hierarchy (see
             # SpectraInfo.__init__)
             raise DataFormatError(
                 self.filename,

@@ -13,13 +13,16 @@ explicit double-buffered DMA whose offsets come from scalar-prefetched
 shift tables, and accumulates in VMEM — the access pattern the hardware
 DMA engines are built for.
 
-``shifted_gather_sum`` currently defaults to the lax formulation
-everywhere: the Pallas path (``backend='pallas'``) is implemented and
-validated in interpret mode, but the AOT TPU compiler available in this
-environment crashes on any DMA/load with a *dynamic* offset (plain
-static-offset DMA kernels compile fine — see ops/pallas_kernels.py), so
-the kernel cannot yet be enabled by default.  Re-evaluate with
-``backend='pallas'`` on a toolchain where dynamic-offset DMA lowers.
+``shifted_gather_sum`` defaults to the lax formulation everywhere and the
+Pallas path is opt-in (``backend='pallas'``): it is validated in interpret
+mode only, and today's TPU compiler (jax 0.9.0 / libtpu 0.0.34, compiling
+for a described v5e) REFUSES it — "Slice shape along dimension 0 must be
+aligned to tiling (8), but is 1" at the row DMA in ``get_dma``, which
+copies ONE row of the [R, L] HBM array where the (8, 128) tiling wants
+eight. ``tests/test_chip_compile.py`` pins that verdict (strict xfail);
+the kernel is not on the survey path (the Fourier engine is the TPU
+default). Making the DMA tile-aligned (an 8-row slab per copy, or a
+[R, 1, L] layout) is the repair if the kernel is ever wanted.
 """
 
 from __future__ import annotations
@@ -99,7 +102,7 @@ def _pallas_gather_sum(data, rows, shifts, out_len: int,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(O, n_t),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((t_block,),
                                lambda o, j, *_, _nt=n_t: (o * _nt + j,),
                                memory_space=pltpu.VMEM),
@@ -140,8 +143,8 @@ def shifted_gather_sum(data, rows, shifts, out_len: int,
     rows = jnp.asarray(rows, jnp.int32)
     shifts = jnp.asarray(shifts, jnp.int32)
     if backend == "auto":
-        # dynamic-offset DMA does not lower in this environment's AOT
-        # TPU compiler (see module docstring); opt in explicitly
+        # the TPU compiler refuses the kernel's single-row DMA (see the
+        # module docstring); opt in explicitly
         backend = "lax"
     if backend == "pallas":
         return _pallas_gather_sum(data, rows, shifts, out_len)

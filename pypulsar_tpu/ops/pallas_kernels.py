@@ -12,8 +12,8 @@ through VMEM once: the cumulative sum is formed in VMEM scratch and every
 width's windowed difference, max, and argmax are reduced in-register —
 HBM traffic drops from (W+1) x D x T reads to a single one.
 
-Falls back transparently to the lax implementation off-TPU (and runs in
-interpret mode inside CPU tests).
+``backend='auto'`` picks the kernel on TPU and the lax formulation on any
+other platform (CPU tests run the kernel in interpret mode).
 """
 
 from __future__ import annotations
@@ -130,6 +130,10 @@ def _pallas_boxcar_stats(ts, widths: Tuple[int, ...], stat_len: int,
 
     kernel = partial(_boxcar_kernel, widths=tuple(int(w) for w in widths),
                      stat_len=stat_len, t_block=t_block)
+    # under shard_map (the DM-sharded sweep chunk) the outputs vary over
+    # the mesh axes exactly as the trial rows do; shard_map's check needs
+    # that said on the kernel's out_shape
+    vma = jax.typeof(ts).vma
     s, ss, mb, ab = pl.pallas_call(
         kernel,
         grid=(Dp // D_BLOCK, n_t),
@@ -153,10 +157,10 @@ def _pallas_boxcar_stats(ts, widths: Tuple[int, ...], stat_len: int,
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Dp, 1), ts.dtype),
-            jax.ShapeDtypeStruct((Dp, 1), ts.dtype),
-            jax.ShapeDtypeStruct((Dp, W), ts.dtype),
-            jax.ShapeDtypeStruct((Dp, W), jnp.int32),
+            jax.ShapeDtypeStruct((Dp, 1), ts.dtype, vma=vma),
+            jax.ShapeDtypeStruct((Dp, 1), ts.dtype, vma=vma),
+            jax.ShapeDtypeStruct((Dp, W), ts.dtype, vma=vma),
+            jax.ShapeDtypeStruct((Dp, W), jnp.int32, vma=vma),
         ],
         interpret=interpret,
     )(ts, ts)
@@ -180,16 +184,13 @@ def _lax_boxcar_stats(ts, widths: Tuple[int, ...], stat_len: int):
 
 
 def _on_tpu() -> bool:
-    try:
-        # lazy import: parallel.sweep imports this module at load time,
-        # so a module-level ops -> parallel.mesh import would cycle;
-        # resolving through the lease registry (PL002) keeps the
-        # backend probe honest under a gang lease
-        from pypulsar_tpu.parallel.mesh import lease_devices
+    # lazy import: parallel.sweep imports this module at load time, so a
+    # module-level ops -> parallel.mesh import would cycle; resolving
+    # through the lease registry (PL002) keeps the backend probe honest
+    # under a gang lease. A backend that cannot be asked raises.
+    from pypulsar_tpu.parallel.mesh import lease_devices
 
-        return lease_devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return lease_devices()[0].platform == "tpu"
 
 
 @partial(jax.jit, static_argnames=("widths", "stat_len", "backend"))

@@ -318,6 +318,12 @@ def _tree_rows_impl(data, tabs, pad: int):
         return jnp.concatenate([new, zero_row], axis=0), None
 
     if tabs.shape[1]:
+        # under shard_map the tables vary over the mesh axis while the
+        # zero-initialised state does not: the scan carry must enter with
+        # the varying axes it leaves with
+        vma = tuple(jax.typeof(tabs).vma)
+        if vma:
+            state = jax.lax.pcast(state, vma, to="varying")
         state, _ = jax.lax.scan(level, state, tabs.transpose(1, 0, 2))
     return state
 
@@ -451,8 +457,6 @@ def _sharded_tree_fn(mesh, out_len, widths, stat_len, group, pad,
     the unsharded engine per trial."""
     from jax.sharding import PartitionSpec as P
 
-    from pypulsar_tpu.parallel.sweep import shard_map_compat
-
     def impl(data, tabs, trial_row, trial_off):
         t = tabs[0].transpose(1, 0, 2)  # local [NL, 4, R] -> [4, NL, R]
         if series:
@@ -462,9 +466,9 @@ def _sharded_tree_fn(mesh, out_len, widths, stat_len, group, pad,
                                 widths, stat_len, group, pad)
 
     out = P("dm") if series else (P("dm"),) * 4
-    fn = shard_map_compat(impl, mesh=mesh,
-                          in_specs=(P(), P("dm"), P("dm"), P("dm")),
-                          out_specs=out)
+    fn = jax.shard_map(impl, mesh=mesh,
+                       in_specs=(P(), P("dm"), P("dm"), P("dm")),
+                       out_specs=out)
     return jax.jit(fn)
 
 

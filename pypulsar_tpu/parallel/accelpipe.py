@@ -1,7 +1,7 @@
 """Pipelined sweep->accel handoff: dedispersed series stream straight
 into the batched acceleration search, no .dat round trip.
 
-The round-5 configs[4] measurement (BENCH_r05.json) put 745.9 s of the
+The round-5 configs[4] measurement (BENCHNOTES.md) put 745.9 s of the
 4364.8 s chain into writing per-DM .dat files to disk only to re-read
 them for the accel stage, and the per-spectrum A/B showed 6.4 of
 8.7 s/spectrum of *serial host time* even with ``--device-prep`` — the

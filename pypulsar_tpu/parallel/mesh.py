@@ -112,11 +112,7 @@ def lease_devices(k: Optional[int] = None) -> list:
         devs = list(lease)
     else:
         devs = healthy_devices(jax.local_devices())
-        default = None
-        try:
-            default = jax.config.jax_default_device
-        except Exception:  # noqa: BLE001 - config name moved: no rotation
-            default = None
+        default = jax.config.jax_default_device
         if default is not None and default in devs:
             i = devs.index(default)
             devs = devs[i:] + devs[:i]

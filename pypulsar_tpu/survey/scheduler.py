@@ -236,6 +236,14 @@ class FleetScheduler:
         self.max_host_workers = max(1, int(max_host_workers))
         self.devices = max(1, int(devices))
         self._njax: object = _UNSET
+        if self.devices > 1:
+            n = self._n_jax_devices()
+            if n is not None and self.devices > n:
+                # a lease IS a chip: more leases than chips would wrap
+                # onto chip 0 and report k-chip work that ran on one
+                raise ValueError(
+                    f"--devices {self.devices} exceeds the {n} local "
+                    f"JAX device(s): a device lease is one real chip")
         self.retries = max(0, int(retries))
         self.resume = resume
         self.telemetry_dir = telemetry_dir
@@ -1014,18 +1022,11 @@ class FleetScheduler:
         except Exception:  # noqa: BLE001 - no jax backend: local account
             return health_mod.DeviceHealth(strike_limit)
 
-    def _lease_real(self, i: int) -> int:
-        """The REAL jax device id lease ``i`` pins by default (leases
-        wrap modulo the chip count on an oversubscribed pool). Strikes
-        are charged against real chips — the account `parallel.mesh`
-        reads — so health checks must translate lease ids the same
-        way."""
-        n = self._n_jax_devices()
-        return i % n if n else i
-
     def _healthy_ids(self) -> List[int]:
+        # lease i is local device i (the pool never exceeds the chip
+        # count), so lease ids index the real-chip strike account
         return [i for i in range(self.devices)
-                if not self._health.is_quarantined(self._lease_real(i))]
+                if not self._health.is_quarantined(i)]
 
     def _deadline_for(self, stage: StageSpec, obs: Observation):
         if self.stage_deadline is not None:
@@ -1112,17 +1113,15 @@ class FleetScheduler:
             return
         kind = "oom" if oom else "device"
         # charge the REAL chips the execution was pinned to (the id
-        # space `parallel.mesh` filters by); on an oversubscribed pool
-        # a quarantined chip takes EVERY lease that maps to it
-        reals = task.last_real_dev_ids \
-            or [self._lease_real(i) for i in ids]
+        # space `parallel.mesh` filters by)
+        reals = task.last_real_dev_ids or list(ids)
         evicted: List[int] = []
         for r in reals:
             allow = len(self._healthy_ids()) > 1
             if self._health.strike(r, kind=kind, error=str(err)[:200],
                                    allow_quarantine=allow):
-                evicted.extend(i for i in range(self.devices)
-                               if self._lease_real(i) == r)
+                if r < self.devices:
+                    evicted.append(r)
         if evicted:
             with self._cv:
                 self._free_ids.difference_update(evicted)
@@ -1464,12 +1463,6 @@ class FleetScheduler:
         measured per-stage cost share (see GANG_COST_MIN_FRAC)."""
         stage = task.stage
         gmax = min(int(getattr(stage, "devices_max", 1)), self.devices)
-        njax = self._n_jax_devices()
-        if njax is not None:
-            # a gang mesh needs k DISTINCT chips; an oversubscribed
-            # lease pool (--devices > real devices) may only widen up
-            # to the real count
-            gmax = min(gmax, njax)
         # a quarantined chip is out of the pool: gangs SHRINK to the
         # surviving leases (placement is not science — artifacts stay
         # byte-identical at the new width)
@@ -1552,7 +1545,7 @@ class FleetScheduler:
             # returns to the pool
             self._free_ids.update(
                 i for i in ids
-                if not self._health.is_quarantined(self._lease_real(i)))
+                if not self._health.is_quarantined(i))
             self._cv.notify_all()
 
     def _n_jax_devices(self) -> Optional[int]:
@@ -1574,11 +1567,9 @@ class FleetScheduler:
         leases really are k chips, not k-fold oversubscription of
         device 0. Guarded: a jax-less run (stub DAGs) skips binding.
 
-        Lease ids wrap modulo the real device count (an oversubscribed
-        pool is legal for 1-chip fleet placement), but a GANG mesh must
-        hold distinct chips — colliding ids are bumped to the next free
-        device; ``_gang_size`` caps k at the real count so a solution
-        always exists."""
+        Lease ``i`` is local device ``i``: the constructor refuses a
+        pool larger than the real device count, so distinct leases are
+        distinct chips and a gang never doubles up on one."""
         if self.devices <= 1:
             return None
         try:
@@ -1587,22 +1578,7 @@ class FleetScheduler:
             devs = jax.local_devices()
         except Exception:  # noqa: BLE001 - no backend: nothing to pin
             return None
-        n = len(devs)
-        if len(ids) > 1:
-            if len(ids) > n:
-                raise ValueError(
-                    f"gang of {len(ids)} leases needs {len(ids)} distinct "
-                    f"devices but only {n} exist")
-            picked: List[int] = []
-            used: set = set()
-            for i in ids:
-                j = i % n
-                while j in used:
-                    j = (j + 1) % n
-                used.add(j)
-                picked.append(j)
-            return [devs[j] for j in picked]
-        return [devs[i % n] for i in ids]
+        return [devs[i] for i in ids]
 
     def _run_device_task(self, task: _Task) -> None:
         """One device-lane execution: decide the gang shape, take the

@@ -516,10 +516,6 @@ env_knob("PYPULSAR_TPU_NO_NATIVE", "str", None, "engine",
          invariant=False,
          help="any value disables the native compiled helpers")
 
-# -- bench ------------------------------------------------------------------
-env_knob("PYPULSAR_TPU_HBM_GB", "float", 16.0, "bench",
-         help="advertised per-chip HBM (GB) bench.py sizes payloads for")
-
 # -- multi-host -------------------------------------------------------------
 env_knob("PYPULSAR_TPU_COORDINATOR", "str", None, "multihost",
          invariant=False,
@@ -577,11 +573,11 @@ env_knob("PYPULSAR_TPU_OBS_SLO_FRAC", "float", 0.8, "obs",
               "event")
 
 # -- compilation plane (round 22) -------------------------------------------
-env_knob("PYPULSAR_TPU_COMPILE_CACHE", "str",
-         "~/.cache/pypulsar_tpu/xla", "compile",
+env_knob("PYPULSAR_TPU_COMPILE_CACHE", "str", "1", "compile",
          invariant=False,
-         help="fleet-shared persistent XLA compilation cache directory "
-              "(jax_compilation_cache_dir); 0/off disables persistence")
+         help="0/off disables the plane's persistent XLA cache set-up. "
+              "The cache lives where JAX_COMPILATION_CACHE_DIR says, "
+              "else at <checkout>/.jax_cache")
 env_knob("PYPULSAR_TPU_COMPILE_AOT", "str", "1", "compile",
          invariant=False,
          help="0 disables the plane's in-process AOT executable "

@@ -447,11 +447,10 @@ def require_virtual_mesh(k):
             from jax.sharding import PartitionSpec as P
 
             from pypulsar_tpu.parallel import make_mesh
-            from pypulsar_tpu.parallel.sweep import shard_map_compat
 
             mesh = make_mesh([2], ("dm",), devices=jax.devices()[:2])
-            fn = shard_map_compat(lambda x: x * 2, mesh=mesh,
-                                  in_specs=(P("dm"),), out_specs=P("dm"))
+            fn = jax.shard_map(lambda x: x * 2, mesh=mesh,
+                               in_specs=(P("dm"),), out_specs=P("dm"))
             np.testing.assert_array_equal(
                 np.asarray(fn(jnp.arange(4.0))), np.arange(4.0) * 2)
             _MESH_PROBE.append((True, ""))

@@ -320,14 +320,18 @@ with telemetry.session() as tlm:
 
 
 def test_persistent_cache_shared_across_processes(tmp_path):
-    """Two processes pointed at one PYPULSAR_TPU_COMPILE_CACHE: the
-    second one's compile is a cross-host persistent hit."""
+    """Two processes given one JAX_COMPILATION_CACHE_DIR: the second
+    one's compile is a cross-host persistent hit, and both the XLA
+    cache entries and the plane's markers live under that directory
+    and nowhere else."""
     _require_spawn()
+    cache = tmp_path / "xla"
     env = dict(os.environ)
     env["PYTHONPATH"] = (_REPO + os.pathsep
                          + env.get("PYTHONPATH", "")).rstrip(os.pathsep)
     env["JAX_PLATFORMS"] = "cpu"
-    env["PYPULSAR_TPU_COMPILE_CACHE"] = str(tmp_path / "xla")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(cache)
+    env.pop("PYPULSAR_TPU_COMPILE_CACHE", None)
 
     def run():
         proc = subprocess.run([sys.executable, "-c", _CHILD], env=env,
@@ -340,8 +344,114 @@ def test_persistent_cache_shared_across_processes(tmp_path):
     t1 = run()
     assert t1.get("compile.cache_miss", 0) == 1
     assert t1.get("compile.persistent_hit", 0) == 0
+    markers = sorted(os.listdir(cache / "plane"))
+    assert markers and all(m.endswith(".json") for m in markers)
+    assert [n for n in os.listdir(cache) if n != "plane"], \
+        "no XLA cache entry under JAX_COMPILATION_CACHE_DIR"
     t2 = run()
     # fresh process: the in-process registry is cold (one miss), but the
     # executable comes off the shared persistent cache
     assert t2.get("compile.cache_miss", 0) == 1
     assert t2.get("compile.persistent_hit", 0) >= 1
+
+
+_PLACEMENT_CHILD = """
+import json
+import jax
+from pypulsar_tpu.compile import plane
+
+seen = []
+_update = jax.config.update
+jax.config.update = lambda k, v: (seen.append(k), _update(k, v))[1]
+try:
+    got = plane.configure_persistent_cache()
+    err = None
+except Exception as e:
+    got, err = None, f"{type(e).__name__}: {e}"
+print("PLACED " + json.dumps({
+    "dir": got, "err": err, "default": plane.DEFAULT_CACHE_DIR,
+    "jax_dir": jax.config.jax_compilation_cache_dir,
+    "set_dir_in_code": "jax_compilation_cache_dir" in seen}))
+"""
+
+
+def _placement(env_overrides):
+    _require_spawn()
+    env = dict(os.environ)
+    env["PYTHONPATH"] = (_REPO + os.pathsep
+                         + env.get("PYTHONPATH", "")).rstrip(os.pathsep)
+    env["JAX_PLATFORMS"] = "cpu"
+    for k in ("JAX_COMPILATION_CACHE_DIR", "PYPULSAR_TPU_COMPILE_CACHE"):
+        env.pop(k, None)
+    env.update(env_overrides)
+    proc = subprocess.run([sys.executable, "-c", _PLACEMENT_CHILD],
+                          env=env, capture_output=True, text=True,
+                          timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    line = [ln for ln in proc.stdout.splitlines()
+            if ln.startswith("PLACED ")][-1]
+    return json.loads(line[len("PLACED "):])
+
+
+def test_cache_dir_from_environment_is_never_set_in_code(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: the plane follows the directory
+    JAX read from it and calls jax.config.update for it nowhere."""
+    got = _placement({"JAX_COMPILATION_CACHE_DIR": str(tmp_path / "c")})
+    assert got["err"] is None
+    assert got["dir"] == got["jax_dir"] == str(tmp_path / "c")
+    assert not got["set_dir_in_code"]
+
+
+def test_cache_dir_default_is_one_fixed_path_in_the_checkout():
+    """Variable unset: one fixed path under the checkout root — the
+    path is part of XLA's cache key, so no temp name, pid or time."""
+    got = _placement({})
+    assert got["err"] is None
+    assert got["default"] == os.path.join(_REPO, ".jax_cache")
+    assert got["dir"] == got["jax_dir"] == got["default"]
+    assert got["set_dir_in_code"]
+
+
+@pytest.mark.parametrize("value,want_err", [
+    ("0", None), ("off", None), ("/some/old/cache/path", "ValueError")])
+def test_cache_knob_keeps_only_its_off_switch(tmp_path, value, want_err):
+    """The knob no longer takes a directory: off values disable the
+    set-up, anything else that is not 'on' is an error, not a silent
+    'no cache'."""
+    got = _placement({"JAX_COMPILATION_CACHE_DIR": str(tmp_path / "c"),
+                      "PYPULSAR_TPU_COMPILE_CACHE": value})
+    assert got["dir"] is None and not got["set_dir_in_code"]
+    if want_err is None:
+        assert got["err"] is None
+    else:
+        assert got["err"].startswith(want_err)
+
+
+def test_aot_execution_error_propagates_argument_mismatch_retraces():
+    """A compiled executable that rejects its arguments (TypeError /
+    ValueError, raised before it runs) goes to plain jit, counted; an
+    exception while it EXECUTES propagates instead of being retried."""
+
+    @plane_jit
+    def f(x):
+        return x + 1.0
+
+    x = jnp.ones((4,), jnp.float32)
+    with telemetry.session() as tlm:
+        f(x)
+        (key,) = list(f._compiled)
+
+        def rejects(*a):
+            raise TypeError("argument mismatch")
+
+        f._compiled[key] = rejects
+        np.testing.assert_array_equal(np.asarray(f(x)), 2.0)
+        assert tlm.counter_totals().get("compile.aot_fallback", 0) == 1
+
+        def device_error(*a):
+            raise RuntimeError("INTERNAL: device halted")
+
+        f._compiled[key] = device_error
+        with pytest.raises(RuntimeError, match="device halted"):
+            f(x)
+        assert tlm.counter_totals().get("compile.aot_fallback", 0) == 1

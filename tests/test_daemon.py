@@ -489,7 +489,7 @@ def test_daemon_soak_harness():
     bench = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(bench)
 
-    args = bench.parse_args(["--daemon-soak", "--quick", "--child"])
+    args = bench.parse_args(["--daemon-soak", "--quick"])
     record = bench.run_daemon_soak(args)
     assert record["value"] == 1.0
     assert record["soak_kill9_reruns"] == 0

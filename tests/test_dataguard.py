@@ -56,7 +56,7 @@ def test_read_exact_short_read_is_located():
 
 def test_dataformaterror_is_valueerror():
     """Existing broad ``except ValueError`` reader handlers keep
-    classifying the new taxonomy."""
+    classifying the new error hierarchy."""
     assert issubclass(DataFormatError, ValueError)
 
 

@@ -305,8 +305,7 @@ def test_race_harness_long_seed_twin():
     through the full bench.py --race harness (in-process)."""
     import bench
 
-    args = bench.parse_args(["--race", "--quick", "--race-seeds", "3",
-                             "--child"])
+    args = bench.parse_args(["--race", "--quick", "--race-seeds", "3"])
     rec = bench.run_race(args)
     assert rec["value"] == 1.0
     assert all(p["order_violations"] == 0 for p in rec["race_per_seed"])

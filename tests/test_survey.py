@@ -669,27 +669,27 @@ def test_gang_auto_cost_gate():
     assert k == 4 and "99%" in reason
 
 
-def test_gang_oversubscribed_pool_distinct_devices(tmp_path):
-    """An oversubscribed lease pool (--devices > real chips) is legal
-    for 1-chip fleet placement, but a gang mesh must hold DISTINCT
-    chips: colliding lease ids (e.g. 0 and 0+n) are bumped to free
-    devices, and auto-gang width is capped at the real device count."""
+def test_lease_pool_larger_than_real_devices_is_refused(tmp_path):
+    """A device lease is one real chip: a pool wider than the real
+    device count would wrap onto chip 0 and report k-chip work that ran
+    on one, so the scheduler refuses it, the CLI exits 2, and lease i
+    binds local device i."""
     _require_virtual_mesh(2)
     import jax
+
+    from pypulsar_tpu.cli import survey as cli
+
     n = len(jax.local_devices())
-    sched = FleetScheduler(
-        [Observation("a", "a.raw", str(tmp_path / "a"))],
-        SurveyConfig(), stages=[_gang_stub("s", devices_max=4 * n)],
-        devices=4 * n, gang="auto")
-    # lease ids that wrap modulo n and collide: [0, n] both map to dev 0
-    gang = sched._jax_gang([0, n])
-    assert len(set(gang)) == 2
-    # a full-width gang over the whole oversubscribed pool is impossible
-    with pytest.raises(ValueError, match="distinct devices"):
-        sched._jax_gang(list(range(n + 1)))
-    # ...and the placement policy never asks for one: k caps at n
-    k, _reason = sched._gang_size(sched._tasks[(0, "s")])
-    assert k <= n
+    obs = [Observation("a", "a.raw", str(tmp_path / "a"))]
+    with pytest.raises(ValueError, match="exceeds the .* local JAX"):
+        FleetScheduler(obs, SurveyConfig(), stages=_stub_stages(),
+                       devices=n + 1)
+    assert cli.main(["a.fil", "-o", str(tmp_path / "out"),
+                     "--devices", str(n + 1)]) == 2
+    sched = FleetScheduler(obs, SurveyConfig(), stages=_stub_stages(),
+                           devices=n, gang="auto")
+    assert sched._jax_gang([0, n - 1]) == [jax.local_devices()[0],
+                                           jax.local_devices()[n - 1]]
 
 
 def test_gang_acquisition_fifo_no_starvation(tmp_path):

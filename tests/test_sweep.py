@@ -599,14 +599,7 @@ def test_sweep_resident_sharded_matches():
 def test_bench_budget_shapes():
     """bench.py's HBM budgeting: fits in the budget, power-of-two FFT
     lengths, sane pending depth (VERDICT r2 item 1)."""
-    import importlib.util
-    import os as _os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench", _os.path.join(_os.path.dirname(_os.path.dirname(
-            _os.path.abspath(__file__))), "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
+    bench = _load_bench()
 
     C = 1024
     freqs = (1500.0 - 300.0 / C * np.arange(C)).astype(np.float64)
@@ -627,6 +620,50 @@ def test_bench_budget_shapes():
     b1 = bench.sweep_bytes(plan, C, T, payload, n, "fourier")
     b2 = bench.sweep_bytes(plan, C, 2 * T, payload, n, "fourier")
     assert 0 < b1 < b2
+
+
+def _load_bench():
+    import importlib.util
+    import os as _os
+
+    spec = importlib.util.spec_from_file_location(
+        "bench", _os.path.join(_os.path.dirname(_os.path.dirname(
+            _os.path.abspath(__file__))), "bench.py"))
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    return bench
+
+
+@pytest.mark.parametrize("mode", [
+    [], ["--quick"], ["--ab"], ["--accel"], ["--fold"], ["--waterfall"],
+    ["--prepass"], ["--survey"], ["--tune"], ["--obs-overhead"]])
+def test_bench_device_metric_modes_fail_without_a_tpu(mode, capsys):
+    """A mode whose value is a time, a rate or a wall ratio has no value
+    without the device: on the CPU bench.main() exits non-zero and prints
+    `"ok": false` with the device's name — never a record under the
+    metric's name, never a CPU re-run."""
+    import json as _json
+
+    bench = _load_bench()
+    assert bench.main(mode) != 0
+    rec = _json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rec["ok"] is False and "metric" not in rec
+    assert rec["platform"] == "cpu" and rec["device_count"] >= 1
+    assert "device_kind" in rec
+
+
+def test_bench_peaks_table_refuses_unknown_device_kind():
+    bench = _load_bench()
+    assert bench.device_peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
+    with pytest.raises(KeyError, match="no published peaks"):
+        bench.device_peaks("cpu")
+
+    class NoStats:
+        def memory_stats(self):
+            return None
+
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        bench.device_hbm_bytes(NoStats())
 
 
 def test_multi_event_chunk_peaks():
