@@ -86,17 +86,19 @@ def main(argv=None):
     from pypulsar_tpu.obs import telemetry
     from pypulsar_tpu.ops.rfifind import rfifind
 
-    reader = open_data_file(args.infile)
-    try:
-        with telemetry.session_from_flag(args.telemetry, tool="rfifind"):
+    with telemetry.session_from_flag(args.telemetry, tool="rfifind"), \
+            telemetry.span("cli.rfifind", aggregate=False):
+        with telemetry.span("io.open"):  # format sniff + header
+            reader = open_data_file(args.infile)
+        try:
             stats, flags, maskfn = rfifind(
                 reader, time=args.time, time_sigma=args.timesig,
                 freq_sigma=args.freqsig, chanfrac=args.chanfrac,
                 intfrac=args.intfrac, zap_chans=args.zapchan,
                 zap_ints=args.zapints, outbase=args.outbase,
             )
-    finally:
-        reader.close()
+        finally:
+            reader.close()
     print(f"wrote {maskfn}: {stats.nint} intervals x {stats.nchan} "
           f"channels, {float(flags.mean()) * 100:.2f}% of blocks flagged, "
           f"mask covers {stats.mask_coverage * 100:.2f}% of the data")

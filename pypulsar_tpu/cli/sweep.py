@@ -23,6 +23,7 @@ import os
 
 import numpy as np
 
+from pypulsar_tpu.obs import telemetry
 from pypulsar_tpu.tune import knobs
 
 
@@ -665,7 +666,6 @@ def main(argv=None):
     ap.add_argument("--process-id", type=int, default=None,
                     help="multi-host mode: this host's rank "
                          "($PYPULSAR_TPU_PROCESS_ID)")
-    from pypulsar_tpu.obs import telemetry
     from pypulsar_tpu.resilience import faultinject
 
     telemetry.add_telemetry_flag(
@@ -678,7 +678,8 @@ def main(argv=None):
     faultinject.configure_from_env()
     if args.fault_inject:
         faultinject.configure(args.fault_inject)
-    with telemetry.session_from_flag(args.telemetry, tool="sweep"):
+    with telemetry.session_from_flag(args.telemetry, tool="sweep"), \
+            telemetry.span("cli.sweep", aggregate=False):
         return _main_parsed(args, ap)
 
 
@@ -745,9 +746,14 @@ def _main_parsed(args, ap):
     outbase = args.outbase or os.path.splitext(args.infile)[0]
     if args.checkpoint and not args.resume:
         _remove_stale_checkpoints(args.checkpoint)
-    reader = _open_reader(args.infile)
-    rfimask = _load_mask(args)
-    _apply_tuning(args, reader)
+    # batch head: reader open (format sniff + header), then mask load and
+    # tuned config (the block source and the sweep plan itself are built
+    # per step: staged.sweep_flat / _run_step, same span name)
+    with telemetry.span("io.open"):
+        reader = _open_reader(args.infile)
+    with telemetry.span("sweep.plan"):
+        rfimask = _load_mask(args)
+        _apply_tuning(args, reader)
     mesh = None
     if args.mesh:
         # build the mesh from the LEASED device set, never bare
@@ -787,7 +793,10 @@ def _main_parsed(args, ap):
                 _journal_fingerprint(args, dms, widths, outbase),
                 tool="sweep-accel")
             journal_done = journal.completed()
-        _remove_stale_output_tmps(outbase, dms, args)
+        with telemetry.span("sweep.plan", n_trials=len(dms)):
+            # four os.path.exists a trial: 0.65 s a batch at 1024 trials
+            # on the benchmark's host (PERF.md, PR 24)
+            _remove_stale_output_tmps(outbase, dms, args)
         staged = None
         if not args.accel_only:
             if journal is not None and "sweep:cands" in journal_done:
@@ -913,14 +922,18 @@ def _emit_sweep_artifacts(staged, outbase, args, journal):
     """Write the single-pulse artifacts (.cands + optional .events/
     .pulses), record them in the run journal, and print the summary —
     one definition for the flat and DDplan paths."""
-    hits = staged.above_threshold(args.threshold)
-    _write_cands(outbase + ".cands", hits)
-    outputs = [outbase + ".cands"]
-    if args.all_events:
-        _emit_events(staged, outbase, args)
-        outputs += [outbase + ".events", outbase + ".pulses"]
-    if journal is not None:
-        journal.done("sweep:cands", outputs)
+    with telemetry.span("sweep.finalize") as sp:  # event extraction
+        hits = staged.above_threshold(args.threshold)
+        if sp is not None:
+            sp.set(rows=len(hits))
+    with telemetry.span("sweep.write", rows=len(hits)):
+        _write_cands(outbase + ".cands", hits)
+        outputs = [outbase + ".cands"]
+        if args.all_events:
+            _emit_events(staged, outbase, args)
+            outputs += [outbase + ".events", outbase + ".pulses"]
+        if journal is not None:
+            journal.done("sweep:cands", outputs)
     print(f"# {staged.n_trials} DM trials swept; {len(hits)} detections "
           f">= {args.threshold} sigma -> {outbase}.cands")
     for c in staged.best(args.topk):

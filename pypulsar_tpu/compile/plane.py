@@ -51,6 +51,7 @@ key first — the counter the multi-host test asserts on.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import inspect
 import itertools
@@ -151,6 +152,16 @@ def configure_persistent_cache() -> Optional[str]:
             "jax_persistent_cache_min_entry_size_bytes", -1)
         jax.config.update(
             "jax_persistent_cache_enable_xla_caches", "all")
+        # names are part of the program: JAX's default key ignores an
+        # operation's metadata, so a cache written before a program was
+        # named (or renamed) keeps serving the executable with the OLD
+        # module scopes, and a profile of today's code prints
+        # yesterday's names (measured, PR 24: jit__masked_block came
+        # back from PR 23's cache without its scopes). Keyed on the
+        # metadata, a trace names the code that ran; the price is one
+        # recompile when a jitted function's source lines move.
+        jax.config.update(
+            "jax_compilation_cache_include_metadata_in_key", True)
     except BaseException:
         with _cache_lock:
             _cache_state["configured"] = False  # fail again, loudly
@@ -284,8 +295,21 @@ class PlaneJit:
         self._static = tuple(static_argnames)
         self._stage = stage
         self.__name__ = name or getattr(fn, "__name__", "fn")
-        self._jit = (jax.jit(fn, static_argnames=self._static)
-                     if self._static else jax.jit(fn))
+        # what the profiler prints is the jitted function's __name__
+        # (the "XLA Modules" line reads jit_<name>) and the name scopes
+        # of its operations: jit a twin that carries the wrapper's name
+        # and runs the body under "<stage>.<name>". functools.wraps keeps
+        # the signature static_argnames and _split bind against.
+        scope = f"{stage}.{self.__name__}" if stage else self.__name__
+
+        @functools.wraps(fn)
+        def named(*args, **kwargs):
+            with jax.named_scope(scope):
+                return fn(*args, **kwargs)
+
+        named.__name__ = named.__qualname__ = self.__name__
+        self._jit = (jax.jit(named, static_argnames=self._static)
+                     if self._static else jax.jit(named))
         self._uid = next(_WRAPPER_IDS)
         self._compiled: Dict[Tuple, Any] = {}
         self._lock = threading.Lock()

@@ -73,6 +73,7 @@ def fold_bins(data, bin_idx, nbins: int):
 _FOLD_BLOCK = 1 << 17  # bounds the live one-hot to ~64 MB at 128 bins
 
 
+@jax.named_scope("fold.matmul")
 def _onehot_fold_2d(data, bin_idx, nbins: int):
     """``data[C, T] @ one_hot(bin_idx)`` accumulated over time blocks so
     the selection matrix never exceeds _FOLD_BLOCK x nbins (a monolithic
@@ -318,6 +319,7 @@ def fold_snr_stats(data, bin_idx, nbins: int, npart: int, dt: float,
 # batched candidate folding (the fold-pipeline kernels)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("fold.matmul")
 def _onehot_fold_1d_batch(data, bin_idx, nbins: int):
     """``[K]``-candidate fold of ONE shared 1-D block: each candidate k
     scatters the same ``data[T]`` into its own bins via
@@ -405,6 +407,7 @@ def fold_parts_batch(series, bin_idx, nbins: int, npart: int):
         return _fold_parts_batch_jit(series, bin_idx, nbins, npart)
 
 
+@jax.named_scope("fold.matmul")
 def _onehot_fold_1d_multi(data, bin_idx, nbins: int):
     """Multi-series twin of :func:`_onehot_fold_1d_batch`: candidate k
     folds its OWN ``data[k]`` row (``einsum('kt,ktb->kb')``) instead of

@@ -312,20 +312,23 @@ def _make_stage_runner(segw: int, Z: int, Wn: int, topk: int,
             width = jnp.minimum(segw, top_hi - r0)
             plane = jnp.zeros((Z * Wn, 2 * segw), jnp.float32)
             for (off0, step, hw, L), tf2, idx in zip(bank_meta, tfs, idxs):
-                tf = join_planes(tf2[0], tf2[1])
-                start = off0 + si * step
-                sl = jax.lax.dynamic_slice(spec_pad, (start,), (L,))
-                cf = jnp.fft.fft(sl)
-                corr = jnp.fft.ifft(cf[None, :] * tf, axis=1)
-                p = (jnp.abs(corr) ** 2).astype(jnp.float32)
-                p = p.reshape(p.shape[0] // 2, 2 * L)
-                plane = plane + jnp.take(p, idx, axis=1)
+                with jax.named_scope("accel.correlate"):
+                    tf = join_planes(tf2[0], tf2[1])
+                    start = off0 + si * step
+                    sl = jax.lax.dynamic_slice(spec_pad, (start,), (L,))
+                    cf = jnp.fft.fft(sl)
+                    corr = jnp.fft.ifft(cf[None, :] * tf, axis=1)
+                    p = (jnp.abs(corr) ** 2).astype(jnp.float32)
+                    p = p.reshape(p.shape[0] // 2, 2 * L)
+                with jax.named_scope("accel.harmonic_sum"):
+                    plane = plane + jnp.take(p, idx, axis=1)
             col = jnp.arange(2 * segw, dtype=jnp.int32)
             plane = jnp.where(col[None, :] < 2 * width, plane,
                               jnp.float32(-jnp.inf))
             outs = []
             for wi in range(Wn):
-                outs.append(_detect_impl(plane[wi::Wn], thresh, topk))
+                with jax.named_scope("accel.detect"):
+                    outs.append(_detect_impl(plane[wi::Wn], thresh, topk))
             vals = jnp.stack([o[0] for o in outs])
             zi = jnp.stack([o[1] for o in outs])
             ri = jnp.stack([o[2] for o in outs])
@@ -371,21 +374,26 @@ def _make_stage_runner_batch(segw: int, Z: int, Wn: int, topk: int,
             width = jnp.minimum(segw, top_hi - r0)
             plane = jnp.zeros((B, Z * Wn, 2 * segw), jnp.float32)
             for (off0, step, hw, L), tf2, idx in zip(bank_meta, tfs, idxs):
-                tf = join_planes(tf2[0], tf2[1])  # [rows, L]
-                start = off0 + si * step
-                sl = jax.lax.dynamic_slice(spec_pad, (0, start), (B, L))
-                cf = jnp.fft.fft(sl, axis=1)  # [B, L]
-                corr = jnp.fft.ifft(cf[:, None, :] * tf[None, :, :], axis=2)
-                p = (jnp.abs(corr) ** 2).astype(jnp.float32)
-                p = p.reshape(B, p.shape[1] // 2, 2 * L)
-                plane = plane + jnp.take(p, idx, axis=2)
+                with jax.named_scope("accel.correlate"):
+                    tf = join_planes(tf2[0], tf2[1])  # [rows, L]
+                    start = off0 + si * step
+                    sl = jax.lax.dynamic_slice(spec_pad, (0, start), (B, L))
+                    cf = jnp.fft.fft(sl, axis=1)  # [B, L]
+                    corr = jnp.fft.ifft(cf[:, None, :] * tf[None, :, :],
+                                        axis=2)
+                    p = (jnp.abs(corr) ** 2).astype(jnp.float32)
+                    p = p.reshape(B, p.shape[1] // 2, 2 * L)
+                with jax.named_scope("accel.harmonic_sum"):
+                    plane = plane + jnp.take(p, idx, axis=2)
             col = jnp.arange(2 * segw, dtype=jnp.int32)
             plane = jnp.where(col[None, None, :] < 2 * width, plane,
                               jnp.float32(-jnp.inf))
             outs = []
             for wi in range(Wn):
-                outs.append(jax.vmap(_detect_impl, in_axes=(0, None, None))(
-                    plane[:, wi::Wn], thresh, topk))
+                with jax.named_scope("accel.detect"):
+                    outs.append(
+                        jax.vmap(_detect_impl, in_axes=(0, None, None))(
+                            plane[:, wi::Wn], thresh, topk))
             vals = jnp.stack([o[0] for o in outs], axis=1)   # [B, Wn, k]
             zi = jnp.stack([o[1] for o in outs], axis=1)
             ri = jnp.stack([o[2] for o in outs], axis=1)
@@ -740,7 +748,6 @@ def accel_search(
                                             front)
         runner = _make_stage_runner(segw, Zrows, Wn, cfg.topk,
                                     tuple(bank_meta))
-        telemetry.counter("accel.stage_dispatches")
         with telemetry.span("accel_stage", H=int(H),
                             n_seg=int(len(seg_ids))):
             return pull_host(*runner(
@@ -779,11 +786,7 @@ def accel_search(
                 raw_hits.append((H, wi, r0, vals[pos, wi], zi[pos, wi],
                                  ri[pos, wi], neigh[pos, wi], width))
 
-    cands = _refine_hits(raw_hits, zs, ws, cfg, numindep, thresh)
-    # counted on completion: a failed search that the CLI retries
-    # serially must not inflate the searched-spectra total
-    telemetry.counter("accel.spectra_searched")
-    return cands
+    return _refine_hits(raw_hits, zs, ws, cfg, numindep, thresh)
 
 
 def _stage_chunk_bytes(tfs, Z: int, Wn: int, segw: int) -> int:
@@ -936,9 +939,6 @@ def accel_search_batch(
             def dispatch(lo, hi, c0=c0):
                 faultinject.trip("accel.stage_dispatch")
                 sl = spec_pad2[c0 + lo:c0 + hi]
-                telemetry.counter("accel.stage_dispatches")
-                for d in span_attrs.get("dev", ()):
-                    telemetry.counter(f"device{d}.accel.stage_dispatches")
                 with telemetry.span("accel_stage_batch", H=int(H),
                                     batch=int(hi - lo),
                                     n_seg=int(len(seg_ids)),
@@ -996,10 +996,5 @@ def accel_search_batch(
                             (H, wi, r0, vals[pos, bl, wi], zi[pos, bl, wi],
                              ri[pos, bl, wi], neigh[pos, bl, wi], width))
 
-    out = [_refine_hits(raw, zs, ws, cfg, numindep, thresh)
-           for raw in raw_per_b]
-    # counted on completion (see accel_search): a batch that raised and
-    # fell back to the serial path must not double-count its spectra
-    telemetry.counter("accel.spectra_searched", B)
-    telemetry.counter("accel.batches")
-    return out
+    return [_refine_hits(raw, zs, ws, cfg, numindep, thresh)
+            for raw in raw_per_b]

@@ -238,15 +238,17 @@ def _prep_spectra_kernel(series, starts, lens, elem_block, elem_off, maxlen):
     # leaks into the low bins through f32 rounding of the butterflies —
     # the same fluctuation-scale argument as the sweep's baseline
     # subtraction (ADVICE r5)
-    s32 = series.astype(jnp.float32)
-    s32 = s32 - jnp.mean(s32, axis=1, keepdims=True)
-    fft = jnp.fft.rfft(s32, axis=1)
-    re = fft.real.astype(jnp.float32)
-    im = fft.imag.astype(jnp.float32)
-    powers = re * re + im * im
-    return jax.vmap(
-        _deredden_body, in_axes=(0, 0, 0, None, None, None, None, None)
-    )(re, im, powers, starts, lens, elem_block, elem_off, maxlen)
+    with jax.named_scope("prep.rfft"):
+        s32 = series.astype(jnp.float32)
+        s32 = s32 - jnp.mean(s32, axis=1, keepdims=True)
+        fft = jnp.fft.rfft(s32, axis=1)
+        re = fft.real.astype(jnp.float32)
+        im = fft.imag.astype(jnp.float32)
+    with jax.named_scope("prep.deredden"):
+        powers = re * re + im * im
+        return jax.vmap(
+            _deredden_body, in_axes=(0, 0, 0, None, None, None, None, None)
+        )(re, im, powers, starts, lens, elem_block, elem_off, maxlen)
 
 
 @plane_jit(static_argnames=("maxlen",), stage="accel")
@@ -258,10 +260,11 @@ def _prep_transformed_kernel(re, im, starts, lens, elem_block, elem_off,
     series to rfft. Mean subtraction is re-expressed spectrally: the
     series mean lives entirely in bin 0, which ``_deredden_body``
     overwrites with 1+0j, so nothing remains to subtract."""
-    powers = re * re + im * im
-    return jax.vmap(
-        _deredden_body, in_axes=(0, 0, 0, None, None, None, None, None)
-    )(re, im, powers, starts, lens, elem_block, elem_off, maxlen)
+    with jax.named_scope("prep.deredden"):
+        powers = re * re + im * im
+        return jax.vmap(
+            _deredden_body, in_axes=(0, 0, 0, None, None, None, None, None)
+        )(re, im, powers, starts, lens, elem_block, elem_off, maxlen)
 
 
 def prep_spectra_batch(series=None, schedule: DereddenSchedule | None = None,

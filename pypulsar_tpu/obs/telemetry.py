@@ -398,6 +398,10 @@ class Telemetry:
             rec = {"type": "counters", "counters": dict(self.counters),
                    "gauges": {k: dict(v) for k, v in self.gauges.items()},
                    "events": dict(self.event_counts)}
+            if _jit_listeners_on:
+                # on disk a session that counts compiles at the source
+                # always says so: 0 is a reading, absence is "not counted"
+                rec["counters"].setdefault("jit.compiles", 0)
             if self.hists:
                 rec["hists"] = {k: _trim_hist(v)
                                 for k, v in self.hists.items()}
@@ -451,6 +455,55 @@ class Telemetry:
                 self._fh = None
 
 
+# ---------------------------------------------------------------------------
+# every compile, counted at the source
+#
+# ``compile.cache_miss`` / ``compile.ms`` are the compile plane's own
+# registry (PlaneJit._compile). JAX itself reports every program that
+# missed its in-memory caches -- plain ``jax.jit`` sites and eager ops
+# included -- through ``jax.monitoring``; one listener pair, registered on
+# the first session of the process and returning at once outside one,
+# feeds the ``jit.*`` counters from there. On this JAX the backend-compile
+# event wraps ``compile_or_get_cached``: ``jit.compiles`` counts programs
+# built OR read from the persistent cache, and ``jit.persistent_hits``
+# says how many of them were reads.
+
+_JAX_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_JAX_TRACE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                     "/jax/core/compile/jaxpr_to_mlir_module_duration")
+_JAX_PERSISTENT_HIT = "/jax/compilation_cache/cache_hits"
+_jit_listeners_on = False
+
+
+def _on_jax_duration(name: str, seconds: float, **kw) -> None:
+    if _session is None:
+        return
+    if name == _JAX_BACKEND_COMPILE:
+        ms = seconds * 1e3
+        counter("jit.compiles")
+        counter("jit.compile_ms", ms)
+        # record_span("compile.first.*") has no live extent; this event
+        # is what puts a compile on the JSONL's timeline
+        event("jit.compile", ms=round(ms, 3), fun=kw.get("fun_name"))
+    elif name in _JAX_TRACE_EVENTS:
+        counter("jit.trace_ms", seconds * 1e3)
+
+
+def _on_jax_event(name: str, **kw) -> None:
+    if _session is not None and name == _JAX_PERSISTENT_HIT:
+        counter("jit.persistent_hits")
+
+
+def _register_jit_listeners() -> None:
+    global _jit_listeners_on
+    jax = sys.modules.get("jax")
+    if _jit_listeners_on or jax is None:
+        return
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    jax.monitoring.register_event_listener(_on_jax_event)
+    _jit_listeners_on = True
+
+
 @contextlib.contextmanager
 def session(path: Optional[str] = None, **meta):
     """Activate telemetry for the block; yields the :class:`Telemetry`.
@@ -465,6 +518,7 @@ def session(path: Optional[str] = None, **meta):
     if outer is not None:
         yield outer
         return
+    _register_jit_listeners()
     tlm = Telemetry(path, meta or None)
     _session = tlm
     try:
@@ -535,6 +589,51 @@ def span(name: str, *, aggregate: bool = True, **attrs):
     return _live_span(name, attrs, aggregate)
 
 
+# ---------------------------------------------------------------------------
+# the seam to the profiler
+#
+# A live span also enters a ``jax.profiler.TraceAnnotation`` on the
+# thread that records it, so under ``jax.profiler.trace`` every span --
+# the ship-ahead and prefetch workers' on their own lines -- sits in the
+# profiler's host plane on the device trace's clock, with its scalar
+# attributes as stats. With no profiler running an annotation is one
+# atomic load. JAX is reached through ``sys.modules`` only (as
+# :func:`_collect_devices` does): ``obs`` never imports it.
+
+_SCALARS = (bool, int, float, str)
+
+
+def _scalars(attrs: Dict[str, Any], skip=()) -> Dict[str, Any]:
+    return {k: v for k, v in attrs.items()
+            if isinstance(v, _SCALARS) and k not in skip}
+
+
+def _annotate(name: str, attrs: Dict[str, Any]):
+    """An entered TraceAnnotation for a live span, or None without JAX."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        ann = jax.profiler.TraceAnnotation(name, **_scalars(attrs))
+        ann.__enter__()
+    except Exception:  # noqa: BLE001 - the profiler is a passenger
+        return None
+    return ann
+
+
+def _close_annotation(ann, attrs: Dict[str, Any], entered) -> None:
+    """Leave the annotation, first handing it the scalar attributes the
+    block attached mid-flight (``sp.set(rows=n)``; ``entered`` are the
+    keys it was opened with)."""
+    try:
+        late = _scalars(attrs, skip=entered)
+        if late:
+            ann.set_metadata(**late)
+        ann.__exit__(None, None, None)
+    except Exception:  # noqa: BLE001
+        pass
+
+
 @contextlib.contextmanager
 def _live_span(name: str, attrs, aggregate: bool = True):
     s = _session
@@ -553,12 +652,16 @@ def _live_span(name: str, attrs, aggregate: bool = True):
                      else ctx.span_id)
         ids = (ctx.trace_id, handle.sid, parent_id)
     stack.append(handle)
+    ann = _annotate(name, attrs)
+    entered = tuple(attrs)
     t_start = s._now()
     t0 = time.perf_counter()
     try:
         yield handle
     finally:
         dur = time.perf_counter() - t0
+        if ann is not None:
+            _close_annotation(ann, handle.attrs, entered)
         stack.pop()
         s._finish_span(name, t_start, dur, parent, depth, handle.attrs,
                        aggregate, ids=ids)

@@ -144,7 +144,8 @@ def sweep_chunk_fourier_impl(
     C, L = data.shape
     G, g, S = stage2_bins.shape
     per = C // nsub
-    X = jnp.fft.rfft(data, n=n_fft, axis=1)  # [C, F]
+    with jax.named_scope("dedisp.rfft"):
+        X = jnp.fft.rfft(data, n=n_fft, axis=1)  # [C, F]
     F = X.shape[1]
     k = jnp.arange(F, dtype=jnp.int32)
     use_lut = phase_mode == "lut" and max_shift1 >= 0 and max_shift2 >= 0 \
@@ -168,31 +169,36 @@ def sweep_chunk_fourier_impl(
 
         def per_group_fact(carry, xs):
             s1, s2 = xs  # [C], [g, S]
-            hi1 = _phase(s1 * jnp.int32(M), k_hi, n_fft)  # [C, Fh]
-            lo1 = _phase(s1, k_lo, n_fft)                 # [C, M]
-            xsub = (Xp * hi1[:, :, None] * lo1[:, None, :]) \
-                .reshape(nsub, per, Fh, M).sum(axis=1)     # [S, Fh, M]
-            hi2 = _phase(s2 * jnp.int32(M), k_hi, n_fft)  # [g, S, Fh]
-            lo2 = _phase(s2, k_lo, n_fft)                 # [g, S, M]
-            xts = (xsub[None] * hi2[..., None] * lo2[..., None, :]) \
-                .sum(axis=1)                               # [g, Fh, M]
-            xts = xts.reshape(-1, Fh * M)[:, :F]
-            ts = jnp.fft.irfft(xts, n=n_fft, axis=1)[:, :out_len]
+            with jax.named_scope("dedisp.stage1"):
+                hi1 = _phase(s1 * jnp.int32(M), k_hi, n_fft)  # [C, Fh]
+                lo1 = _phase(s1, k_lo, n_fft)                 # [C, M]
+                xsub = (Xp * hi1[:, :, None] * lo1[:, None, :]) \
+                    .reshape(nsub, per, Fh, M).sum(axis=1)     # [S, Fh, M]
+            with jax.named_scope("dedisp.stage2"):
+                hi2 = _phase(s2 * jnp.int32(M), k_hi, n_fft)  # [g, S, Fh]
+                lo2 = _phase(s2, k_lo, n_fft)                 # [g, S, M]
+                xts = (xsub[None] * hi2[..., None] * lo2[..., None, :]) \
+                    .sum(axis=1)                               # [g, Fh, M]
+                xts = xts.reshape(-1, Fh * M)[:, :F]
+            with jax.named_scope("dedisp.irfft"):
+                ts = jnp.fft.irfft(xts, n=n_fft, axis=1)[:, :out_len]
             s, ss, mb_g, ab_g = boxcar_stats(ts, widths, stat_len,
                                              backend=boxcar_backend)
             return carry, (s, ss, mb_g, ab_g)
 
     def per_group(carry, xs):
         s1, s2 = xs  # [C], [g, S]
-        if use_lut:
-            ph1 = t1[s1]
-            ph2 = t_hi[s2 // _LUT_LO] * t_lo[s2 % _LUT_LO]
-        else:
-            ph1 = _phase(s1, k, n_fft)
-            ph2 = _phase(s2, k, n_fft)
-        xsub = (X * ph1).reshape(nsub, per, F).sum(axis=1)
-        xts = (xsub[None, :, :] * ph2).sum(axis=1)  # [g, F]
-        ts = jnp.fft.irfft(xts, n=n_fft, axis=1)[:, :out_len]
+        with jax.named_scope("dedisp.stage1"):
+            ph1 = t1[s1] if use_lut else _phase(s1, k, n_fft)
+            xsub = (X * ph1).reshape(nsub, per, F).sum(axis=1)
+        with jax.named_scope("dedisp.stage2"):
+            if use_lut:
+                ph2 = t_hi[s2 // _LUT_LO] * t_lo[s2 % _LUT_LO]
+            else:
+                ph2 = _phase(s2, k, n_fft)
+            xts = (xsub[None, :, :] * ph2).sum(axis=1)  # [g, F]
+        with jax.named_scope("dedisp.irfft"):
+            ts = jnp.fft.irfft(xts, n=n_fft, axis=1)[:, :out_len]
         s, ss, mb_g, ab_g = boxcar_stats(ts, widths, stat_len,
                                          backend=boxcar_backend)
         return carry, (s, ss, mb_g, ab_g)
@@ -234,7 +240,8 @@ def dedisperse_series_fourier_impl(
     C, L = data.shape
     G, g, S = stage2_bins.shape
     per = C // nsub
-    X = jnp.fft.rfft(data, n=n_fft, axis=1)  # [C, F]
+    with jax.named_scope("dedisp.rfft"):
+        X = jnp.fft.rfft(data, n=n_fft, axis=1)  # [C, F]
     F = X.shape[1]
     k = jnp.arange(F, dtype=jnp.int32)
 
@@ -247,24 +254,32 @@ def dedisperse_series_fourier_impl(
 
         def body(carry, xs):
             s1, s2 = xs
-            hi1 = _phase(s1 * jnp.int32(M), k_hi, n_fft)
-            lo1 = _phase(s1, k_lo, n_fft)
-            xsub = (Xp * hi1[:, :, None] * lo1[:, None, :]) \
-                .reshape(nsub, per, Fh, M).sum(axis=1)
-            hi2 = _phase(s2 * jnp.int32(M), k_hi, n_fft)
-            lo2 = _phase(s2, k_lo, n_fft)
-            xts = (xsub[None] * hi2[..., None] * lo2[..., None, :]) \
-                .sum(axis=1)
-            xts = xts.reshape(-1, Fh * M)[:, :F]
-            return carry, jnp.fft.irfft(xts, n=n_fft, axis=1)[:, :out_len]
+            with jax.named_scope("dedisp.stage1"):
+                hi1 = _phase(s1 * jnp.int32(M), k_hi, n_fft)
+                lo1 = _phase(s1, k_lo, n_fft)
+                xsub = (Xp * hi1[:, :, None] * lo1[:, None, :]) \
+                    .reshape(nsub, per, Fh, M).sum(axis=1)
+            with jax.named_scope("dedisp.stage2"):
+                hi2 = _phase(s2 * jnp.int32(M), k_hi, n_fft)
+                lo2 = _phase(s2, k_lo, n_fft)
+                xts = (xsub[None] * hi2[..., None] * lo2[..., None, :]) \
+                    .sum(axis=1)
+                xts = xts.reshape(-1, Fh * M)[:, :F]
+            with jax.named_scope("dedisp.irfft"):
+                return carry, jnp.fft.irfft(
+                    xts, n=n_fft, axis=1)[:, :out_len]
     else:
         def body(carry, xs):
             s1, s2 = xs
-            ph1 = _phase(s1, k, n_fft)
-            ph2 = _phase(s2, k, n_fft)
-            xsub = (X * ph1).reshape(nsub, per, F).sum(axis=1)
-            xts = (xsub[None, :, :] * ph2).sum(axis=1)
-            return carry, jnp.fft.irfft(xts, n=n_fft, axis=1)[:, :out_len]
+            with jax.named_scope("dedisp.stage1"):
+                ph1 = _phase(s1, k, n_fft)
+                xsub = (X * ph1).reshape(nsub, per, F).sum(axis=1)
+            with jax.named_scope("dedisp.stage2"):
+                ph2 = _phase(s2, k, n_fft)
+                xts = (xsub[None, :, :] * ph2).sum(axis=1)
+            with jax.named_scope("dedisp.irfft"):
+                return carry, jnp.fft.irfft(
+                    xts, n=n_fft, axis=1)[:, :out_len]
 
     _, ts = jax.lax.scan(body, 0, (stage1_bins, stage2_bins))
     return ts.reshape(G * g, out_len)
@@ -332,7 +347,8 @@ def sweep_chunk_spectra_impl(
     live = (col < mean_len).astype(data.dtype)[None, :]
     mu = (data * live).sum(axis=1, keepdims=True) / jnp.float32(mean_len)
     data = data - mu * live
-    X = jnp.fft.rfft(data, n=n_fft, axis=1)  # [C, F]
+    with jax.named_scope("dedisp.rfft"):
+        X = jnp.fft.rfft(data, n=n_fft, axis=1)  # [C, F]
     F = X.shape[1]
     k = jnp.arange(F, dtype=jnp.int32)
     didx = jnp.arange(dec_len, dtype=jnp.int32) * jnp.int32(dec_stride)

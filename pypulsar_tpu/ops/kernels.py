@@ -292,8 +292,11 @@ def channel_maskvals(data, maskval="median-mid80"):
         n = int(np.round(0.1 * T))
         if n == 0:
             return jnp.median(data, axis=-1)
-        srt = jnp.sort(data, axis=-1)[:, n:-n]
-        return jnp.median(srt, axis=-1)
+        # the two sorts of the mask fill (median re-sorts the middle)
+        with jax.named_scope("mask.sort_mid80"):
+            srt = jnp.sort(data, axis=-1)[:, n:-n]
+        with jax.named_scope("mask.median"):
+            return jnp.median(srt, axis=-1)
     return jnp.full((C,), maskval, dtype=data.dtype)
 
 
@@ -302,7 +305,8 @@ def masked(data, mask, maskval="median-mid80"):
     """Replace masked cells (mask True) with per-channel fill values
     (reference formats/spectra.py:190-227)."""
     vals = channel_maskvals(data, maskval)
-    return jnp.where(mask, vals[:, None].astype(data.dtype), data)
+    with jax.named_scope("mask.fill"):
+        return jnp.where(mask, vals[:, None].astype(data.dtype), data)
 
 
 @jax.jit

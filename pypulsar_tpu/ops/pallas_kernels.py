@@ -210,8 +210,10 @@ def boxcar_stats(ts, widths: Tuple[int, ...], stat_len: int,
     widths = tuple(int(w) for w in widths)
     if backend == "auto":
         backend = "pallas" if _on_tpu() else "lax"
-    if backend == "pallas":
-        return _pallas_boxcar_stats(ts, widths, stat_len)
-    if backend == "interpret":
-        return _pallas_boxcar_stats(ts, widths, stat_len, interpret=True)
-    return _lax_boxcar_stats(ts, widths, stat_len)
+    with jax.named_scope("boxcar.scan"):
+        if backend == "pallas":
+            return _pallas_boxcar_stats(ts, widths, stat_len)
+        if backend == "interpret":
+            return _pallas_boxcar_stats(ts, widths, stat_len,
+                                        interpret=True)
+        return _lax_boxcar_stats(ts, widths, stat_len)

@@ -57,6 +57,7 @@ __all__ = [
 
 
 @partial(jax.jit, static_argnames=("pts", "n_fft"))
+@jax.named_scope("rfifind.block_stats")
 def _block_stats_impl(data, pts: int, n_fft: int):
     """data[C, nint*pts] -> (mean[nint, C], std[nint, C], maxpow[nint, C]).
 
@@ -85,7 +86,7 @@ def _block_stats_impl(data, pts: int, n_fft: int):
 def block_stats(data, pts: int):
     """Device per-block stats of ``data[C, T]`` (whole intervals only)."""
     n_fft = fourier_chunk_len(pts)
-    return _block_stats_impl(jnp.asarray(data, jnp.float32), pts, n_fft)
+    return _block_stats_impl(transfer.ship(data, jnp.float32), pts, n_fft)
 
 
 def block_stats_numpy(data: np.ndarray, pts: int):
@@ -265,15 +266,21 @@ def _iter_file_blocks(reader, samples_per_read: int):
         flip = len(f) > 1 and f[0] > f[-1]
     else:
         flip = True
+    # on-disk bytes of one spectrum, for io.bytes_read
+    per_spec = getattr(reader, "bytes_per_spectrum", None)
     pos = 0
     while pos < total:
         n = min(samples_per_read, total - pos)
-        if get_samples is not None:
-            d = get_samples(pos, n).T
-        elif get_interval is not None:
-            d = get_interval(pos, pos + n).T
-        else:
-            d = np.asarray(reader.get_spectra(pos, n).data)
+        # read + host unpack (sub-byte files widen to float32 here)
+        with telemetry.span("io.read", aggregate=False, samples=int(n)):
+            if get_samples is not None:
+                d = get_samples(pos, n).T
+            elif get_interval is not None:
+                d = get_interval(pos, pos + n).T
+            else:
+                d = np.asarray(reader.get_spectra(pos, n).data)
+        telemetry.counter("io.bytes_read", int(
+            n * per_spec if per_spec else d.nbytes))
         yield d[::-1] if flip else d
         pos += n
 
@@ -346,20 +353,26 @@ def rfifind(
 
     def consume(chunk, final=False):
         nonlocal carry
-        buf = np.concatenate([carry, np.asarray(chunk, np.float32)], axis=1)
-        nint = buf.shape[1] // pts
-        if final:
-            tail = buf.shape[1] - nint * pts
-            if tail >= pts // 2:
-                pad = np.repeat(buf[:, -1:], pts - tail, axis=1)
-                buf = np.concatenate([buf, pad], axis=1)
-                nint += 1
+        # float32 cast (materializes the reader's transposed / flipped
+        # view) + carry concatenate + tail pad: the block as it ships
+        with telemetry.span("rfifind.stage_block") as sp:
+            buf = np.concatenate([carry, np.asarray(chunk, np.float32)],
+                                 axis=1)
+            nint = buf.shape[1] // pts
+            if final:
+                tail = buf.shape[1] - nint * pts
+                if tail >= pts // 2:
+                    pad = np.repeat(buf[:, -1:], pts - tail, axis=1)
+                    buf = np.concatenate([buf, pad], axis=1)
+                    nint += 1
+            if sp is not None:
+                sp.set(bytes=int(buf.nbytes))
         if nint:
             telemetry.counter("rfifind.intervals", int(nint))
-            # one batched pull per block (3 device->host syncs otherwise)
+            block = transfer.ship(buf[:, : nint * pts], jnp.float32)
+            # program + ONE batched pull (3 device->host syncs otherwise)
             with telemetry.span("rfifind_block_stats", nint=int(nint)):
-                m, s, p = transfer.pull_host(
-                    *block_stats(buf[:, : nint * pts], pts))
+                m, s, p = transfer.pull_host(*block_stats(block, pts))
             means.append(m)
             stds.append(s)
             maxpows.append(p)
@@ -380,10 +393,12 @@ def rfifind(
         maxpow=np.concatenate(maxpows), ptsperint=pts, dtint=pts * dt,
         lofreq=lofreq, df=df, mjd=mjd,
     )
-    flags = clip_stats(stats, time_sigma=time_sigma, freq_sigma=freq_sigma)
-    zc, zi, per_int = mask_products(flags, chanfrac=chanfrac, intfrac=intfrac,
-                                    extra_zap_chans=zap_chans,
-                                    extra_zap_ints=zap_ints)
+    with telemetry.span("rfifind.clip"):
+        flags = clip_stats(stats, time_sigma=time_sigma,
+                           freq_sigma=freq_sigma)
+        zc, zi, per_int = mask_products(
+            flags, chanfrac=chanfrac, intfrac=intfrac,
+            extra_zap_chans=zap_chans, extra_zap_ints=zap_ints)
     # effective mask coverage (union of whole-channel, whole-interval and
     # per-interval zaps, via the reader's own table builder). A BRIGHT
     # PULSAR trips the Fourier max-power detector in every (interval,
@@ -406,12 +421,13 @@ def rfifind(
     if outbase is not None:
         from pypulsar_tpu.io.rfimask import write_mask
 
-        maskfn = write_mask(
-            outbase + "_rfifind.mask", time_sigma=time_sigma,
-            freq_sigma=freq_sigma, mjd=stats.mjd, dtint=stats.dtint,
-            lofreq=stats.lofreq, df=stats.df, nchan=stats.nchan,
-            nint=stats.nint, ptsperint=pts, zap_chans=zc, zap_ints=zi,
-            zap_chans_per_int=per_int,
-        )
-        stats.save(outbase + "_rfifind.stats.npz")
+        with telemetry.span("rfifind.write"):
+            maskfn = write_mask(
+                outbase + "_rfifind.mask", time_sigma=time_sigma,
+                freq_sigma=freq_sigma, mjd=stats.mjd, dtint=stats.dtint,
+                lofreq=stats.lofreq, df=stats.df, nchan=stats.nchan,
+                nint=stats.nint, ptsperint=pts, zap_chans=zc, zap_ints=zi,
+                zap_chans_per_int=per_int,
+            )
+            stats.save(outbase + "_rfifind.stats.npz")
     return stats, flags, maskfn

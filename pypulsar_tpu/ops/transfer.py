@@ -20,7 +20,30 @@ import numpy as np
 
 from pypulsar_tpu.obs import telemetry
 
-__all__ = ["split_complex", "to_host_complex", "join_planes", "pull_host"]
+__all__ = ["split_complex", "to_host_complex", "join_planes", "pull_host",
+           "ship"]
+
+
+def ship(x, dtype=None):
+    """Host -> device: ``jnp.asarray(x, dtype)``, the call every ship
+    site made itself, under one ``h2d.ship`` span that carries the bytes
+    put on the link (also added to the ``h2d.bytes`` counter). The span
+    is as long as the call holds its thread; nothing waits for the copy
+    to land. An array that already lives on a device passes through
+    uncounted. Sink-only span (``aggregate=False``): ships nest inside
+    the sweep loop's stages and run on the ship-ahead worker, whose wall
+    overlaps the main thread's."""
+    import jax.numpy as jnp
+
+    if isinstance(x, jax.Array):
+        return jnp.asarray(x, dtype=dtype)
+    wire = np.dtype(dtype if dtype is not None
+                    else getattr(x, "dtype", np.float32))
+    nbytes = int(np.size(x)) * wire.itemsize
+    with telemetry.span("h2d.ship", aggregate=False, bytes=nbytes):
+        out = jnp.asarray(x, dtype=dtype)
+    telemetry.counter("h2d.bytes", nbytes)
+    return out
 
 
 def pull_host(*arrays):
@@ -32,12 +55,18 @@ def pull_host(*arrays):
     pull on a hot path. Always returns a tuple
     (same arity as the arguments), so star-splatted call sites unpack
     predictably even for one output. Under an active telemetry session
-    the pull is accounted to the ``d2h.bytes``/``d2h.pulls`` counters."""
-    if telemetry.is_active():
-        telemetry.counter("d2h.bytes", sum(
-            int(getattr(a, "nbytes", 0) or 0) for a in arrays))
-        telemetry.counter("d2h.pulls")
-    return jax.device_get(arrays)
+    the pull is a ``d2h.pull`` span (sink-only; its wall includes the
+    wait for the programs that produce the arrays) and is accounted to
+    the ``d2h.bytes``/``d2h.pulls`` counters."""
+    if not telemetry.is_active():
+        return jax.device_get(arrays)
+    nbytes = sum(int(getattr(a, "nbytes", 0) or 0) for a in arrays)
+    with telemetry.span("d2h.pull", aggregate=False, bytes=nbytes,
+                        arrays=len(arrays)):
+        out = jax.device_get(arrays)
+    telemetry.counter("d2h.bytes", nbytes)
+    telemetry.counter("d2h.pulls")
+    return out
 
 
 def join_planes(re, im):

@@ -28,7 +28,7 @@ import numpy as np
 
 from pypulsar_tpu.compile import plane_jit
 from pypulsar_tpu.obs import telemetry
-from pypulsar_tpu.ops import kernels
+from pypulsar_tpu.ops import kernels, transfer
 from pypulsar_tpu.tune import knobs
 from pypulsar_tpu.parallel.sweep import (
     DEFAULT_WIDTHS,
@@ -263,6 +263,28 @@ class _ReaderSource:
         return block[::-1] if self._flip else block
 
 
+def _timed_reads(raw_blocks):
+    """``raw_blocks`` with each pull from the reader under an ``io.read``
+    span (on whichever thread iterates: the ship-ahead worker), its
+    on-disk bytes added to ``io.bytes_read``."""
+    it = iter(raw_blocks)
+    try:
+        while True:
+            with telemetry.span("io.read", aggregate=False) as sp:
+                item = next(it, None)
+                if item is not None and sp is not None:
+                    sp.set(samples=int(item[1].shape[0]),
+                           bytes=int(item[1].nbytes))
+            if item is None:
+                return
+            telemetry.counter("io.bytes_read", int(item[1].nbytes))
+            yield item
+    finally:
+        close = getattr(it, "close", None)
+        if close is not None:
+            close()
+
+
 def _ship_ahead(raw_blocks, depth: int = 2):
     """Host->device ship of streamed blocks on a background thread.
 
@@ -286,14 +308,11 @@ def _ship_ahead(raw_blocks, depth: int = 2):
 
     def ship(item):
         pos, block = item
-        if telemetry.is_active():  # counters are thread-safe
-            telemetry.counter("h2d.bytes",
-                              int(getattr(block, "nbytes", 0) or 0))
-        return pos, jnp.asarray(block)
+        return pos, transfer.ship(block)
 
     # retries: a transient wire failure re-ships the (still in hand)
     # host block instead of aborting the whole streamed sweep
-    return prefetch(raw_blocks, depth=depth, name="sweep.ship",
+    return prefetch(_timed_reads(raw_blocks), depth=depth, name="sweep.ship",
                     transform=ship, thread_name="pypulsar-ship-ahead",
                     retries=2)
 
@@ -336,7 +355,7 @@ class _MaskedSource:
                 # past 2^31 samples; base + (rem + arange(L)) // pts is
                 # exact for any file length (rem < pts, base < nint)
                 block = _masked_block(
-                    jnp.asarray(block, dtype=jnp.float32), self._table,
+                    transfer.ship(block, jnp.float32), self._table,
                     min(pos // self._pts, nint - 1), pos % self._pts,
                     self._pts)
             yield pos, block
@@ -406,9 +425,7 @@ def _downsampled_blocks(src, factor: int, payload_ds: int, overlap_ds: int):
         return
     for pos, block in src.chan_major_blocks(payload_ds * factor,
                                             overlap_ds * factor):
-        if telemetry.is_active() and not isinstance(block, jax.Array):
-            telemetry.counter("h2d.bytes", 4 * int(np.size(block)))
-        data = jnp.asarray(block, dtype=jnp.float32)
+        data = transfer.ship(block, jnp.float32)
         if factor > 1:
             nbin = data.shape[1] // factor
             if nbin == 0:
@@ -507,17 +524,20 @@ def _run_step(src, dms, factor: int, nsub: int, group_size: int,
     n_ds = src.nsamples // factor
     if n_ds == 0:
         return None
-    if group_size <= 0:
-        from pypulsar_tpu.parallel.sweep import choose_group_size
+    from pypulsar_tpu.parallel.sweep import (
+        choose_group_size,
+        padded_group_count,
+    )
 
-        group_size = choose_group_size(dms, src.frequencies, dt_eff, nsub)
-    from pypulsar_tpu.parallel.sweep import padded_group_count
-
-    ndm = 1 if mesh is None else mesh.shape["dm"]
-    pad_groups_to = padded_group_count(-(-len(dms) // group_size), ndm)
-    plan = make_sweep_plan(dms, src.frequencies, dt_eff, nsub=nsub,
-                           group_size=group_size, widths=widths,
-                           pad_groups_to=pad_groups_to)
+    with telemetry.span("sweep.plan", n_trials=len(dms)):
+        if group_size <= 0:
+            group_size = choose_group_size(dms, src.frequencies, dt_eff,
+                                           nsub)
+        ndm = 1 if mesh is None else mesh.shape["dm"]
+        pad_groups_to = padded_group_count(-(-len(dms) // group_size), ndm)
+        plan = make_sweep_plan(dms, src.frequencies, dt_eff, nsub=nsub,
+                               group_size=group_size, widths=widths,
+                               pad_groups_to=pad_groups_to)
     # default payload is BOUNDED (round 5): the previous whole-file
     # default made a --chunk-less CLI sweep of an hour-scale file try to
     # build one 2^26-sample chunk (a ~275 GB device buffer) — small data
@@ -614,9 +634,10 @@ def sweep_flat(
     streaming/downsampling machinery). ``checkpoint_path`` enables in-sweep
     checkpoint/resume (see SweepCheckpoint); ``rfimask`` (an
     io.rfimask.RfifindMask) applies median-mid80 mask fill per block."""
-    src = _make_source(source, rfimask)
-    ckpt = (SweepCheckpoint(checkpoint_path, every=checkpoint_every)
-            if checkpoint_path else None)
+    with telemetry.span("sweep.plan"):  # the block source, with its guards
+        src = _make_source(source, rfimask)
+        ckpt = (SweepCheckpoint(checkpoint_path, every=checkpoint_every)
+                if checkpoint_path else None)
     step = _run_step(src, np.asarray(dms, dtype=np.float64), int(downsamp),
                      nsub, group_size, tuple(widths), chunk_payload, mesh,
                      verbose=verbose, checkpoint=ckpt, engine=engine,

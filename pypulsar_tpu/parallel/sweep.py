@@ -56,7 +56,6 @@ from pypulsar_tpu.ops import transfer
 from pypulsar_tpu.ops.pallas_kernels import boxcar_stats
 from pypulsar_tpu.obs import telemetry
 from pypulsar_tpu.tune import knobs
-from pypulsar_tpu.utils import profiling
 
 DEFAULT_WIDTHS = (1, 2, 4, 8, 16, 32)
 
@@ -392,15 +391,18 @@ def _sweep_chunk_impl(
 
     def per_group(carry, xs):
         shift1, shift2 = xs
-        if engine == "scan":
-            # scan-based formulation (see _shift_segment_sum)
-            sub = _shift_segment_sum(data, shift1, L1, per)  # [S, L1]
-        else:
-            sliced = _slice_rows(data, shift1, L1)  # [C, L1]
-            sub = sliced.reshape(nsub, per, L1).sum(axis=1)  # [S, L1]
-        ts = jax.vmap(lambda sh: _slice_rows(sub, sh, out_len).sum(axis=0))(
-            shift2
-        )  # [g, out_len]
+        with jax.named_scope("dedisp.stage1"):
+            if engine == "scan":
+                # scan-based formulation (see _shift_segment_sum)
+                sub = _shift_segment_sum(data, shift1, L1, per)  # [S, L1]
+            else:
+                sliced = _slice_rows(data, shift1, L1)  # [C, L1]
+                sub = sliced.reshape(nsub, per, L1).sum(axis=1)  # [S, L1]
+        with jax.named_scope("dedisp.stage2"):
+            ts = jax.vmap(
+                lambda sh: _slice_rows(sub, sh, out_len).sum(axis=0))(
+                shift2
+            )  # [g, out_len]
         # fused detection stats: Pallas kernel on TPU, lax elsewhere
         # (windows start within the payload region)
         s, ss, mb_g, ab_g = boxcar_stats(ts, widths, stat_len)
@@ -484,10 +486,13 @@ def _dedisperse_series_jit(data, stage1_bins, stage2_bins, nsub,
 
     def per_group(carry, xs):
         shift1, shift2 = xs
-        sliced = _slice_rows(data, shift1, L1)
-        sub = sliced.reshape(nsub, per, L1).sum(axis=1)
-        ts = jax.vmap(lambda sh: _slice_rows(sub, sh, out_len).sum(axis=0))(
-            shift2)
+        with jax.named_scope("dedisp.stage1"):
+            sliced = _slice_rows(data, shift1, L1)
+            sub = sliced.reshape(nsub, per, L1).sum(axis=1)
+        with jax.named_scope("dedisp.stage2"):
+            ts = jax.vmap(
+                lambda sh: _slice_rows(sub, sh, out_len).sum(axis=0))(
+                shift2)
         return carry, ts
 
     _, ts = jax.lax.scan(per_group, 0, (stage1_bins, stage2_bins))
@@ -898,7 +903,7 @@ class SweepCheckpoint:
         self._drained += n
         if fire:
             telemetry.counter("sweep.checkpoint_saves")
-            with profiling.stage("checkpoint_save"):
+            with telemetry.span("checkpoint_save"):
                 self.save(plan, chunk_payload, acc, cursor, baseline,
                           context)
 
@@ -1064,7 +1069,7 @@ def sweep_stream(
         due = []
         while len(pending) > limit:
             due.append(pending.pop(0))
-        with profiling.stage("device_wait+accumulate"):
+        with telemetry.span("device_wait+accumulate"):
             flat = transfer.pull_host(
                 *(arr for _, _, parts in due for outs in parts
                   for arr in outs))
@@ -1082,9 +1087,9 @@ def sweep_stream(
                         np.concatenate(got[j::4]) for j in range(4))
                 acc.update(start, stat_len, s, ss, mb, ab)
                 cursor = start + stat_len
-        # outside the stage: checkpoint_save has its own profiling stage
-        # and nested stages both record wall time (utils/profiling.py),
-        # so saving inside would double-count in the overlap accounting
+        # outside the span: checkpoint_save has its own aggregated span
+        # and nested aggregated spans both record wall time, so saving
+        # inside would double-count in the overlap accounting
         if checkpoint is not None:
             checkpoint.on_drained(plan, chunk_payload, acc, cursor,
                                   baseline, ckpt_context, n=len(due))
@@ -1095,7 +1100,7 @@ def sweep_stream(
         if L < need:  # end-of-data: pad with zeros (reference pads padval=0)
             data = jnp.pad(data, ((0, 0), (0, need - L)))
         stat_len = min(chunk_payload, L)
-        with profiling.stage("dispatch_sweep_chunk"):
+        with telemetry.span("dispatch_sweep_chunk"):
             pending.append((start, stat_len, run_chunk(data, stat_len)))
         if telemetry.is_active():
             # one record per streamed chunk: position, payload and the
@@ -1115,25 +1120,21 @@ def sweep_stream(
         baseline = jnp.asarray(baseline, dtype=jnp.float32).reshape(-1, 1)
     # explicit iteration so the time spent PRODUCING each block (disk read
     # wait + host->device ship in the source generator) is attributed to
-    # its own profiling stage — the streamed-bench overlap accounting
+    # its own span (block_source) — the streamed-bench overlap accounting
     # needs transfer separated from device wait (BENCHNOTES.md round 4)
     _block_iter = iter(blocks)
     while True:
-        with profiling.stage("block_source"):
+        with telemetry.span("block_source"):
             nxt = next(_block_iter, None)
         if nxt is None:
             break
         start, block = nxt
         if start < cursor:  # chunk already accumulated (checkpoint resume)
             continue
-        with profiling.stage("host_to_device"):
-            was_host = not isinstance(block, jax.Array)
-            if chan_major:
-                data = jnp.asarray(block, dtype=jnp.float32)
-            else:
-                data = jnp.asarray(np.ascontiguousarray(block.T), dtype=jnp.float32)
-            if was_host and telemetry.is_active():
-                telemetry.counter("h2d.bytes", int(data.nbytes))
+        with telemetry.span("host_to_device"):
+            if not chan_major:
+                block = np.ascontiguousarray(block.T)
+            data = transfer.ship(block, jnp.float32)
         if baseline is None:
             # per-channel baseline from the first block (see the SNR
             # accumulation-order contract in the docstring)
@@ -1176,8 +1177,9 @@ def sweep_stream(
         # accumulation grouping is deterministic
         return AccumParts(acc.n, acc.s, acc.ss, acc.mb, acc.ab, B,
                           tuple(acc.chunk_mb), tuple(acc.chunk_ab))
-    return finalize_sweep(plan, acc.n, acc.s, acc.ss, acc.mb, acc.ab, B,
-                          chunk_mb=acc.chunk_mb, chunk_ab=acc.chunk_ab)
+    with telemetry.span("sweep.finalize", rows=int(plan.n_real_trials)):
+        return finalize_sweep(plan, acc.n, acc.s, acc.ss, acc.mb, acc.ab, B,
+                              chunk_mb=acc.chunk_mb, chunk_ab=acc.chunk_ab)
 
 
 def finalize_sweep(plan: SweepPlan, n: int, s, ss, mb, ab,

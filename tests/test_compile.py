@@ -455,3 +455,79 @@ def test_aot_execution_error_propagates_argument_mismatch_retraces():
         with pytest.raises(RuntimeError, match="device halted"):
             f(x)
         assert tlm.counter_totals().get("compile.aot_fallback", 0) == 1
+
+
+def test_plane_jit_names_the_module_and_scopes_its_operations():
+    """What the profiler prints follows the wrapper's name: the module is
+    jit_<name>, every operation sits under "<stage>.<name>", and the
+    signature static_argnames bind against is still the original's."""
+    from pypulsar_tpu.compile import PlaneJit
+
+    def run(x, n):
+        return (x * 2.0).sum() * n
+
+    pj = PlaneJit(run, stage="accel", name="accel_stage",
+                  static_argnames=("n",))
+    x = jnp.ones(4, jnp.float32)
+    lowered = pj._jit.lower(x, n=3)
+    text = lowered.as_text(debug_info=True)
+    assert "module @jit_accel_stage" in text
+    assert "jit(accel_stage)/accel.accel_stage/mul" in text
+    assert "HloModule jit_accel_stage" in lowered.compile().as_text()
+    assert float(pj(x, 3)) == float(pj(x, n=3)) == 24.0
+    assert pj.cache_size() == 1  # both call forms, one registry entry
+    # without name= the function's own name is kept, with its stage
+    anon = PlaneJit(run, stage="sweep", static_argnames=("n",))
+    assert "jit(run)/sweep.run/" in anon._jit.lower(x, n=1).as_text(
+        debug_info=True)
+
+
+def test_jit_compiles_covers_the_planes_misses():
+    """jit.compiles (JAX's own report) counts at least what the plane's
+    registry counts as misses, and none on a registry hit."""
+    f = plane_jit(lambda x: (x * 7.0 + 2.0).sum(), name="t_jit_vs_plane")
+    x = jnp.ones((4, 4), jnp.float32)
+    with telemetry.session() as tlm:
+        f(x)
+        cold = tlm.counter_totals()
+    with telemetry.session() as tlm:
+        f(x)
+        warm = tlm.counter_totals()
+    assert cold["jit.compiles"] >= cold["compile.cache_miss"] == 1
+    assert warm.get("jit.compiles", 0) == 0 == warm.get("compile.cache_miss", 0)
+
+
+_PERSISTENT_PROBE = """
+import jax, jax.numpy as jnp
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+from pypulsar_tpu.obs import telemetry
+x = jnp.ones(64)
+with telemetry.session() as t:
+    jax.jit(lambda v: (v * 3.0 - 1.0).sum())(x).block_until_ready()
+    c = t.counter_totals()
+print("COUNTS", int(c.get("jit.compiles", 0)),
+      int(c.get("jit.persistent_hits", 0)))
+"""
+
+
+def test_jit_compiles_includes_reads_from_the_persistent_cache(tmp_path):
+    """What jit.compiles counts on this JAX: programs that missed the
+    in-memory caches, built OR read from disk; jit.persistent_hits says
+    how many were reads (PERF.md states it so)."""
+    _require_spawn()
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    env["PYTHONPATH"] = (_REPO + os.pathsep
+                         + env.get("PYTHONPATH", "")).rstrip(os.pathsep)
+    seen = []
+    for _ in range(2):
+        proc = subprocess.run([sys.executable, "-c", _PERSISTENT_PROBE],
+                              env=env, capture_output=True, text=True,
+                              timeout=300)
+        assert proc.returncode == 0, proc.stderr[-1500:]
+        line = next(ln for ln in proc.stdout.splitlines()
+                    if ln.startswith("COUNTS"))
+        seen.append(tuple(int(v) for v in line.split()[1:]))
+    assert seen[0] == (1, 0)  # built
+    assert seen[1] == (1, 1)  # read from disk, still counted as a compile

@@ -448,3 +448,279 @@ def test_tlmsum_multi_trace_fleet_rollup(tmp_path, capsys):
     assert "=====" not in capsys.readouterr().out
     # one unreadable path among several: others still render, rc 1
     assert tlmsum_main([paths[0], str(tmp_path / "missing.jsonl")]) == 1
+
+
+# ---------------------------------------------------------------------------
+# the seam to the profiler, the jit.* counters, the leaves (PR 24)
+# ---------------------------------------------------------------------------
+
+
+def _host_events(logdir):
+    """{name: [(line index, start_ns, end_ns, stats)]} of the host plane of
+    the one trace under ``logdir``."""
+    import glob
+    import warnings
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(str(logdir) + "/plugins/profile/*/*.xplane.pb")
+    out = {}
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", DeprecationWarning)
+        data = ProfileData.from_file(path)
+        for plane in data.planes:
+            if not plane.name.startswith("/host:CPU"):
+                continue
+            for li, line in enumerate(plane.lines):
+                for ev in line.events:
+                    out.setdefault(ev.name, []).append(
+                        (li, ev.start_ns, ev.start_ns + ev.duration_ns,
+                         dict(ev.stats)))
+    return out
+
+
+def test_spans_land_in_the_profilers_trace(tmp_path):
+    """A span inside a session under jax.profiler.trace is an event of the
+    SAME .xplane.pb as the operations: scalar attributes as stats (those
+    attached mid-flight too), nested inside its parent on one clock, a
+    worker thread's span on a line of its own."""
+    import threading
+
+    import jax
+    import jax.numpy as jnp
+
+    def work():
+        with telemetry.span("seam.worker", bytes=10):
+            pass
+
+    with jax.profiler.trace(str(tmp_path)):
+        with telemetry.session():
+            with telemetry.span("seam.outer", a=1, tag="s", skipped=[1, 2]):
+                with telemetry.span("seam.inner", k=2.5) as sp:
+                    jnp.ones(8).sum().block_until_ready()
+                    sp.set(rows=7)
+                t = threading.Thread(target=work)
+                t.start()
+                t.join()
+    ev = _host_events(tmp_path)
+    (outer,), (inner,), (worker,) = (ev["seam.outer"], ev["seam.inner"],
+                                     ev["seam.worker"])
+    assert outer[3] == {"a": 1, "tag": "s"}  # scalars only
+    assert inner[3] == {"k": 2.5, "rows": 7}
+    assert worker[3] == {"bytes": 10}
+    assert outer[0] == inner[0] and outer[1] <= inner[1] <= inner[2] <= outer[2]
+    assert worker[0] != outer[0]
+    assert outer[1] <= worker[1] <= worker[2] <= outer[2]  # one clock
+
+
+def test_off_path_is_unchanged_and_obs_imports_no_jax():
+    """No session, recorder off: span() is still the shared null context;
+    and no module of obs/ imports jax as it loads (the seam and the device
+    snapshot reach it only once something else has: sys.modules)."""
+    import ast
+    import glob
+    import os
+
+    from pypulsar_tpu.obs import flightrec
+
+    flightrec.configure(0)
+    try:
+        assert telemetry.span("x", a=1) is telemetry._NULL_SPAN
+    finally:
+        flightrec.configure(None)
+    obs_dir = os.path.dirname(telemetry.__file__)
+    for path in glob.glob(os.path.join(obs_dir, "*.py")):
+        for node in ast.parse(open(path).read()).body:  # module level
+            names = ([a.name for a in node.names]
+                     if isinstance(node, ast.Import) else
+                     [node.module or ""] if isinstance(node, ast.ImportFrom)
+                     else [])
+            assert not any(n == "jax" or n.startswith("jax.")
+                           for n in names), (path, names)
+
+
+def test_jit_compiles_counted_at_the_source(tmp_path):
+    """A fresh plain-jit function inside a session raises jit.compiles by
+    one (with an event on the timeline) and a second call by none; outside
+    a session nothing is recorded; the JSONL states the counter even at 0."""
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.ones(16)  # the constant's own program compiles out here
+    f = jax.jit(lambda v: (v * 3.0 - 1.0).sum())
+    path = str(tmp_path / "jit.jsonl")
+    with telemetry.session(path) as tlm:
+        before = tlm.counter_totals().get("jit.compiles", 0)
+        f(x).block_until_ready()
+        once = tlm.counter_totals()
+        f(x).block_until_ready()
+        twice = tlm.counter_totals()
+        assert tlm.event_counts.get("jit.compile", 0) == 1
+    assert once["jit.compiles"] - before == 1
+    assert twice["jit.compiles"] == once["jit.compiles"]
+    assert once["jit.compile_ms"] > 0 and once["jit.trace_ms"] > 0
+    ev = next(r for r in _read_jsonl(path)
+              if r["type"] == "event" and r["name"] == "jit.compile")
+    assert ev["attrs"]["ms"] > 0 and ev["t"] >= 0
+    g = jax.jit(lambda v: (v * 5.0).sum())
+    g(x).block_until_ready()  # no session: the listener returns at once
+    quiet = str(tmp_path / "quiet.jsonl")
+    with telemetry.session(quiet) as tlm:
+        g(x).block_until_ready()
+        assert "jit.compiles" not in tlm.counter_totals()
+    final = [r for r in _read_jsonl(quiet) if r["type"] == "counters"][-1]
+    assert final["counters"]["jit.compiles"] == 0
+
+
+def test_ship_and_pull_record_span_and_bytes(tmp_path):
+    from pypulsar_tpu.ops import transfer
+
+    import jax.numpy as jnp
+
+    host = np.arange(12, dtype=np.uint8).reshape(3, 4)
+    path = str(tmp_path / "xfer.jsonl")
+    with telemetry.session(path) as tlm:
+        dev = transfer.ship(host)                    # native dtype: 12 B
+        wide = transfer.ship(host, jnp.float32)      # cast on the host: 48 B
+        again = transfer.ship(dev, jnp.float32)      # on device: not a ship
+        a, b = transfer.pull_host(dev, wide)
+        totals = tlm.counter_totals()
+    assert dev.dtype == jnp.uint8 and wide.dtype == jnp.float32
+    assert again.dtype == jnp.float32
+    np.testing.assert_array_equal(a, host)
+    np.testing.assert_array_equal(b, host.astype(np.float32))
+    assert totals["h2d.bytes"] == 12 + 48
+    assert totals["d2h.bytes"] == 12 + 48 and totals["d2h.pulls"] == 1
+    spans = [r for r in _read_jsonl(path) if r["type"] == "span"]
+    ships = [r for r in spans if r["name"] == "h2d.ship"]
+    assert [r["attrs"]["bytes"] for r in ships] == [12, 48]
+    (pull,) = [r for r in spans if r["name"] == "d2h.pull"]
+    assert pull["attrs"] == {"bytes": 60, "arrays": 2}
+    assert all(r.get("noagg") for r in ships + [pull])  # sink-only
+    # no session: same values, nothing recorded
+    np.testing.assert_array_equal(
+        transfer.pull_host(transfer.ship(host))[0], host)
+
+
+def _span_paths(path):
+    """{span name: set of parent names} of one telemetry JSONL."""
+    out = {}
+    for r in _read_jsonl(path):
+        if r["type"] == "span":
+            out.setdefault(r["name"], set()).add(r.get("parent"))
+    return out
+
+
+@pytest.fixture(scope="module")
+def toy_fil(tmp_path_factory):
+    from pypulsar_tpu.io.filterbank import write_filterbank
+
+    rng = np.random.RandomState(24)
+    nchan, nsamp = 16, 4096
+    data = (rng.randn(nsamp, nchan) * 8 + 64).clip(0, 255).astype(np.uint8)
+    fn = str(tmp_path_factory.mktemp("leaves") / "toy.fil")
+    write_filterbank(fn, dict(fch1=1500.0, foff=-4.0, nchans=nchan,
+                              tsamp=1e-3, nbits=8), data)
+    return fn
+
+
+def test_cli_rfifind_holds_every_leaf_under_its_root(toy_fil, tmp_path):
+    from pypulsar_tpu.cli import rfifind as cli_rfifind
+
+    tlm = str(tmp_path / "mask.jsonl")
+    assert cli_rfifind.main([toy_fil, "-o", str(tmp_path / "toy"),
+                             "-t", "0.512", "--telemetry", tlm]) == 0
+    paths = _span_paths(tlm)
+    assert paths["cli.rfifind"] == {None}
+    for leaf in ("io.open", "io.read", "rfifind.stage_block", "h2d.ship",
+                 "rfifind_block_stats", "rfifind.clip", "rfifind.write"):
+        assert paths[leaf] == {"cli.rfifind"}, (leaf, paths.get(leaf))
+    assert paths["d2h.pull"] == {"rfifind_block_stats"}
+    final = [r for r in _read_jsonl(tlm) if r["type"] == "counters"][-1]
+    assert final["counters"]["io.bytes_read"] == 16 * 4096  # as on disk
+    assert final["counters"]["h2d.bytes"] == 4 * 16 * 4096  # as float32
+    blocks = [r for r in _read_jsonl(tlm) if r["type"] == "span"
+              and r["name"] == "rfifind.stage_block"]
+    assert all(r["attrs"]["bytes"] >= 0 for r in blocks)
+
+
+def test_cli_sweep_holds_every_leaf_under_its_root(toy_fil, tmp_path):
+    from pypulsar_tpu.cli import sweep as cli_sweep
+
+    tlm = str(tmp_path / "sweep.jsonl")
+    assert cli_sweep.main([toy_fil, "-o", str(tmp_path / "toy"),
+                           "--lodm", "0", "--dmstep", "5", "--numdms", "4",
+                           "-s", "8", "--chunk", "1024",
+                           "--telemetry", tlm]) == 0
+    paths = _span_paths(tlm)
+    assert paths["cli.sweep"] == {None}
+    assert paths["io.open"] == paths["sweep.plan"] == {"cli.sweep"}
+    assert paths["sweep.finalize"] == {"sweep_step", "cli.sweep"}
+    assert paths["sweep.write"] == {"cli.sweep"}
+    assert paths["sweep_step"] == {"cli.sweep"}
+    assert paths["d2h.pull"] == {"device_wait+accumulate"}
+    # the reads and ships run on the ship-ahead worker: roots of its thread
+    assert paths["io.read"] == {None} and paths["h2d.ship"] == {None}
+    for name in ("block_source", "host_to_device", "dispatch_sweep_chunk",
+                 "device_wait+accumulate"):  # the loop's stages keep their names
+        assert paths[name] == {"sweep_step"}
+    recs = _read_jsonl(tlm)
+    final = [r for r in recs if r["type"] == "counters"][-1]["counters"]
+    assert final["io.bytes_read"] == final["h2d.bytes"] > 0  # 8-bit, native
+    (write,) = [r for r in recs if r["type"] == "span"
+                and r["name"] == "sweep.write"]
+    assert write["attrs"]["rows"] >= 0
+
+
+def test_trace_report_reads_spans_and_attrs_from_the_xplane(tmp_path):
+    """tools/trace_report.py decodes the .xplane.pb itself: same events as
+    jax's own reader, the span tree with exclusive seconds, and the
+    name-scope path of an operation taken from whichever stat holds it."""
+    import glob
+    import importlib.util
+    import os
+
+    import jax
+    import jax.numpy as jnp
+
+    spec = importlib.util.spec_from_file_location(
+        "trace_report", os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "tools", "trace_report.py"))
+    tr = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tr)
+
+    with jax.profiler.trace(str(tmp_path)):
+        with telemetry.session():
+            with telemetry.span("bench.step", step=0):
+                with telemetry.span("tr.root", n=3):
+                    with telemetry.span("tr.leaf", bytes=5):
+                        jnp.ones(8).sum().block_until_ready()
+                    with telemetry.span("tr.leaf", bytes=6):
+                        pass
+    (path,) = glob.glob(str(tmp_path) + "/plugins/profile/*/*.xplane.pb")
+    planes = tr.load(path)
+    mine = {name: evs for name, evs in _host_events(tmp_path).items()
+            if name.startswith("tr.")}
+    got = [e for p in planes if p["name"].startswith("/host:CPU")
+           for ln in p["lines"] for e in ln["events"]
+           if e[0].startswith("tr.")]
+    assert sorted(e[0] for e in got) == ["tr.leaf", "tr.leaf", "tr.root"]
+    root = next(e for e in got if e[0] == "tr.root")
+    assert root[3] == {"n": 3}
+    assert abs(root[1] - mine["tr.root"][0][1]) < 1e3  # same clock, ns
+    assert sorted(e[3]["bytes"] for e in got if e[0] == "tr.leaf") == [5, 6]
+    tree = tr.tree(planes, "tr.root", depth=2)
+    total, calls, self_s = tree[("tr.root",)]
+    leaf_total, leaf_calls, _ = tree[("tr.root", "tr.leaf")]
+    assert calls == 1 and leaf_calls == 2
+    assert total == pytest.approx(root[2] / 1e9)
+    assert 0 <= self_s <= total and leaf_total <= total
+    assert tr.window(planes)[0] is not None  # the bench.step annotation
+    assert tr.is_program_span("rfifind.stage_block")
+    assert tr.is_program_span("device_wait+accumulate")
+    assert not tr.is_program_span("PjitFunction(jit(_ingest_tc))")
+    assert not tr.is_program_span("TpuClient::LinearizeIntoImpl")
+    assert tr.scope_of({"tf_op": "jit(accel_stage)/accel.accel_stage/while/"
+                                 "body/accel.correlate/fft"}) == (
+        "accel.accel_stage", "accel.correlate")
+    assert tr.scope_of({"device_offset_ps": 5}) == (None, None)
