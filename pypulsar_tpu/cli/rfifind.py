@@ -72,24 +72,15 @@ def build_parser():
     return parser
 
 
-def open_data_file(fn: str):
-    from pypulsar_tpu.io import psrfits
-    from pypulsar_tpu.io.filterbank import FilterbankFile
-
-    if fn.endswith((".fits", ".sf")) or psrfits.is_PSRFITS(fn):
-        return psrfits.PsrfitsFile(fn)
-    return FilterbankFile(fn)
-
-
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    from pypulsar_tpu.io.opener import open_reader
     from pypulsar_tpu.obs import telemetry
     from pypulsar_tpu.ops.rfifind import rfifind
 
     with telemetry.session_from_flag(args.telemetry, tool="rfifind"), \
             telemetry.span("cli.rfifind", aggregate=False):
-        with telemetry.span("io.open"):  # format sniff + header
-            reader = open_data_file(args.infile)
+        reader = open_reader(args.infile)
         try:
             stats, flags, maskfn = rfifind(
                 reader, time=args.time, time_sigma=args.timesig,

@@ -23,16 +23,9 @@ import os
 
 import numpy as np
 
+from pypulsar_tpu.io.opener import open_reader
 from pypulsar_tpu.obs import telemetry
 from pypulsar_tpu.tune import knobs
-
-
-def _open_reader(fn: str):
-    from pypulsar_tpu.io import filterbank, psrfits
-
-    if psrfits.is_PSRFITS(fn):
-        return psrfits.PsrfitsFile(fn)
-    return filterbank.FilterbankFile(fn)
 
 
 def _engine_arg(value: str) -> str:
@@ -281,7 +274,7 @@ def _main_multi(args, ap, widths):
             ap.error("--ddplan requires --hidm")
         # plan geometry from the FIRST file's header so every host
         # executes the identical plan (survey files share geometry)
-        reader0 = _open_reader(files[0])
+        reader0 = open_reader(files[0])
         try:
             ddplan = _make_ddplan(reader0, args)
         finally:
@@ -308,7 +301,7 @@ def _main_multi(args, ap, widths):
         hits = staged.above_threshold(args.threshold)
         _write_cands(base + ".cands", hits)
         if args.write_dats and not args.ddplan:
-            reader = _open_reader(path)
+            reader = open_reader(path)
             try:
                 _write_dats_auto(base, reader, dms, args,
                             rfimask=rfimask)
@@ -321,7 +314,7 @@ def _main_multi(args, ap, widths):
     merged = dist.multi_host_sweep(
         files, dms, nsub=args.nsub, group_size=args.group_size,
         chunk_payload=args.chunk, mesh=mesh, topk_per_file=args.topk,
-        open_reader=_open_reader, ddplan=ddplan, downsamp=args.downsamp,
+        open_reader=open_reader, ddplan=ddplan, downsamp=args.downsamp,
         widths=widths, engine=args.engine, rfimask=rfimask,
         checkpoint_base=args.checkpoint,
         checkpoint_every=args.checkpoint_every, per_file=per_file)
@@ -433,7 +426,7 @@ def _main_timeshard(args, ap, widths):
                        f"{args.checkpoint}.step{i}.r{rank}.tmp.npz"):
                 if os.path.exists(fn):
                     os.remove(fn)
-    reader = _open_reader(infile)
+    reader = open_reader(infile)
     try:
         dt = float(reader.tsamp)
         if args.ddplan:
@@ -749,8 +742,7 @@ def _main_parsed(args, ap):
     # batch head: reader open (format sniff + header), then mask load and
     # tuned config (the block source and the sweep plan itself are built
     # per step: staged.sweep_flat / _run_step, same span name)
-    with telemetry.span("io.open"):
-        reader = _open_reader(args.infile)
+    reader = open_reader(args.infile)
     with telemetry.span("sweep.plan"):
         rfimask = _load_mask(args)
         _apply_tuning(args, reader)

@@ -83,10 +83,10 @@ def _geometry(args, ap):
     is the entry the pipeline run will hit."""
     if not args.file:
         return args.nchan, args.nsamp, "nbits%d" % args.nbits
-    from pypulsar_tpu.cli.sweep import _open_reader
+    from pypulsar_tpu.io.opener import open_reader
 
     try:
-        reader = _open_reader(args.file)
+        reader = open_reader(args.file)
         import numpy as np
 
         return (len(np.asarray(reader.frequencies)),

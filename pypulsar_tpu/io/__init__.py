@@ -9,6 +9,7 @@ from pypulsar_tpu.io.psrfits import (  # noqa: F401
     write_psrfits,
     unpack_4bit,
 )
+from pypulsar_tpu.io.opener import open_reader  # noqa: F401
 from pypulsar_tpu.io.rfimask import RfifindMask, write_mask  # noqa: F401
 from pypulsar_tpu.io.parfile import PsrPar, psr_par, write_par  # noqa: F401
 from pypulsar_tpu.io.prestopfd import PfdFile, make_pfd, fft_rotate  # noqa: F401

@@ -124,10 +124,18 @@ def _split_comment(valpart: str) -> str:
     return valpart
 
 
-def _read_header(f) -> Header:
+def _read_header(f, first: bytes) -> Header:
+    """Read one header from ``f``. Its first card must be ``first``
+    (``SIMPLE  =`` for the primary, ``XTENSION=`` for an extension):
+    anything else is refused after one block at most, not scanned card
+    by card to the end of the file for an ``END`` that is not there."""
     hdr = Header()
+    block = f.read(BLOCK)
+    if not block.startswith(first):
+        raise ValueError(
+            f"not a FITS header (first card is {block[:9]!r}, "
+            f"not {first.decode()!r})")
     while True:
-        block = f.read(BLOCK)
         if len(block) < BLOCK:
             raise ValueError("truncated FITS header")
         for i in range(0, BLOCK, CARDLEN):
@@ -140,6 +148,7 @@ def _read_header(f) -> Header:
             if card[8:10] != "= ":
                 continue
             hdr[key] = _parse_value(_split_comment(card[10:]))
+        block = f.read(BLOCK)
 
 
 def _fmt_value(value) -> str:
@@ -312,6 +321,7 @@ class HDUList:
     def __init__(self, hdus: Sequence[HDU]):
         self._hdus = list(hdus)
         self._file = None
+        self.header_bytes = 0  # set by open(): bytes read to build this
 
     def __iter__(self):
         return iter(self._hdus)
@@ -381,12 +391,28 @@ def update_primary_header(fn: str, updates: Dict[str, object]) -> None:
 
 
 def open(fn: str, mode: str = "readonly", memmap: bool = True) -> HDUList:  # noqa: A001
-    """Open a FITS file read-only; BINTABLE data are memmapped."""
+    """Open a FITS file read-only; BINTABLE data are memmapped. A file
+    whose first card is not ``SIMPLE  =`` is refused (ValueError) from
+    its first block. ``header_bytes`` on the result is what was read to
+    build it: headers only, data are skipped by seek."""
     f = builtins.open(fn, "rb")
+    try:
+        out = _read_hdus(f, fn)
+    except BaseException:
+        f.close()
+        raise
+    out._file = f
+    return out
+
+
+def _read_hdus(f, fn: str) -> HDUList:
     hdus: List[HDU] = []
     filesize = os.fstat(f.fileno()).st_size
+    header_bytes = 0
     while f.tell() < filesize:
-        hdr = _read_header(f)
+        start = f.tell()
+        hdr = _read_header(f, b"XTENSION=" if hdus else b"SIMPLE  =")
+        header_bytes += f.tell() - start
         if hdr.get("XTENSION", "").strip() == "BINTABLE":
             nrow_bytes = int(hdr["NAXIS1"])
             nrows = int(hdr["NAXIS2"])
@@ -427,5 +453,5 @@ def open(fn: str, mode: str = "readonly", memmap: bool = True) -> HDUList:  # no
             name = str(hdr.get("EXTNAME", "PRIMARY")).strip() or "PRIMARY"
             hdus.append(HDU(hdr, name=name))
     out = HDUList(hdus)
-    out._file = f
+    out.header_bytes = header_bytes
     return out

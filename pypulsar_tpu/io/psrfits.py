@@ -91,16 +91,26 @@ _UNPACKERS = {4: unpack_4bit, 2: unpack_2bit, 1: unpack_1bit}
 def is_PSRFITS(fn: str) -> bool:
     """True if the file looks like PSRFITS: FITSTYPE == PSRFITS or a
     SUBINT extension present (reference :577-591)."""
+    return sniff_PSRFITS(fn)[0]
+
+
+def sniff_PSRFITS(fn: str):
+    """``(is_PSRFITS(fn), header bytes read to say so)``. Headers only:
+    both codecs refuse a file that does not begin ``SIMPLE  =`` from its
+    first block and skip every HDU's data by seek. The byte count is the
+    in-tree codec's (``fitsio.HDUList.header_bytes``); astropy reports
+    none, and a file refused mid-walk counts 0."""
     if not os.path.isfile(fn):
-        return False
+        return False, 0
     try:
         with _fits().open(fn, mode="readonly", memmap=True) as hdus:
+            nread = getattr(hdus, "header_bytes", 0)
             primary = hdus[0].header
             if str(primary.get("FITSTYPE", "")).upper().startswith("PSRFITS"):
-                return True
-            return any(h.name == "SUBINT" for h in hdus)
+                return True, nread
+            return any(h.name == "SUBINT" for h in hdus), nread
     except Exception:
-        return False
+        return False, 0
 
 
 def DATEOBS_to_MJD(dateobs: str):

@@ -67,6 +67,12 @@ def load_records(path: str) -> Iterable[dict]:
             yield rec
 
 
+# counters that tlmsum totals as bytes (with a rate over the wall): the
+# wire's (``h2d.bytes``, ``d2h.bytes``) and the disk's, where the bytes
+# read to decide a file's format stand beside the bytes of data read
+_BYTE_COUNTER_ENDS = (".bytes", "io.bytes_read", "io.sniff_bytes")
+
+
 def _fmt_bytes(n: float) -> str:
     for unit, div in (("GB", 1e9), ("MB", 1e6), ("kB", 1e3)):
         if abs(n) >= div:
@@ -456,9 +462,9 @@ def render(s: TraceSummary, file: TextIO, top: int = 20) -> None:
               f"{ent['n']:>4d} runs  burns>80%: {ent['burns']:<4d} "
               f"worst {100.0 * ent['worst_frac']:5.1f}%{flag}")
     byte_counters = {k: v for k, v in s.counters.items()
-                     if k.endswith(".bytes")}
+                     if k.endswith(_BYTE_COUNTER_ENDS)}
     other_counters = {k: v for k, v in s.counters.items()
-                      if not k.endswith(".bytes")}
+                      if k not in byte_counters}
     if byte_counters:
         p("#\n# transfer totals:")
         for name, v in sorted(byte_counters.items()):

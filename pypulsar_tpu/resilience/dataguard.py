@@ -53,6 +53,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from pypulsar_tpu.io.errors import DataFormatError
+from pypulsar_tpu.io.opener import SNIFF_LEN, format_of
 from pypulsar_tpu.obs import telemetry
 from pypulsar_tpu.resilience import faultinject
 from pypulsar_tpu.tune import knobs
@@ -314,21 +315,15 @@ def validate_input(path: str) -> Optional[Dict]:
         return None
     try:
         with open(path, "rb") as f:
-            magic = f.read(16)
+            magic = f.read(SNIFF_LEN)
     except OSError:
         return None
-    if magic.startswith(b"SIMPLE"):
+    fmt = format_of(magic)
+    if fmt == "fits":
         return _validate_psrfits(path)
-    if _sniff_sigproc(magic):
+    if fmt == "sigproc":
         return _validate_filterbank(path)
     return None
-
-
-def _sniff_sigproc(magic: bytes) -> bool:
-    """True when the leading bytes carry a SIGPROC HEADER_START marker —
-    the cheap is-it-claiming-to-be-ours test (a failing parse after a
-    positive sniff is a data error, not an unrecognized format)."""
-    return magic[4:16] == b"HEADER_START"
 
 
 def _validate_filterbank(path: str) -> Dict:

@@ -1795,13 +1795,13 @@ class FleetScheduler:
         plus the fleet config's grid — everything a warmer needs to
         rebuild the shapes its stage will dispatch. None when the
         header cannot be read (the stage machinery owns that error)."""
-        from pypulsar_tpu.cli.sweep import _open_reader
+        from pypulsar_tpu.io.opener import open_reader
 
         import numpy as np
 
         cfg = self.cfg
         try:
-            r = _open_reader(self.obs[i].infile)
+            r = open_reader(self.obs[i].infile)
             try:
                 freqs = np.asarray(r.frequencies, dtype=np.float64)
                 tsamp = float(r.tsamp)

@@ -624,12 +624,28 @@ def toy_fil(tmp_path_factory):
     return fn
 
 
-def test_cli_rfifind_holds_every_leaf_under_its_root(toy_fil, tmp_path):
+def _open_by_rfifind(fil, tmp_path):
     from pypulsar_tpu.cli import rfifind as cli_rfifind
 
-    tlm = str(tmp_path / "mask.jsonl")
-    assert cli_rfifind.main([toy_fil, "-o", str(tmp_path / "toy"),
+    tlm = str(tmp_path / "tlm.jsonl")
+    assert cli_rfifind.main([fil, "-o", str(tmp_path / "toy"),
                              "-t", "0.512", "--telemetry", tlm]) == 0
+    return tlm, 1
+
+
+def _open_by_sweep(fil, tmp_path):
+    from pypulsar_tpu.cli import sweep as cli_sweep
+
+    tlm = str(tmp_path / "tlm.jsonl")
+    assert cli_sweep.main([fil, "-o", str(tmp_path / "toy"),
+                           "--lodm", "0", "--dmstep", "5", "--numdms", "4",
+                           "-s", "8", "--chunk", "1024",
+                           "--telemetry", tlm]) == 0
+    return tlm, 1
+
+
+def test_cli_rfifind_holds_every_leaf_under_its_root(toy_fil, tmp_path):
+    tlm, _ = _open_by_rfifind(toy_fil, tmp_path)
     paths = _span_paths(tlm)
     assert paths["cli.rfifind"] == {None}
     for leaf in ("io.open", "io.read", "rfifind.stage_block", "h2d.ship",
@@ -645,13 +661,7 @@ def test_cli_rfifind_holds_every_leaf_under_its_root(toy_fil, tmp_path):
 
 
 def test_cli_sweep_holds_every_leaf_under_its_root(toy_fil, tmp_path):
-    from pypulsar_tpu.cli import sweep as cli_sweep
-
-    tlm = str(tmp_path / "sweep.jsonl")
-    assert cli_sweep.main([toy_fil, "-o", str(tmp_path / "toy"),
-                           "--lodm", "0", "--dmstep", "5", "--numdms", "4",
-                           "-s", "8", "--chunk", "1024",
-                           "--telemetry", tlm]) == 0
+    tlm, _ = _open_by_sweep(toy_fil, tmp_path)
     paths = _span_paths(tlm)
     assert paths["cli.sweep"] == {None}
     assert paths["io.open"] == paths["sweep.plan"] == {"cli.sweep"}
@@ -670,6 +680,55 @@ def test_cli_sweep_holds_every_leaf_under_its_root(toy_fil, tmp_path):
     (write,) = [r for r in recs if r["type"] == "span"
                 and r["name"] == "sweep.write"]
     assert write["attrs"]["rows"] >= 0
+
+
+def _open_by_warm_pool(fil, tmp_path):
+    """The survey's warm pool reads each observation's geometry through
+    the same opener, on its own thread: its open is in the trace too."""
+    from pypulsar_tpu.survey.dag import SurveyConfig
+    from pypulsar_tpu.survey.scheduler import FleetScheduler
+    from pypulsar_tpu.survey.state import Observation
+
+    obs = [Observation(f"toy{i}", fil, str(tmp_path / f"toy{i}"))
+           for i in range(2)]
+    sched = FleetScheduler(obs, SurveyConfig(numdms=4))
+    tlm = str(tmp_path / "tlm.jsonl")
+    with telemetry.session(tlm):
+        for i in range(len(obs)):
+            geo = sched._obs_geometry(i)
+            assert geo["n_samples"] == 4096 and len(geo["freqs"]) == 16
+    return tlm, len(obs)
+
+
+@pytest.mark.parametrize("opens", [_open_by_rfifind, _open_by_sweep,
+                                   _open_by_warm_pool],
+                         ids=["rfifind", "sweep", "warm_pool"])
+def test_every_open_is_one_io_open_span(toy_fil, tmp_path, opens):
+    """Exactly one ``io.open`` span per file opened, the opener's own,
+    with the bytes it read to decide the format: 16 for a SIGPROC file
+    (never a pass over the file), summed in ``io.sniff_bytes``."""
+    tlm, n_opened = opens(toy_fil, tmp_path)
+    recs = _read_jsonl(tlm)
+    spans = [r for r in recs if r["type"] == "span"
+             and r["name"] == "io.open"]
+    assert len(spans) == n_opened
+    assert all(r["attrs"] == {"format": "sigproc", "sniff_bytes": 16}
+               for r in spans)
+    final = [r for r in recs if r["type"] == "counters"][-1]["counters"]
+    assert final["io.sniff_bytes"] == 16 * n_opened
+
+
+def test_tlmsum_totals_disk_bytes_beside_the_wire(toy_fil, tmp_path):
+    import io
+
+    tlm, _ = _open_by_sweep(toy_fil, tmp_path)
+    out = io.StringIO()
+    summarize.render(summarize.summarize(summarize.load_records(tlm)), out)
+    text = out.getvalue()
+    totals = text.split("# transfer totals:")[1].split("# counters:")[0]
+    for name in ("io.sniff_bytes", "io.bytes_read", "h2d.bytes",
+                 "d2h.bytes"):
+        assert name in totals, (name, totals)
 
 
 def test_trace_report_reads_spans_and_attrs_from_the_xplane(tmp_path):
