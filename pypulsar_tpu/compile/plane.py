@@ -17,7 +17,7 @@ three caches:
    switch.
 2. **In-process AOT executable registry**: per-wrapper executables
    from ``jit(f).lower(...).compile()`` keyed by (stage, static
-   argument values, dynamic leaf shapes/dtypes, default device, jax
+   argument values, dynamic leaf shapes/dtypes/shardings, default device, jax
    version, device kind, resolved tuned-config digest). A repeat
    geometry skips tracing entirely — ``compile.cache_hit`` — and a
    tuned config change (round 17) keys a *different* entry, so tuning
@@ -31,11 +31,11 @@ three caches:
 
 Anything the AOT path cannot key faithfully — tracer inputs (a
 plane-wrapped fn called under an outer trace), variadic signatures,
-multi-device arrays from a mesh context — or that refuses to lower, or
+multi-device arrays whose sharding names no mesh — or that refuses to lower, or
 whose arguments the compiled executable rejects before it runs, goes to
-the held plain ``jax.jit`` and counts ``compile.aot_fallback``; factory
-sites that close over meshes/shardings opt out wholesale with
-``aot=False`` (the plane still owns their telemetry). An exception
+the held plain ``jax.jit`` and counts ``compile.aot_fallback``; a site
+can opt out wholesale with
+``aot=False`` (the plane still owns its telemetry). An exception
 while a compiled program EXECUTES (a device error, an OOM) is not a
 keying problem and propagates; a cache directory that cannot be set up
 raises.
@@ -251,7 +251,7 @@ def _leaf_key(x: Any) -> Tuple:
             except Exception:
                 raise _Unkeyable("unreadable placement")
             if len(devs) != 1:
-                raise _Unkeyable("multi-device input")
+                return ("a", tuple(shape), str(dtype), _sharding_key(x))
             d = str(next(iter(devs)))
             # an array already sitting where jit would commit a host
             # input keys like a host input — so a ShapeDtypeStruct
@@ -442,6 +442,21 @@ def _device_kind() -> str:
         k = jax.devices()[0].device_kind  # psrlint: ignore[PL002] -- cache-key metadata (hardware KIND, not a compute placement); no lease involved
         _kind_cache["kind"] = k
     return k
+
+
+def _sharding_key(x: Any) -> Tuple:
+    """Placement of a committed multi-device array (a gang lease's
+    sharded batch): the device ids of its mesh IN ORDER plus the
+    partition spec — what the lowering reads off the argument, so the
+    executable compiled under this key accepts exactly the arrays that
+    key to it, and a gang on chips {2,3} never finds the executable
+    lowered for {0,1}. A sharding that names no mesh is unkeyable."""
+    s = x.sharding
+    mesh, spec = getattr(s, "mesh", None), getattr(s, "spec", None)
+    if mesh is None or spec is None:
+        raise _Unkeyable(f"multi-device input under {type(s).__name__}")
+    return ("mesh", tuple(int(d.id) for d in mesh.devices.flat),
+            tuple(mesh.axis_names), tuple(mesh.devices.shape), str(spec))
 
 
 def plane_jit(fn: Optional[Callable] = None, *, static_argnames=(),

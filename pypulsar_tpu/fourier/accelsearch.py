@@ -421,10 +421,10 @@ def _make_stage_runner_batch(segw: int, Z: int, Wn: int, topk: int,
         return shd(spec_pad2, tfs, idxs,
                    jnp.int32(top_lo), jnp.int32(top_hi), thresh, seg_ids)
 
-    # sharded factory: the mesh closure makes AOT keying unsound, so the
-    # plane holds plain-jit dispatch (aot=False) and keeps the telemetry
-    return plane_jit(run_sharded, stage="accel", name="accel_stage_sharded",
-                     aot=False)
+    # one wrapper per mesh (this factory is memoised on mesh_devs): its
+    # AOT executables belong to exactly those chips, and the sharded
+    # batch keys by its own mesh and partition spec besides
+    return plane_jit(run_sharded, stage="accel", name="accel_stage_sharded")
 
 
 def _detect_impl(accum, thresh, k: int):

@@ -511,6 +511,23 @@ def render(s: TraceSummary, file: TextIO, top: int = 20) -> None:
                 # limit evicted this lease from the pool mid-fleet
                 line += "  [QUARANTINED]"
             p(line)
+    # lease roll-up: what the fleet scheduler's pool offered (chips x
+    # its wall) against what stages held leases on (k x each lease's
+    # wall), by stage — a gang that waits on one-chip stages shows here
+    leased = s.counters.get("survey.lease_chip_s")
+    if leased is not None:
+        line = f"#\n# leases: {leased:.2f} chip-s leased"
+        pool = s.counters.get("survey.pool_chip_s")
+        if pool:
+            line += (f" of {pool:.2f} offered "
+                     f"({100.0 * (1.0 - leased / pool):.1f}% of the pool "
+                     f"unleased)")
+        p(line + f"  lease wait "
+                 f"{s.counters.get('survey.lease_wait_s', 0.0):.3f}s")
+        prefix = "survey.lease_chip_s."
+        for k, v in sorted(s.counters.items()):
+            if k.startswith(prefix):
+                p(f"#   {k[len(prefix):]:<10s} {v:9.2f} chip-s")
     # per-host roll-up (round 18): the multi-host fleet's utilization
     # and membership churn — busy seconds per host from the scheduler's
     # host-stamped stage spans, adoption/cede/strike counts per host

@@ -465,10 +465,10 @@ def dedisperse_series_chunk(data, stage1_bins, stage2_bins, nsub,
                                   out_len, slack2, engine)
 
 
-@plane_jit(static_argnames=("nsub", "out_len", "slack2", "engine"),
-           stage="sweep")
-def _dedisperse_series_jit(data, stage1_bins, stage2_bins, nsub,
-                           out_len: int, slack2: int, engine="gather"):
+def _dedisperse_series_impl(data, stage1_bins, stage2_bins, nsub,
+                            out_len: int, slack2: int, engine="gather"):
+    """Traceable body of :func:`dedisperse_series_chunk` (shared by the
+    single-device program and the mesh-sharded factory)."""
     engine = resolve_engine(engine)
     if engine == "fourier":
         from pypulsar_tpu.ops.fourier_dedisperse import (
@@ -499,6 +499,12 @@ def _dedisperse_series_jit(data, stage1_bins, stage2_bins, nsub,
     return ts.reshape(G * g, out_len)
 
 
+_dedisperse_series_jit = plane_jit(
+    _dedisperse_series_impl,
+    static_argnames=("nsub", "out_len", "slack2", "engine"),
+    stage="sweep", name="_dedisperse_series_jit")
+
+
 def make_sharded_sweep_chunk(mesh: Mesh, nsub, out_len, slack2, widths,
                              stat_len, engine="gather"):
     """Chunk sweep with trial groups sharded over the mesh 'dm' axis.
@@ -518,25 +524,32 @@ def make_sharded_sweep_chunk(mesh: Mesh, nsub, out_len, slack2, widths,
 
         return make_sharded_tree_sweep_chunk(mesh, out_len, tuple(widths),
                                              stat_len)
-    impl = partial(
-        _sweep_chunk_impl,
-        nsub=nsub,
-        out_len=out_len,
-        slack2=slack2,
-        widths=widths,
-        stat_len=stat_len,
-        engine=engine,
-    )
+    return _sharded_sweep_chunk(mesh, nsub, out_len, slack2, tuple(widths),
+                                stat_len, engine)
+
+
+@functools.lru_cache(maxsize=64)
+def _sharded_sweep_chunk(mesh, nsub, out_len, slack2, widths, stat_len,
+                         engine):
+    """One plane wrapper per (mesh, chunk geometry): the wrapper owns the
+    AOT executables of its mesh, so a second observation on the same
+    gang finds them (``compile.cache_hit``) and a gang on other chips
+    holds its own."""
+
+    # a closure, not a partial: the plane binds the call against the
+    # signature, and only the three arrays are arguments
+    def impl(data, stage1_bins, stage2_bins):
+        return _sweep_chunk_impl(data, stage1_bins, stage2_bins, nsub,
+                                 out_len, slack2, widths, stat_len,
+                                 engine=engine)
+
     fn = jax.shard_map(
         impl,
         mesh=mesh,
         in_specs=(P(), P("dm"), P("dm")),
         out_specs=P("dm"),
     )
-    # mesh-closing factory: plane-wrapped for telemetry, aot=False (AOT
-    # keying across meshes is unsound; XLA's persistent cache still hits)
-    return plane_jit(fn, stage="sweep", name="sweep_sharded_chunk",
-                     aot=False)
+    return plane_jit(fn, stage="sweep", name="sweep_sharded_chunk")
 
 
 def make_sharded_series_chunk(mesh: Mesh, nsub, out_len, slack2,
@@ -556,9 +569,14 @@ def make_sharded_series_chunk(mesh: Mesh, nsub, out_len, slack2,
         )
 
         return make_sharded_tree_series_chunk(mesh, out_len)
+    return _sharded_series_chunk(mesh, nsub, out_len, slack2, engine)
 
+
+@functools.lru_cache(maxsize=64)
+def _sharded_series_chunk(mesh, nsub, out_len, slack2, engine):
+    """Memoised like :func:`_sharded_sweep_chunk`."""
     def impl(data, stage1_bins, stage2_bins):
-        return dedisperse_series_chunk(data, stage1_bins, stage2_bins,
+        return _dedisperse_series_impl(data, stage1_bins, stage2_bins,
                                        nsub, out_len, slack2, engine)
 
     fn = jax.shard_map(
@@ -567,8 +585,7 @@ def make_sharded_series_chunk(mesh: Mesh, nsub, out_len, slack2,
         in_specs=(P(), P("dm"), P("dm")),
         out_specs=P("dm"),
     )
-    return plane_jit(fn, stage="sweep", name="series_sharded_chunk",
-                     aot=False)
+    return plane_jit(fn, stage="sweep", name="series_sharded_chunk")
 
 
 def make_sharded_sweep_chunk_2d(

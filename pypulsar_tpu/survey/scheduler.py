@@ -1582,43 +1582,63 @@ class FleetScheduler:
 
     def _run_device_task(self, task: _Task) -> None:
         """One device-lane execution: decide the gang shape, take the
-        lease(s), record the placement decision, run pinned."""
-        obs = self.obs[task.obs_i]
+        lease(s), account it, run :meth:`_run_leased` under it."""
         k, reason = self._gang_size(task)
+        t_ask = time.perf_counter()
         ids = self._acquire_devices(k)
         if ids is None:  # fleet unwinding while we waited
             return
+        t_lease = time.perf_counter()
+        wait_s = t_lease - t_ask
+        telemetry.counter("survey.lease_wait_s", wait_s)
         if len(ids) < k:  # pool shrank while waiting: gang shrinks too
             k = len(ids)
             reason += f"; shrunk to {k} while waiting"
         task.last_dev_ids = list(ids)
         task.last_real_dev_ids = None
         try:
-            telemetry.event("survey.gang_decision", obs=obs.name,
-                            stage=task.stage.name, k=k, chips=ids,
-                            reason=reason)
-            trace = self._traces[task.obs_i]
-            if trace is not None:
-                trace.event("survey.gang_decision", stage=task.stage.name,
-                            k=k, chips=ids, reason=reason)
-            gang_devs = self._jax_gang(ids)
-            if gang_devs is not None:
-                task.last_real_dev_ids = [
-                    int(getattr(d, "id", i))
-                    for i, d in zip(ids, gang_devs)]
-            mates = self._claim_lane_mates(task, k)
-            if gang_devs is not None:
-                import jax
-
-                from pypulsar_tpu.parallel.mesh import device_lease
-
-                with jax.default_device(gang_devs[0]), \
-                        device_lease(gang_devs):
-                    self._run_lane(task, mates, k, ids, pinned=True)
-            else:
-                self._run_lane(task, mates, k, ids, pinned=False)
+            # the lease as the pool sees it: k chips held from grant to
+            # release, whatever the stage does with them (sink-only, the
+            # stage span inside it carries the aggregated wall)
+            with telemetry.span("survey.lease", aggregate=False,
+                                stage=task.stage.name, k=k, chips=ids,
+                                wait_s=round(wait_s, 6)):
+                self._run_leased(task, k, ids, reason)
         finally:
+            chip_s = k * (time.perf_counter() - t_lease)
+            telemetry.counter("survey.lease_chip_s", chip_s)
+            telemetry.counter(f"survey.lease_chip_s.{task.stage.name}",
+                              chip_s)
             self._release_devices(ids)
+
+    def _run_leased(self, task: _Task, k: int, ids: List[int],
+                    reason: str) -> None:
+        """The body of one lease: record the decision, bind the chips,
+        run the lane."""
+        obs = self.obs[task.obs_i]
+        telemetry.event("survey.gang_decision", obs=obs.name,
+                        stage=task.stage.name, k=k, chips=ids,
+                        reason=reason)
+        trace = self._traces[task.obs_i]
+        if trace is not None:
+            trace.event("survey.gang_decision", stage=task.stage.name,
+                        k=k, chips=ids, reason=reason)
+        gang_devs = self._jax_gang(ids)
+        if gang_devs is not None:
+            task.last_real_dev_ids = [
+                int(getattr(d, "id", i))
+                for i, d in zip(ids, gang_devs)]
+        mates = self._claim_lane_mates(task, k)
+        if gang_devs is not None:
+            import jax
+
+            from pypulsar_tpu.parallel.mesh import device_lease
+
+            with jax.default_device(gang_devs[0]), \
+                    device_lease(gang_devs):
+                self._run_lane(task, mates, k, ids, pinned=True)
+        else:
+            self._run_lane(task, mates, k, ids, pinned=False)
 
     def _claim_lane_mates(self, task: _Task, k: int) -> List[_Task]:
         """Round 24 batch lanes.  A single-chip lease taken for a
@@ -1821,6 +1841,30 @@ class FleetScheduler:
             fold_nbins=cfg.fold_nbins, fold_npart=cfg.fold_npart,
             fold_batch=cfg.fold_batch)
 
+    def _warm_placement(self):
+        """The placement of the lowest healthy chip, for the warm pool's
+        thread: with several leases every stage thread is pinned to its
+        lease's chip and the plane keys executables by that placement, so
+        a warmer running unpinned would compile programs no stage ever
+        finds. Only that chip's lane finds what the pool compiles (S7);
+        with one lease nothing is pinned, here as there."""
+        import contextlib
+
+        healthy = self._healthy_ids()
+        devs = self._jax_gang([min(healthy)]) if healthy else None
+        if devs is None:
+            return contextlib.nullcontext()
+        import jax
+
+        return jax.default_device(devs[0])
+
+    def _would_gang(self, obs_i: int, stage_name: str) -> bool:
+        """True when a lease taken now would span several chips for this
+        stage: its sharded programs belong to the mesh of that lease,
+        which the one-chip warmers cannot lower for."""
+        task = self._tasks.get((obs_i, stage_name))
+        return task is not None and self._gang_size(task)[0] > 1
+
     def _warmpool_loop(self) -> None:
         """Host-pool precompile daemon: while the devices chew on the
         current observations, AOT-compile the next ready observation's
@@ -1862,10 +1906,13 @@ class FleetScheduler:
             t_rel = time.perf_counter() - self._t0
             t0 = time.perf_counter()
             n = 0
-            with telemetry.span("survey.precompile", obs=obs.name):
+            with telemetry.span("survey.precompile", obs=obs.name), \
+                    self._warm_placement():
                 for stage in warmable_stages():
                     if self._stop:
                         break
+                    if self._would_gang(target, stage):
+                        continue
                     n += warm_stage(stage, **geo)
             dur = time.perf_counter() - t0
             telemetry.counter("survey.precompiled", n)
@@ -1971,6 +2018,10 @@ class FleetScheduler:
                 self._warm_thread = None
             self._write_health_json()
             self.result.wall = time.perf_counter() - self._t0
+            # what the pool offered: every chip for the scheduler's wall
+            # (survey.lease_chip_s over this is the leased share)
+            telemetry.counter("survey.pool_chip_s",
+                              self.devices * self.result.wall)
             for m in self._manifests:
                 if m is not None:
                     m.close()
