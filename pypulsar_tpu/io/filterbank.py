@@ -32,8 +32,11 @@ class FilterbankFile:
     """
 
     # iter_blocks yields (startsamp, [time, chan] ndarray) blocks stepping
-    # by block_size — the contract _ReaderSource's streaming fast path
-    # requires (fbobs.iter_blocks has different semantics and no marker)
+    # by block_size — the contract the raw streaming paths require
+    # (parallel/staged._ReaderSource, ops.rfifind.rfifind: blocks ship as
+    # the file holds them and the device unpacks; fbobs.iter_blocks has
+    # different semantics and no marker, PsrfitsFile no iter_blocks: both
+    # unpack on the host)
     BLOCK_ITER_ARRAYS = True
 
     def __init__(self, filfn: str):
@@ -56,8 +59,9 @@ class FilterbankFile:
             # reference formats/psrfits.py:48-50). Raw blocks stay PACKED
             # so a 4-bit file ships half an 8-bit file's bytes over the
             # host->device wire (the streamed sweep's measured
-            # bottleneck); unpack happens on device (parallel/staged.
-            # _ingest_tc) or on host in get_samples.
+            # bottleneck); unpack happens on device (ops/ingest.
+            # _ingest_tc: the sweep's block source and the mask stage)
+            # or on host in get_samples / get_spectra.
             # (validate_header already rejected anything outside
             # {1, 2, 4, 8, 16, 32})
             if self.nchans % (8 // nbits):
@@ -149,7 +153,11 @@ class FilterbankFile:
 
     def get_samples(self, startsamp: int, N: int) -> np.ndarray:
         """Raw [time, chan] block as float32 (no Spectra wrapper);
-        sub-byte files are unpacked on host here."""
+        sub-byte files are unpacked on host here, and every sample
+        widens to 4 bytes on the host (16x a 2-bit file's bytes). The
+        random-access path: get_spectra, waterfaller, the tests' host
+        reference. The streaming readers of a whole file (sweep, mask
+        stage) take ``iter_blocks(raw=True)`` and unpack on the device."""
         data = self._read_raw_block(startsamp, N)
         if self.nbits < 8:
             from pypulsar_tpu.io.psrfits import _UNPACKERS
@@ -195,7 +203,8 @@ class FilterbankFile:
         where the f32 cast is exact and fused — a quarter of the bytes
         on the host->device link.
         Sub-byte files yield PACKED [time, nchans*nbits//8] uint8 blocks
-        when ``raw`` (device-side unpack in parallel/staged._ingest_tc:
+        when ``raw`` (device-side unpack in ops/ingest._ingest_tc, for
+        the sweep's block source and for ops.rfifind's mask stage alike:
         a 4-bit file ships HALF the 8-bit bytes, VERDICT r4 item 2) and
         host-unpacked float32 [time, chan] otherwise.
 

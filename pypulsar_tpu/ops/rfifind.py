@@ -45,6 +45,7 @@ import numpy as np
 from pypulsar_tpu.obs import telemetry
 from pypulsar_tpu.ops import transfer
 from pypulsar_tpu.ops.fourier_dedisperse import fourier_chunk_len
+from pypulsar_tpu.ops.ingest import _ingest_tc, _timed_reads, ingest_nbits
 
 __all__ = [
     "RfiStats",
@@ -248,14 +249,22 @@ def mask_products(
 
 
 def _iter_file_blocks(reader, samples_per_read: int):
-    """Yield [nchan, n] LOW-frequency-first blocks from a filterbank /
-    PSRFITS / multi-file (fbobs) reader — the .mask channel convention
+    """Yield [nchan, n] LOW-frequency-first HOST blocks from a PSRFITS /
+    multi-file (fbobs) / filterbank reader — the .mask channel convention
     (PRESTO reorders every band ascending on read, so mask channel 0 is
     always the lowest frequency regardless of on-disk order;
-    io/rfimask.py docstring). ``get_samples`` (filterbank) and
+    io/rfimask.py docstring). Everything here is unpacked and widened to
+    float32 by the reader, on the host: ``get_samples`` (filterbank) and
     ``get_sample_interval`` (fbobs) return on-disk order, flipped here
     when the band is descending; the ``get_spectra`` fallback (PSRFITS)
-    delivers high-frequency-first Spectra, always flipped."""
+    delivers high-frequency-first Spectra, always flipped.
+
+    ``rfifind()`` takes this path for readers WITHOUT the
+    ``BLOCK_ITER_ARRAYS`` marker (PsrfitsFile, FilterbankObs). A reader
+    with it (FilterbankFile: every SIGPROC input) never comes here: its
+    blocks ship as the file holds them and the device unpacks
+    (ops/ingest.py). The filterbank branch stays as the host reference
+    the parity tests hold that path to."""
     total = int(getattr(reader, "nspec", None)
                 or reader.number_of_samples)
     get_samples = getattr(reader, "get_samples", None)
@@ -317,6 +326,16 @@ def rfifind(
     trailing partial interval shorter than half an interval is dropped
     (it has too few samples for stable statistics), otherwise it is
     padded by repeating its last sample into a full interval.
+
+    Where the samples are unpacked: a reader with the
+    ``BLOCK_ITER_ARRAYS`` marker (FilterbankFile: every SIGPROC input,
+    any bit depth) is read through ``iter_blocks(raw=True)``; its blocks
+    go to the device as the file holds them and ``ops/ingest._ingest_tc``
+    unpacks, transposes, widens and flips them there (counter
+    ``rfifind.raw_blocks``). Readers without it (PsrfitsFile,
+    FilterbankObs) and array input are staged as float32 on the host
+    (``_iter_file_blocks``). Both hand ``_block_stats_impl`` the same
+    float32 block, so the statistics agree bit for bit.
     """
     if isinstance(source, np.ndarray) or hasattr(source, "ndim"):
         if dt is None:
@@ -349,42 +368,71 @@ def rfifind(
 
     pts = max(int(round(time / dt)), 2)
     means, stds, maxpows = [], [], []
-    carry = np.zeros((nchan, 0), dtype=np.float32)
+    # a reader with the marker hands out its blocks as the file holds them
+    # ([time, row] in the file's dtype, sub-byte samples packed): they are
+    # staged and shipped so, and unpacked, transposed, widened and flipped
+    # on the device. Everything else is staged as [chan, time] float32.
+    raw = blocks is None and getattr(source, "BLOCK_ITER_ARRAYS", False)
+    taxis = 0 if raw else 1  # the time axis of a staged block
+    if raw:
+        flip = len(f) > 1 and f[0] > f[-1]  # .mask: channel 0 = lowest
+        nbits = ingest_nbits(source)
+    carry = None  # samples past the last whole interval, once a block came
 
     def consume(chunk, final=False):
         nonlocal carry
-        # float32 cast (materializes the reader's transposed / flipped
-        # view) + carry concatenate + tail pad: the block as it ships
+        # the block as it ships: carry + chunk along the time axis, then
+        # the tail pad. Host path: the float32 cast and the concatenate
+        # materialize the reader's transposed / flipped view. Raw path: a
+        # row-append of the file's own bytes, and with nothing carried
+        # (every read but the last) the reader's block itself, uncopied.
         with telemetry.span("rfifind.stage_block") as sp:
-            buf = np.concatenate([carry, np.asarray(chunk, np.float32)],
-                                 axis=1)
-            nint = buf.shape[1] // pts
+            if not raw:
+                chunk = np.asarray(chunk, np.float32)
+            if raw and (carry is None or not len(carry)):
+                buf = chunk
+            else:
+                buf = np.concatenate(
+                    [chunk] if carry is None else [carry, chunk], axis=taxis)
+            nint = buf.shape[taxis] // pts
             if final:
-                tail = buf.shape[1] - nint * pts
+                tail = buf.shape[taxis] - nint * pts
                 if tail >= pts // 2:
-                    pad = np.repeat(buf[:, -1:], pts - tail, axis=1)
-                    buf = np.concatenate([buf, pad], axis=1)
+                    last = np.take(buf, [-1], axis=taxis)
+                    pad = np.repeat(last, pts - tail, axis=taxis)
+                    buf = np.concatenate([buf, pad], axis=taxis)
                     nint += 1
             if sp is not None:
                 sp.set(bytes=int(buf.nbytes))
+        head, carry = np.split(buf, [nint * pts], axis=taxis)
         if nint:
             telemetry.counter("rfifind.intervals", int(nint))
-            block = transfer.ship(buf[:, : nint * pts], jnp.float32)
+            if raw:
+                dev = transfer.ship(head)
+                with telemetry.span("rfifind.ingest", nint=int(nint)):
+                    block = _ingest_tc(dev, flip, nbits)
+            else:
+                block = transfer.ship(head, jnp.float32)
             # program + ONE batched pull (3 device->host syncs otherwise)
             with telemetry.span("rfifind_block_stats", nint=int(nint)):
                 m, s, p = transfer.pull_host(*block_stats(block, pts))
             means.append(m)
             stds.append(s)
             maxpows.append(p)
-        carry = buf[:, nint * pts:]
 
-    if blocks is not None:
+    if raw:
+        for _pos, b in _timed_reads(
+                source.iter_blocks(pts * ints_per_read, 0, raw=True)):
+            telemetry.counter("rfifind.raw_blocks")
+            consume(b)
+    elif blocks is not None:
         for b in blocks:
             consume(b)
     else:
         for b in _iter_file_blocks(source, pts * ints_per_read):
             consume(b)
-    consume(np.zeros((nchan, 0), np.float32), final=True)
+    if carry is not None:
+        consume(np.take(carry, [], axis=taxis), final=True)
 
     if not means:
         raise ValueError("no complete intervals: data shorter than time/2")

@@ -177,6 +177,23 @@ def test_rfifind_block_stats(one_chip):
     assert temp + args < V5E_HBM_BYTES
 
 
+def test_rfifind_raw_ingest(one_chip):
+    """The mask stage's ingest of one read of a 2-bit pointing as the
+    file holds it (16 one-second intervals x 256 packed bytes a
+    spectrum), band flipped to the .mask convention; its float32 output
+    is block_stats' input, so both are resident at once."""
+    from pypulsar_tpu.ops.ingest import _ingest_tc
+
+    pts = int(round(1.0 / TSAMP))
+    compiled = _ingest_tc._jit.lower(
+        _sds((16 * pts, NCHAN * 2 // 8), jnp.uint8, one_chip),
+        flip=True, nbits=2).compile()
+    temp, args = _device_bytes(compiled)
+    out = compiled.memory_analysis().output_size_in_bytes
+    assert out == 4 * NCHAN * 16 * pts
+    assert temp + args + out < V5E_HBM_BYTES / 4
+
+
 def _prep_args(batch, sharding, table_sh):
     from pypulsar_tpu.fourier.kernels import deredden_schedule
 

@@ -649,12 +649,14 @@ def test_cli_rfifind_holds_every_leaf_under_its_root(toy_fil, tmp_path):
     paths = _span_paths(tlm)
     assert paths["cli.rfifind"] == {None}
     for leaf in ("io.open", "io.read", "rfifind.stage_block", "h2d.ship",
-                 "rfifind_block_stats", "rfifind.clip", "rfifind.write"):
+                 "rfifind.ingest", "rfifind_block_stats", "rfifind.clip",
+                 "rfifind.write"):
         assert paths[leaf] == {"cli.rfifind"}, (leaf, paths.get(leaf))
     assert paths["d2h.pull"] == {"rfifind_block_stats"}
     final = [r for r in _read_jsonl(tlm) if r["type"] == "counters"][-1]
     assert final["counters"]["io.bytes_read"] == 16 * 4096  # as on disk
-    assert final["counters"]["h2d.bytes"] == 4 * 16 * 4096  # as float32
+    assert final["counters"]["h2d.bytes"] == 16 * 4096  # as on disk too
+    assert final["counters"]["rfifind.raw_blocks"] == 1  # one read
     blocks = [r for r in _read_jsonl(tlm) if r["type"] == "span"
               and r["name"] == "rfifind.stage_block"]
     assert all(r["attrs"]["bytes"] >= 0 for r in blocks)

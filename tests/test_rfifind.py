@@ -252,3 +252,149 @@ def test_clip_stats_is_iterative():
     flags = clip_stats(stats, time_sigma=10.0)
     assert flags[3, 0] and flags[4, 0]
     assert not flags[10, 0]
+
+
+# ---------------------------------------------------------------------------
+# raw ingest: a SIGPROC reader's blocks ship as the file holds them and
+# are unpacked / transposed / widened / flipped on the device
+# ---------------------------------------------------------------------------
+
+_PTS = 64  # samples an interval (time 0.064 s at 1 ms)
+_READ = 16 * _PTS  # samples a read (rfifind's ints_per_read default)
+_TAILS = {"none": 0, "dropped": 20, "padded": 40}  # 20 < _PTS // 2 <= 40
+
+
+def _toy_fil(path, nbits, foff, nsamp, nchan=16, seed=0):
+    """A SIGPROC file of integer samples in the range ``nbits`` holds,
+    with one saturated interval and one channel that carries a tone;
+    returns (fn, number of reads rfifind makes of it)."""
+    rng = np.random.RandomState(1000 * nbits + seed)
+    top = min((1 << nbits) - 1, 255)
+    data = rng.randint(0, top + 1, size=(nsamp, nchan))
+    data[3 * _PTS:4 * _PTS] = top  # one interval saturated in every channel
+    data[:, 5] = np.where(np.arange(nsamp) % 2, top, 0)  # a tone, all along
+    fn = str(path / f"toy{nbits}.fil")
+    write_filterbank(fn, dict(fch1=1500.0, foff=foff, nchans=nchan,
+                              tsamp=1e-3, nbits=nbits, tstart=59000.0),
+                     data.astype(np.float32))
+    return fn, -(-nsamp // _READ)
+
+
+def _run_both(fn, tmp_path, monkeypatch):
+    """rfifind() over one FilterbankFile twice: as it is (raw blocks, the
+    device ingest) and with the marker hidden on that same reader (the
+    host path: _iter_file_blocks' host unpack + consume's float32
+    staging). Returns the two (stats, flags, maskfn) triples."""
+    from pypulsar_tpu.io.filterbank import FilterbankFile
+
+    with FilterbankFile(fn) as reader:
+        dev = rfifind(reader, time=_PTS * 1e-3,
+                      outbase=str(tmp_path / "dev"))
+        monkeypatch.setattr(reader, "BLOCK_ITER_ARRAYS", False,
+                            raising=False)
+        host = rfifind(reader, time=_PTS * 1e-3,
+                       outbase=str(tmp_path / "host"))
+    return dev, host
+
+
+@pytest.mark.parametrize("tail", sorted(_TAILS))
+@pytest.mark.parametrize("foff", [-2.0, 2.0], ids=["descending", "ascending"])
+@pytest.mark.parametrize("nbits", [2, 4, 8, 32])
+def test_raw_ingest_matches_host_path(tmp_path, monkeypatch, nbits, foff,
+                                      tail):
+    """Two and a half reads plus the tail: every statistic bit for bit,
+    the .mask byte for byte, every array of the stats sidecar equal."""
+    nsamp = 2 * _READ + 8 * _PTS + _TAILS[tail]
+    fn, _ = _toy_fil(tmp_path, nbits, foff, nsamp)
+    (dstats, dflags, dmask), (hstats, hflags, hmask) = _run_both(
+        fn, tmp_path, monkeypatch)
+    assert dstats.nint == 40 + (tail == "padded")
+    for name in ("mean", "std", "maxpow"):
+        d, h = getattr(dstats, name), getattr(hstats, name)
+        assert d.dtype == h.dtype and d.shape == h.shape
+        assert d.tobytes() == h.tobytes(), name
+    assert np.array_equal(dflags, hflags)
+    assert dflags[:, 15 - 5 if foff < 0 else 5].all()  # the tone's channel
+    with open(dmask, "rb") as a, open(hmask, "rb") as b:
+        assert a.read() == b.read()
+    with np.load(str(tmp_path / "dev_rfifind.stats.npz")) as a, \
+            np.load(str(tmp_path / "host_rfifind.stats.npz")) as b:
+        assert sorted(a.files) == sorted(b.files)
+        for key in a.files:
+            assert a[key].dtype == b[key].dtype
+            assert a[key].tobytes() == b[key].tobytes(), key
+
+
+def test_raw_ingest_counts_blocks_and_packed_bytes(tmp_path):
+    """A 2-bit file: one raw block a read, the link carries the file's
+    packed bytes (whole intervals, plus the tail's pad rows) and not
+    their float32 expansion, and the read counter the bytes on disk."""
+    from pypulsar_tpu.io.filterbank import FilterbankFile
+    from pypulsar_tpu.obs import telemetry
+
+    nchan, nsamp = 16, 2 * _READ + 8 * _PTS + _TAILS["padded"]
+    fn, reads = _toy_fil(tmp_path, 2, -2.0, nsamp, nchan=nchan)
+    row = nchan * 2 // 8  # packed bytes a spectrum
+    with FilterbankFile(fn) as reader, telemetry.session() as tlm:
+        stats, _, _ = rfifind(reader, time=_PTS * 1e-3)
+        counters = tlm.counter_totals()
+        assert "rfifind.ingest" in tlm.stages
+    assert reads == 3 and counters["rfifind.raw_blocks"] == reads
+    assert counters["rfifind.intervals"] == stats.nint == 41
+    assert counters["io.bytes_read"] == nsamp * row
+    assert counters["h2d.bytes"] == 41 * _PTS * row  # 24 pad rows in it
+
+
+@pytest.mark.parametrize("kind", ["psrfits", "fbobs", "array"])
+def test_host_path_readers_take_no_raw_ingest(tmp_path, kind):
+    """Readers without the marker, and array input, stay on the host
+    path: no raw block, float32 on the link, the result as
+    block_stats gives it for the same samples."""
+    from pypulsar_tpu.obs import telemetry
+
+    C, T, pts = 16, 4 * 256, 256
+    rng = np.random.RandomState(6)
+    data = (rng.randn(C, T) * 2.0 + 10.0).astype(np.float32)  # low-first
+    if kind == "psrfits":
+        from pypulsar_tpu.io import psrfits
+
+        fn = str(tmp_path / "h.fits")
+        psrfits.write_psrfits(fn, data, 1400.0 + np.arange(C), tsamp=1e-3,
+                              nsamp_per_subint=256, nbits=32)
+        source, kw = psrfits.PsrfitsFile(fn), {}
+    elif kind == "fbobs":
+        from pypulsar_tpu.io.fbobs import FilterbankObs
+
+        fns = []
+        for i in range(2):
+            fn = str(tmp_path / f"h{i}.fil")
+            half = data[::-1, i * T // 2:(i + 1) * T // 2].T  # hi-first
+            write_filterbank(fn, dict(
+                fch1=1400.0 + C - 1, foff=-1.0, nchans=C, tsamp=1e-3,
+                nbits=32, tstart=56000.0 + i * (T // 2) * 1e-3 / 86400.0),
+                half)
+            fns.append(fn)
+        source, kw = FilterbankObs(fns), {}
+    else:
+        source, kw = data, dict(dt=1e-3, hifreq_first=False)
+    with telemetry.session() as tlm:
+        stats, _, _ = rfifind(source, time=pts * 1e-3, **kw)
+        counters = tlm.counter_totals()
+        assert "rfifind.ingest" not in tlm.stages
+    assert counters.get("rfifind.raw_blocks", 0) == 0
+    assert counters["h2d.bytes"] == 4 * C * T
+    m, s, p = (np.asarray(x) for x in block_stats(data, pts))
+    assert stats.mean.tobytes() == m.tobytes()
+    assert stats.std.tobytes() == s.tobytes()
+    assert stats.maxpow.tobytes() == p.tobytes()
+
+
+def test_one_ingest_function_for_sweep_and_mask():
+    """The sweep's block source and the mask stage run the SAME device
+    ingest (ops/ingest.py): no second copy of the unpack."""
+    from pypulsar_tpu.ops import ingest
+    from pypulsar_tpu.ops import rfifind as ops_rfifind
+    from pypulsar_tpu.parallel import staged
+
+    assert staged._ingest_tc is ops_rfifind._ingest_tc is ingest._ingest_tc
+    assert staged._timed_reads is ops_rfifind._timed_reads
