@@ -9,7 +9,7 @@
 PY ?= python
 CPU_ENV = JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 
-.PHONY: test test-fourier test-faults test-fold test-obs test-survey test-corruption test-tune test-multihost test-race test-daemon test-broker test-candstore bench-broker bench-candplane lint dryrun smoke bench bench-quick bench-ab bench-accel bench-accel-pipeline bench-fold bench-obs bench-survey bench-multichip bench-multihost-fleet bench-specfuse bench-telemetry bench-tree bench-tune bench-compile native clean
+.PHONY: test test-faults test-fold test-obs test-survey test-corruption test-tune test-multihost test-race test-daemon test-broker test-candstore bench-broker bench-candplane lint dryrun smoke bench bench-quick bench-ab bench-accel bench-accel-pipeline bench-fold bench-obs bench-survey bench-multichip bench-multihost-fleet bench-specfuse bench-telemetry bench-tune bench-compile native clean
 
 # the whole survey chain on the attached chip, checked against the NumPy
 # twins; fails without a TPU (see chip_smoke.py; `--chips 4` on four)
@@ -40,10 +40,6 @@ lint:
 	else \
 		echo "# ruff not installed: third-party pass skipped (psrlint gate ran)"; \
 	fi
-
-# the whole suite with the TPU-default engine forced (cross-engine check)
-test-fourier:
-	PYPULSAR_TPU_SWEEP_ENGINE=fourier $(CPU_ENV) $(PY) -m pytest tests/ -q
 
 # the resilience suite: injected OOM / IO errors / kill+resume at every
 # journal kill-point, candidate tables proven bit-identical to unfaulted
@@ -234,15 +230,6 @@ bench-multihost-fleet:
 bench-specfuse:
 	$(CPU_ENV) $(PY) -m pytest tests/test_accel_pipeline.py -q -k "spectral"
 	$(PY) bench.py --accel --spectral --out BENCH_r10_specfuse.json
-
-# tree dedispersion (round 16): the tree-engine parity suite (exact
-# snap, mesh bit-identity, chain byte-identity, kill/resume), then the
-# three-engine A/B at the production DM-count geometry — SNR parity
-# asserted in-process, adds/cell from tools/dedisp_roofline.py as the
-# gate -> BENCH_r11_tree.json
-bench-tree:
-	$(CPU_ENV) $(PY) -m pytest tests/test_sweep.py tests/test_accel_pipeline.py -q -k "tree"
-	$(CPU_ENV) $(PY) bench.py --dedisp-tree --out BENCH_r11_tree.json
 
 # auto-tuning (round 17): the tune suite, then the bounded-search A/B
 # at 2 geometries (trials <= budget, tuned >= hand-picked baseline,

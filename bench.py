@@ -34,7 +34,7 @@ Prints ONE JSON line:
   {"metric": ..., "value": N, "unit": ..., "vs_baseline": N, ...extras}
 
 Usage: python bench.py [--quick] [--trials D] [--nsamp T] [--nchan C]
-                       [--engine auto|gather|scan|fourier] [--ab]
+                       [--engine auto|gather|fourier] [--ab]
 """
 
 import argparse
@@ -61,8 +61,7 @@ DEVICE_PEAKS = {
 # Modes whose gates are structural (byte parity, counters, recovery) and
 # whose headline value is not a time, rate or wall ratio: runnable on any
 # backend, recorded with the backend's name. Every other mode needs a TPU.
-HARNESS_MODES = ("broker", "candplane", "chaos", "race", "corruption",
-                 "dedisp_tree")
+HARNESS_MODES = ("broker", "candplane", "chaos", "race", "corruption")
 # Harnesses that start several JAX processes (a killed-and-restarted
 # daemon, a multi-host fleet, the cross-process compile-cache leg). A chip
 # belongs to one process at a time, so these always run on the CPU backend:
@@ -109,7 +108,7 @@ def parse_args(argv=None):
     ap.add_argument("--nsamp", type=int, default=None)
     ap.add_argument("--dm-max", type=float, default=500.0)
     ap.add_argument("--engine", default="auto",
-                    help="sweep chunk engine: auto|gather|scan|fourier|tree")
+                    help="sweep chunk engine: auto|gather|fourier")
     ap.add_argument("--tune", action="store_true",
                     help="auto-tuning A/B (round 17): bounded search vs "
                          "hand-picked defaults at >=2 geometries, "
@@ -125,14 +124,6 @@ def parse_args(argv=None):
                          "persistent-cache hits, and the fleet "
                          "warm-pool precompile overlap "
                          "(BENCH_r17_compile.json)")
-    ap.add_argument("--dedisp-tree", action="store_true",
-                    help="run the round-16 three-engine dedispersion A/B "
-                         "(gather vs fourier vs tree) at a production "
-                         "DM-count geometry (>=1024 chans, >=1000 "
-                         "trials): SNR parity asserted in-process, "
-                         "structural adds/cell from "
-                         "tools/dedisp_roofline.py as the gate "
-                         "(BENCH_r11_tree.json)")
     ap.add_argument("--baseline-trials", type=int, default=None,
                     help="NumPy trials to actually run before extrapolating")
     ap.add_argument("--profile", action="store_true",
@@ -556,9 +547,8 @@ def run_benchmark(args):
         data = jax.random.normal(key, (C, T), dtype=jnp.float32)
         float(jnp.sum(data[0, :8]))  # force materialization
         spec = Spectra(freqs, dt, data)
-        # single-dispatch whole-sweep program (the tree engine's host-
-        # built tables keep it on the streamed path, sweep_resident docs)
-        resident = T % chunk == 0 and engine != "tree"
+        # single-dispatch whole-sweep program
+        resident = T % chunk == 0
         def run():
             if resident:
                 return sweep_resident(spec, dms, nsub=nsub,
@@ -722,7 +712,7 @@ def run_ab(args):
         return float(jnp.asarray(jax.tree_util.tree_leaves(out)[0]).ravel()[0])
 
     results = {}
-    for engine in ("fourier", "gather", "scan", "tree"):
+    for engine in ("fourier", "gather"):
         try:
             fn = lambda: sweep_chunk(data, s1, s2, plan.nsub, out_len,
                                      plan.max_shift2, plan.widths, chunk,
@@ -1302,187 +1292,6 @@ def run_specfuse(args):
             "decimate": int(dec_counters.get("d2h.bytes", 0)),
         },
         "n_trials": D,
-    }
-
-
-def _load_dedisp_roofline():
-    """tools/dedisp_roofline.py loaded as a module — the ONE definition
-    of the structural work accounting the bench record cites (the
-    BENCHNOTES complexity claims must be tool-derived)."""
-    import importlib.util
-
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "tools", "dedisp_roofline.py")
-    spec = importlib.util.spec_from_file_location("dedisp_roofline", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def run_dedisp_tree(args):
-    """Three-engine dedispersion A/B at a production DM-count geometry
-    (round 16 / ISSUE 11 acceptance): >=1024 chans x >=1000 DM trials
-    through the SAME streamed sweep for engine=gather (the bit-exact-SNR
-    reference), fourier (the TPU default) and tree (the shared-work
-    merge engine, ops/tree_dedisperse.py).
-
-    The GATE is structural (the PR 10 convention): adds-per-cell per
-    engine come from tools/dedisp_roofline.py's exact table accounting —
-    tree scales ~log2(nchan) at a fixed DM grid while naive per-channel
-    shifts scale ~nchan and the two-stage direct engine pays C/g + S —
-    plus the tree engine's own telemetry counters (tree.adds_total /
-    tree.merge_levels / tree.bytes_on_device) from the measured run.
-    CPU-toy wall times are reported honestly as CPU-toy wall times; SNR
-    parity vs the direct engine is ASSERTED in-process.
-
-    The DM grid tops out at the FDMT-regime diagonal (full-band delay
-    span ~ nchan samples) — the dense-survey regime the tree recurrence
-    was invented for (PAPERS.md 1201.5380); a DDplan step at dense
-    low-DM spacing has exactly this shape."""
-    acquire_backend()
-    import jax
-    import jax.numpy as jnp
-
-    from pypulsar_tpu.core.spectra import Spectra
-    from pypulsar_tpu.obs import telemetry as _tlm
-    from pypulsar_tpu.ops import numpy_ref, tree_dedisperse
-    from pypulsar_tpu.parallel import make_sweep_plan, sweep_spectra
-
-    roof = _load_dedisp_roofline()
-    if args.quick:
-        C = args.nchan or 256
-        D = args.trials or 256
-        T = args.nsamp or 1 << 13
-    else:
-        C = args.nchan or 1024
-        D = args.trials or 1024
-        T = args.nsamp or 1 << 14
-    dt = 64e-6
-    nsub, group = min(64, C), min(32, D)
-    freqs = (1500.0 - 300.0 / C * np.arange(C)).astype(np.float64)
-    dm_max = roof.diagonal_dm(C, dt, 1500.0, 300.0)
-    dms = np.linspace(0.0, dm_max, D)
-    rng = np.random.RandomState(19)
-    data = rng.randn(C, T).astype(np.float32)
-    # a real dispersed pulse so peak SNRs are O(10) and the parity
-    # assert exercises signal trials, not just noise
-    bins = numpy_ref.bin_delays(dm_max / 2, freqs, dt)
-    t0_pulse = T // 3
-    for c in range(C):
-        idx = t0_pulse + bins[c]
-        if idx < T:
-            data[c, idx] += 0.5
-    spec = Spectra(freqs, dt, jnp.asarray(data))
-    print(f"# dedisp-tree A/B: {C} chans x {T} samples x {D} trials "
-          f"(DM 0-{dm_max:.1f}, the span~nchan diagonal), nsub={nsub}, "
-          f"g={group}", file=sys.stderr)
-
-    walls, results, counters = {}, {}, {}
-    with _tlm.session(tool="bench-dedisp-tree") as tlm:
-        for engine in ("gather", "fourier", "tree"):
-            def run():
-                return sweep_spectra(spec, dms, nsub=nsub,
-                                     group_size=group, engine=engine)
-
-            run()  # warm: compile at the real shape
-            before = dict(tlm.counter_totals())
-            best = float("inf")
-            for _ in range(2):  # best of 2, the sweep-bench discipline
-                t0 = time.perf_counter()
-                res = run()
-                best = min(best, time.perf_counter() - t0)
-            walls[engine] = best
-            results[engine] = res
-            counters[engine] = {
-                k: v - before.get(k, 0)
-                for k, v in tlm.counter_totals().items()
-                if v != before.get(k, 0)}
-            print(f"# engine={engine:8s} wall {best:7.2f} s (CPU toy)",
-                  file=sys.stderr)
-
-    ref = results["gather"]
-
-    def rel_err(res):
-        return float((np.abs(res.snr - ref.snr)
-                      / np.maximum(np.abs(ref.snr), 1.0)).max())
-
-    rel_tree, rel_fourier = rel_err(results["tree"]), rel_err(
-        results["fourier"])
-    # the parity gate, asserted in-process: the contract number (2e-6,
-    # pinned at the suite's contract geometry by
-    # test_tree_engine_snr_tolerance) — and at THIS geometry the tree
-    # must additionally be at least as tight as the published
-    # fourier engine, whose own f32 floor grows past 2e-6 at
-    # production scale (both recorded; nothing hidden)
-    assert rel_tree <= max(2e-6, rel_fourier), \
-        f"tree SNR parity {rel_tree:.2e} looser than both the 2e-6 " \
-        f"contract and the fourier engine's {rel_fourier:.2e}"
-    assert np.array_equal(results["tree"].peak_sample, ref.peak_sample)
-    # half of the tree leg's counter total is the warm run; the diff
-    # covers the two measured reps
-    tree_adds = int(counters["tree"].get("tree.adds_total", 0) // 2)
-
-    # tool-derived structural accounting (the complexity gate)
-    struct = roof.analyze(C, D, T, dm_max, nsub=nsub, group_size=group,
-                          dt=dt)
-    nchans = [C // 4, C // 2, C, 2 * C]
-    scaling = roof.scaling_sweep(nchans, D, T, dm_max, nsub, group, dt,
-                                 1500.0, 300.0)
-    growth = scaling["growth"]
-    # the work-complexity win: tree adds/cell grow ~log2(nchan) (within
-    # 2x over an 8x channel range, tracking the level count) while
-    # naive per-channel shifts grow ~nchan (8x), and at this geometry
-    # the tree undercuts even the two-stage direct engine
-    assert growth["tree"] < 2.0 < growth["naive"], growth
-    assert struct["adds_per_cell"]["tree"] < \
-        struct["adds_per_cell"]["direct_two_stage"], struct
-    # shared-work scaling with trial count: the per-cell adds DROP as
-    # trials share the tree (the production-DM-count story)
-    ndm_scan = [roof.analyze(C, n, T, dm_max, nsub=nsub,
-                             group_size=group, dt=dt)
-                for n in (D, 2 * D, 4 * D)]
-
-    unit = (f"direct-over-tree adds/cell ratio at {C} chans x {D} "
-            f"trials (structural, tools/dedisp_roofline.py; walls are "
-            f"CPU-toy walls, labeled as such per the PR 10 convention; "
-            f"SNR parity vs engine=gather asserted in-process)")
-    return {
-        "metric": "dedisp_tree_ab",
-        "value": struct["work_ratio_direct_over_tree"],
-        "unit": unit,
-        "nchan": C, "n_trials": D, "nsamp": T,
-        "dm_max_diagonal": round(dm_max, 3),
-        "delay_span_bins": struct["delay_span_bins"],
-        "wall_note": "CPU-toy walls (no TPU in this container): the "
-                     "structural counters are the gate, the walls are "
-                     "context",
-        "wall_gather_s": round(walls["gather"], 2),
-        "wall_fourier_s": round(walls["fourier"], 2),
-        "wall_tree_s": round(walls["tree"], 2),
-        "snr_parity": {
-            "contract": "gather=bit-exact reference; tree toleranced "
-                        "like fourier (<=2e-6 at the contract geometry, "
-                        "tests/test_sweep.py::"
-                        "test_tree_engine_snr_tolerance)",
-            "tree_rel_err": rel_tree,
-            "fourier_rel_err": rel_fourier,
-            "peak_samples_identical": True,
-        },
-        "adds_per_cell": struct["adds_per_cell"],
-        "bytes_per_cell": struct["bytes_per_cell"],
-        "tree_structure": struct["tree"],
-        "tree_counters_measured": {
-            "adds_total_per_rep": tree_adds,
-            "merge_levels": struct["tree"]["merge_levels"],
-            "bytes_on_device": int(
-                counters["tree"].get("tree.bytes_on_device", 0) // 2),
-        },
-        "scaling_vs_nchan": scaling,
-        "scaling_vs_ndm": [
-            {"ndm": r["ndm"], "tree_adds_per_cell":
-             r["adds_per_cell"]["tree"],
-             "direct_over_tree": r["work_ratio_direct_over_tree"]}
-            for r in ndm_scan],
     }
 
 
@@ -4946,8 +4755,6 @@ def _run_mode(args):
         return run_compile(args)
     if args.ab:
         return run_ab(args)
-    if args.dedisp_tree:
-        return run_dedisp_tree(args)
     if args.accel and args.spectral:
         return run_specfuse(args)
     if args.accel:
@@ -4987,7 +4794,7 @@ def main(argv=None) -> int:
             and not (args.quick or args.ab or args.accel or args.fold
                      or args.waterfall or args.prepass or args.survey
                      or args.broker or args.candplane
-                     or args.chaos or args.corruption or args.dedisp_tree or args.tune
+                     or args.chaos or args.corruption or args.tune
                      or args.compile or args.multihost or args.race
                      or args.obs_overhead or args.daemon_soak
                      or args.nsamp or args.nchan)

@@ -416,6 +416,7 @@ def check_twins(fil: str, sizes: dict) -> dict:
     from pypulsar_tpu.parallel.sweep import (
         choose_group_size,
         make_sweep_plan,
+        resolve_engine,
         sweep_chunk,
     )
 
@@ -444,7 +445,8 @@ def check_twins(fil: str, sizes: dict) -> dict:
     padded[:, :T] = data - data.mean(axis=1, keepdims=True)
     out = sweep_chunk(jnp.asarray(padded), jnp.asarray(plan.stage1_bins),
                       jnp.asarray(plan.stage2_bins), plan.nsub, T + W,
-                      plan.max_shift2, tuple(plan.widths), T, engine="auto")
+                      plan.max_shift2, tuple(plan.widths), T,
+                      engine=resolve_engine("auto"))
     placed["sweep"] = _on_tpu(*out)
     s, ss, mb, _ab = (np.asarray(x, dtype=np.float64) for x in out)
     mean = s / T
@@ -537,7 +539,7 @@ def check_device_path() -> None:
     need = T + W + plan.max_total_shift
     text = jax.jit(lambda d, s1, s2: sweep_chunk(
         d, s1, s2, plan.nsub, T + W, plan.max_shift2, DEFAULT_WIDTHS, T,
-        engine="auto")).lower(
+        engine=engine)).lower(
             jax.ShapeDtypeStruct((NCHAN, need), jnp.float32),
             jnp.asarray(plan.stage1_bins),
             jnp.asarray(plan.stage2_bins)).as_text()
@@ -739,6 +741,7 @@ def check_sharded_intermediates(fil: str, sizes: dict) -> None:
         choose_group_size,
         make_sharded_series_chunk,
         make_sweep_plan,
+        resolve_engine,
     )
 
     mesh = gang_mesh(4)
@@ -753,7 +756,7 @@ def check_sharded_intermediates(fil: str, sizes: dict) -> None:
     padded = np.zeros((len(freqs), need), np.float32)
     padded[:, :T] = data
     fn = make_sharded_series_chunk(mesh, plan.nsub, T, plan.max_shift2,
-                                   engine="auto")
+                                   engine=resolve_engine("auto"))
     series = fn(jnp.asarray(padded), jnp.asarray(plan.stage1_bins),
                 jnp.asarray(plan.stage2_bins))
     re, _im = prep_spectra_batch(series[:8], mesh=mesh)

@@ -1255,7 +1255,7 @@ class TelemetryNameDriftRule(ProjectRule):
     bench / the tests consume the same dotted literal.  Rename one side
     and the other silently reads zeros — the observability flavor of
     PL004's knob drift (round 21).  Two directions, scoped to the
-    dotted ``survey.`` / ``tree.`` / ``tune.`` families:
+    dotted ``survey.`` / ``tune.`` families:
 
     - a consumer literal (``pypulsar_tpu/obs/summarize.py``,
       ``bench.py``, ``tests/``) nothing in the production tree emits is
@@ -1276,12 +1276,12 @@ class TelemetryNameDriftRule(ProjectRule):
     name = "telemetry-name-drift"
     summary = "telemetry name referenced on one side of the emit/consume contract only"
 
-    _FAMILIES = ("survey.", "tree.", "tune.")
+    _FAMILIES = ("survey.", "tune.")
     _EMIT_FNS = ("counter", "event", "gauge", "span", "record_span")
     _FAULT_FNS = ("trip", "trip_data", "hits", "configure",
                   "parse_chaos_spec")
     _NAME_RE = re.compile(
-        r"^(?:survey|tree|tune)\.[A-Za-z0-9_.]*[A-Za-z0-9_]$")
+        r"^(?:survey|tune)\.[A-Za-z0-9_.]*[A-Za-z0-9_]$")
     # dotted names that are files, not telemetry channels
     _EXT = (".json", ".jsonl", ".npz", ".npy", ".out", ".txt", ".fil",
             ".dat", ".csv", ".md")

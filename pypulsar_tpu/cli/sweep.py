@@ -48,18 +48,6 @@ def _engine_arg(value: str) -> str:
         % (value, hint, ", ".join(valid)))
 
 
-def _check_engine_env(ap) -> None:
-    """Early validation of PYPULSAR_TPU_SWEEP_ENGINE (consulted only
-    when --engine is 'auto'): same parse-time error + hint as the flag,
-    instead of the mid-run resolve_engine failure."""
-    env = knobs.env_str("PYPULSAR_TPU_SWEEP_ENGINE")
-    if env and env != "auto":
-        try:
-            _engine_arg(env)
-        except argparse.ArgumentTypeError as e:
-            ap.error("PYPULSAR_TPU_SWEEP_ENGINE: %s" % e)
-
-
 def _apply_tuning(args, reader) -> None:
     """Round-17 auto-tuning consult for the flat single-file path:
     install the cached throughput config for this run's ACTUAL geometry
@@ -531,11 +519,10 @@ def main(argv=None):
                          "this run, else the local device list")
     ap.add_argument("--engine", default="auto", type=_engine_arg,
                     help="chunk-kernel formulation: auto (fourier on "
-                         "TPU, gather elsewhere), gather, scan, fourier, "
-                         "or tree (log2(nchan) shared-work merge levels "
-                         "— the production-DM-count engine, round 16); "
-                         "validated here against the ENGINES registry "
-                         "with a closest-match hint")
+                         "TPU, gather elsewhere), fourier, or gather "
+                         "(the bit-parity reference); validated here "
+                         "against the ENGINES registry with a "
+                         "closest-match hint")
     ap.add_argument("--mask", dest="maskfile", default=None,
                     help="rfifind .mask file (ours or PRESTO's) applied "
                          "per block with median-mid80 fill")
@@ -666,8 +653,6 @@ def main(argv=None):
     faultinject.add_fault_flag(ap)
     args = ap.parse_args(argv)
 
-    if args.engine == "auto":
-        _check_engine_env(ap)
     faultinject.configure_from_env()
     if args.fault_inject:
         faultinject.configure(args.fault_inject)

@@ -52,9 +52,7 @@ __all__ = [
 # up — the plane already owns their compile telemetry and caching.
 OPS_LEAF_ALLOWLIST: Tuple[str, ...] = (
     "pypulsar_tpu/ops/kernels.py",
-    "pypulsar_tpu/ops/tree_dedisperse.py",
     "pypulsar_tpu/ops/fourier_dedisperse.py",
-    "pypulsar_tpu/ops/pallas_dedisperse.py",
     "pypulsar_tpu/ops/pallas_kernels.py",
     "pypulsar_tpu/ops/rfifind.py",
 )

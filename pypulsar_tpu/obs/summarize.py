@@ -629,24 +629,6 @@ def render(s: TraceSummary, file: TextIO, top: int = 20) -> None:
         sf_bits.append(f"series bytes kept on device={_fmt_bytes(n_kept)}")
     if sf_bits:
         p("#\n# spectral fusion: " + "  ".join(sf_bits))
-    # tree-dedispersion roll-up (round 16): the shared-work engine's
-    # structural counters — merge depth, adds actually performed for
-    # ALL trials together, and the resident merge-state footprint
-    # (per-device splits land in the per-device section via the
-    # device{N}.tree.* stamps, the PR 6 lease contract)
-    tr_bits = []
-    lv = s.gauges.get("tree.merge_levels", {}).get("max")
-    if lv:
-        tr_bits.append(f"merge levels={int(lv)}")
-    n_adds = s.counters.get("tree.adds_total")
-    if n_adds:
-        tr_bits.append(f"shared-work adds={_fmt_count(n_adds)}")
-    n_state = s.counters.get("tree.bytes_on_device")
-    if n_state:
-        tr_bits.append(f"merge-state bytes on device="
-                       f"{_fmt_bytes(n_state)}")
-    if tr_bits:
-        p("#\n# tree dedispersion: " + "  ".join(tr_bits))
     # auto-tuning roll-up (round 17): what the bounded search cost and
     # what the geometry-keyed cache saved — trials run, hit/miss
     # counts, and the winning config per stage (tune.winner/applied

@@ -3,9 +3,8 @@
 Why: the time-domain formulation of the sweep's hot loop — per-row
 ``dynamic_slice`` gathers (parallel/sweep.py ``_slice_rows``) — lowers to a
 generic XLA gather that measured **26 GB/s effective on v5e** (3% of the
-819 GB/s HBM roofline; see BENCHNOTES.md for the recorded A/B), and the
-Pallas dynamic-offset-DMA alternative does not compile on this toolchain
-(ops/pallas_dedisperse.py). This module removes the gather entirely: a
+819 GB/s HBM roofline; see BENCHNOTES.md for the recorded A/B). This
+module removes the gather entirely: a
 circular shift by ``s`` bins is multiplication by ``exp(2i*pi*k*s/n)`` in
 the Fourier domain, so the whole two-stage shift-and-sum becomes
 
@@ -15,8 +14,8 @@ the Fourier domain, so the whole two-stage shift-and-sum becomes
     ts = irfft(Xts)[:, :out_len]
 
 — batched power-of-two FFTs plus *elementwise multiply-reduce* streams,
-the access pattern XLA fuses to full bandwidth on TPU. The default
-``phase_mode='factored'`` further factors the frequency-bin axis
+the access pattern XLA fuses to full bandwidth on TPU. The phase is
+applied factored over the frequency-bin axis
 (k = M*hi + lo) so the per-shift phase costs ~2*sqrt(F) transcendentals
 instead of F — the round-3 profile showed the stages were
 phase-generation-bound at ~92G cos-sin/s, and this lifted the measured
@@ -49,8 +48,7 @@ import jax.numpy as jnp
 
 from pypulsar_tpu.ops.pallas_kernels import boxcar_stats
 
-__all__ = ["sweep_chunk_fourier", "sweep_chunk_spectra",
-           "fourier_chunk_len"]
+__all__ = ["sweep_chunk_spectra", "fourier_chunk_len"]
 
 
 def fourier_chunk_len(min_len: int) -> int:
@@ -72,20 +70,6 @@ def _phase(shifts, k, n_fft: int):
     return jax.lax.complex(jnp.cos(ang), jnp.sin(ang))
 
 
-def _phase_table(max_shift: int, k, n_fft: int, stride: int = 1):
-    """[max_shift//stride + 1, F] rows of W^(k * stride * j) — the phase
-    of every possible (strided) integer shift, built once per dispatch so
-    the per-trial phase becomes a row gather (+ one complex multiply for
-    a hi*lo factorization) instead of per-element cos/sin. The v5e probe
-    measured the gathered stage-2 ~2x the transcendental formulation
-    (BENCHNOTES.md round-3 component table)."""
-    j = jnp.arange(max_shift // stride + 1, dtype=jnp.int32) * stride
-    return _phase(j, k, n_fft)
-
-
-_LUT_LO = 64  # stage-2 shifts factor as s = 64*hi + lo; tables stay ~100 MB
-
-
 def _fact_split(F: int) -> int:
     """Power-of-two M minimizing ceil(F/M) + M — the per-shift
     transcendental count of the bin-axis factorization below."""
@@ -99,6 +83,40 @@ def _fact_split(F: int) -> int:
     return best
 
 
+def _factored_view(X):
+    """The spectrum X[C, F] viewed as Xp[C, Fh, M] (zero-padded to Fh*M
+    bins) for the bin-axis factorization k = M*hi + lo, with the two bin
+    ranges ``k_hi[Fh]``, ``k_lo[M]``."""
+    C, F = X.shape
+    M = _fact_split(F)
+    Fh = -(-F // M)
+    k_hi = jnp.arange(Fh, dtype=jnp.int32)
+    k_lo = jnp.arange(M, dtype=jnp.int32)
+    Xp = jnp.pad(X, ((0, 0), (0, Fh * M - F))).reshape(C, Fh, M)
+    return Xp, k_hi, k_lo
+
+
+def _two_stage_factored(Xp, k_hi, k_lo, s1, s2, nsub: int, n_fft: int):
+    """One trial group's two-stage shift-and-sum in the Fourier domain:
+    Xp[C, Fh, M], s1[C], s2[g, S] -> per-trial spectra [g, Fh*M].
+
+    W^(s*k) = W^((s*M)*hi) * W^(s*lo), so each shift costs Fh + M
+    ~ 2*sqrt(F) cos/sin pairs instead of F, applied as two rank-3
+    broadcast complex multiplies — hi along axis 1, lo along axis 2 —
+    gather-free, no F-length phase row ever materialized."""
+    C, Fh, M = Xp.shape
+    per = C // nsub
+    with jax.named_scope("dedisp.stage1"):
+        hi1 = _phase(s1 * jnp.int32(M), k_hi, n_fft)  # [C, Fh]
+        lo1 = _phase(s1, k_lo, n_fft)                 # [C, M]
+        xsub = (Xp * hi1[:, :, None] * lo1[:, None, :]) \
+            .reshape(nsub, per, Fh, M).sum(axis=1)     # [S, Fh, M]
+    with jax.named_scope("dedisp.stage2"):
+        hi2 = _phase(s2 * jnp.int32(M), k_hi, n_fft)  # [g, S, Fh]
+        lo2 = _phase(s2, k_lo, n_fft)                 # [g, S, M]
+        xts = (xsub[None] * hi2[..., None] * lo2[..., None, :]) \
+            .sum(axis=1)                               # [g, Fh, M]
+        return xts.reshape(-1, Fh * M)
 
 
 def sweep_chunk_fourier_impl(
@@ -110,10 +128,6 @@ def sweep_chunk_fourier_impl(
     widths: Tuple[int, ...],
     stat_len: int,
     n_fft: int,
-    boxcar_backend: str = "auto",
-    phase_mode: str = "factored",
-    max_shift1: int = 0,
-    max_shift2: int = 0,
 ):
     """Fourier-path equivalent of parallel.sweep._sweep_chunk_impl.
 
@@ -122,89 +136,28 @@ def sweep_chunk_fourier_impl(
     Returns per-trial (sum[D], sumsq[D], maxbox[D, W], argbox[D, W]) with
     window starts confined to the first ``stat_len`` samples.
 
-    ``phase_mode``: 'factored' (default) factors the BIN axis
-    (k = M*hi + lo => W^(s*k) = W^((s*M)*hi) * W^(s*lo)) so each shift
-    costs ~2*sqrt(F) cos/sin pairs instead of F, applied as two rank-3
-    broadcast complex multiplies over the spectrum viewed as [C, Fh, M]
-    — gather-free, no F-length phase row ever materialized. 'direct'
-    computes cos/sin per element; 'lut' gathers per-shift phase rows
-    from tables built once per dispatch, stage 2 factoring
-    ``s = 64*hi + lo`` into two table rows and one complex multiply.
-    All use the same exact int32-wraparound index math; factored/lut
-    differ from direct by one extra f32 complex multiply (~3e-7
-    relative), inside the sweep's SNR parity budget. Measured on v5e
-    (round-4 A/B, bench geometry, 1024-trial chunk): factored 146 ms
-    vs direct 323 ms vs lut 646 ms — the round-3 "transcendental
-    floor" was real (the stages were phase-generation-bound) and the
-    bin-axis factorization removes it; the earlier LUT attempt lost
-    because it factored the SHIFT axis and paid per-element gathers.
-    'lut' needs the static bounds ``max_shift1``/``max_shift2``
-    (<=0 falls back to 'direct').
+    The phase is applied factored over the BIN axis
+    (:func:`_two_stage_factored`): the stages were phase-generation-bound
+    with cos/sin per element (323 ms a 1024-trial chunk on v5e against
+    146 ms factored, BENCHNOTES.md round-4 A/B). The factorization costs
+    one extra f32 complex multiply (~3e-7 relative), inside the sweep's
+    SNR parity budget.
     """
-    C, L = data.shape
     G, g, S = stage2_bins.shape
-    per = C // nsub
     with jax.named_scope("dedisp.rfft"):
         X = jnp.fft.rfft(data, n=n_fft, axis=1)  # [C, F]
     F = X.shape[1]
-    k = jnp.arange(F, dtype=jnp.int32)
-    use_lut = phase_mode == "lut" and max_shift1 >= 0 and max_shift2 >= 0 \
-        and (max_shift1 or max_shift2)
-    if use_lut:
-        t1 = _phase_table(max_shift1, k, n_fft)  # [max1+1, F]
-        t_hi = _phase_table(max_shift2, k, n_fft, stride=_LUT_LO)
-        t_lo = _phase_table(min(_LUT_LO - 1, max_shift2), k, n_fft)
-
-    if phase_mode == "factored":
-        # Bin-axis factorization k = M*hi + lo: view the spectrum as
-        # [C, Fh, M] (zero-padded to Fh*M bins) and apply the phase as two
-        # rank-3 broadcast multiplies — hi along axis 1, lo along axis 2 —
-        # so no F-length phase row is ever materialized and each shift
-        # costs only Fh + M ~ 2*sqrt(F) cos/sin pairs.
-        M = _fact_split(F)
-        Fh = -(-F // M)
-        k_hi = jnp.arange(Fh, dtype=jnp.int32)
-        k_lo = jnp.arange(M, dtype=jnp.int32)
-        Xp = jnp.pad(X, ((0, 0), (0, Fh * M - F))).reshape(C, Fh, M)
-
-        def per_group_fact(carry, xs):
-            s1, s2 = xs  # [C], [g, S]
-            with jax.named_scope("dedisp.stage1"):
-                hi1 = _phase(s1 * jnp.int32(M), k_hi, n_fft)  # [C, Fh]
-                lo1 = _phase(s1, k_lo, n_fft)                 # [C, M]
-                xsub = (Xp * hi1[:, :, None] * lo1[:, None, :]) \
-                    .reshape(nsub, per, Fh, M).sum(axis=1)     # [S, Fh, M]
-            with jax.named_scope("dedisp.stage2"):
-                hi2 = _phase(s2 * jnp.int32(M), k_hi, n_fft)  # [g, S, Fh]
-                lo2 = _phase(s2, k_lo, n_fft)                 # [g, S, M]
-                xts = (xsub[None] * hi2[..., None] * lo2[..., None, :]) \
-                    .sum(axis=1)                               # [g, Fh, M]
-                xts = xts.reshape(-1, Fh * M)[:, :F]
-            with jax.named_scope("dedisp.irfft"):
-                ts = jnp.fft.irfft(xts, n=n_fft, axis=1)[:, :out_len]
-            s, ss, mb_g, ab_g = boxcar_stats(ts, widths, stat_len,
-                                             backend=boxcar_backend)
-            return carry, (s, ss, mb_g, ab_g)
+    Xp, k_hi, k_lo = _factored_view(X)
 
     def per_group(carry, xs):
         s1, s2 = xs  # [C], [g, S]
-        with jax.named_scope("dedisp.stage1"):
-            ph1 = t1[s1] if use_lut else _phase(s1, k, n_fft)
-            xsub = (X * ph1).reshape(nsub, per, F).sum(axis=1)
-        with jax.named_scope("dedisp.stage2"):
-            if use_lut:
-                ph2 = t_hi[s2 // _LUT_LO] * t_lo[s2 % _LUT_LO]
-            else:
-                ph2 = _phase(s2, k, n_fft)
-            xts = (xsub[None, :, :] * ph2).sum(axis=1)  # [g, F]
+        xts = _two_stage_factored(Xp, k_hi, k_lo, s1, s2, nsub, n_fft)
         with jax.named_scope("dedisp.irfft"):
-            ts = jnp.fft.irfft(xts, n=n_fft, axis=1)[:, :out_len]
-        s, ss, mb_g, ab_g = boxcar_stats(ts, widths, stat_len,
-                                         backend=boxcar_backend)
-        return carry, (s, ss, mb_g, ab_g)
+            ts = jnp.fft.irfft(xts[:, :F], n=n_fft, axis=1)[:, :out_len]
+        return carry, boxcar_stats(ts, widths, stat_len)
 
-    body = per_group_fact if phase_mode == "factored" else per_group
-    _, (s, ss, mb, ab) = jax.lax.scan(body, 0, (stage1_bins, stage2_bins))
+    _, (s, ss, mb, ab) = jax.lax.scan(per_group, 0,
+                                      (stage1_bins, stage2_bins))
     D = G * g
     return (
         s.reshape(D),
@@ -214,14 +167,6 @@ def sweep_chunk_fourier_impl(
     )
 
 
-sweep_chunk_fourier = jax.jit(
-    sweep_chunk_fourier_impl,
-    static_argnames=("nsub", "out_len", "widths", "stat_len", "n_fft",
-                     "boxcar_backend", "phase_mode", "max_shift1",
-                     "max_shift2"),
-)
-
-
 def dedisperse_series_fourier_impl(
     data,
     stage1_bins,
@@ -229,7 +174,6 @@ def dedisperse_series_fourier_impl(
     nsub: int,
     out_len: int,
     n_fft: int,
-    phase_mode: str = "factored",
 ):
     """Two-stage subband dedispersed SERIES for every trial: the same
     phase math as :func:`sweep_chunk_fourier_impl` with the fused boxcar
@@ -237,58 +181,21 @@ def dedisperse_series_fourier_impl(
     kernel of the streamed .dat writer (cli sweep --write-dats on files
     too large for a device-resident Spectra; PRESTO-prepsubband
     semantics: subband dedispersion, not per-channel-exact)."""
-    C, L = data.shape
     G, g, S = stage2_bins.shape
-    per = C // nsub
     with jax.named_scope("dedisp.rfft"):
         X = jnp.fft.rfft(data, n=n_fft, axis=1)  # [C, F]
     F = X.shape[1]
-    k = jnp.arange(F, dtype=jnp.int32)
+    Xp, k_hi, k_lo = _factored_view(X)
 
-    if phase_mode == "factored":
-        M = _fact_split(F)
-        Fh = -(-F // M)
-        k_hi = jnp.arange(Fh, dtype=jnp.int32)
-        k_lo = jnp.arange(M, dtype=jnp.int32)
-        Xp = jnp.pad(X, ((0, 0), (0, Fh * M - F))).reshape(C, Fh, M)
-
-        def body(carry, xs):
-            s1, s2 = xs
-            with jax.named_scope("dedisp.stage1"):
-                hi1 = _phase(s1 * jnp.int32(M), k_hi, n_fft)
-                lo1 = _phase(s1, k_lo, n_fft)
-                xsub = (Xp * hi1[:, :, None] * lo1[:, None, :]) \
-                    .reshape(nsub, per, Fh, M).sum(axis=1)
-            with jax.named_scope("dedisp.stage2"):
-                hi2 = _phase(s2 * jnp.int32(M), k_hi, n_fft)
-                lo2 = _phase(s2, k_lo, n_fft)
-                xts = (xsub[None] * hi2[..., None] * lo2[..., None, :]) \
-                    .sum(axis=1)
-                xts = xts.reshape(-1, Fh * M)[:, :F]
-            with jax.named_scope("dedisp.irfft"):
-                return carry, jnp.fft.irfft(
-                    xts, n=n_fft, axis=1)[:, :out_len]
-    else:
-        def body(carry, xs):
-            s1, s2 = xs
-            with jax.named_scope("dedisp.stage1"):
-                ph1 = _phase(s1, k, n_fft)
-                xsub = (X * ph1).reshape(nsub, per, F).sum(axis=1)
-            with jax.named_scope("dedisp.stage2"):
-                ph2 = _phase(s2, k, n_fft)
-                xts = (xsub[None, :, :] * ph2).sum(axis=1)
-            with jax.named_scope("dedisp.irfft"):
-                return carry, jnp.fft.irfft(
-                    xts, n=n_fft, axis=1)[:, :out_len]
+    def body(carry, xs):
+        s1, s2 = xs
+        xts = _two_stage_factored(Xp, k_hi, k_lo, s1, s2, nsub, n_fft)
+        with jax.named_scope("dedisp.irfft"):
+            return carry, jnp.fft.irfft(
+                xts[:, :F], n=n_fft, axis=1)[:, :out_len]
 
     _, ts = jax.lax.scan(body, 0, (stage1_bins, stage2_bins))
     return ts.reshape(G * g, out_len)
-
-
-dedisperse_series_fourier = jax.jit(
-    dedisperse_series_fourier_impl,
-    static_argnames=("nsub", "out_len", "n_fft", "phase_mode"),
-)
 
 
 def sweep_chunk_spectra_impl(
@@ -300,7 +207,6 @@ def sweep_chunk_spectra_impl(
     dec_stride: int,
     dec_len: int,
     mean_len: int,
-    phase_mode: str = "factored",
 ):
     """Per-trial dedispersed SPECTRA, pre-irfft — the spectral-fusion
     kernel (round 15). Same two-stage phase math as
@@ -340,49 +246,22 @@ def sweep_chunk_spectra_impl(
     the f32 butterflies at fluctuation scale instead of the ~100x-sigma
     DC of 8-bit data.
     """
-    C, L = data.shape
     G, g, S = stage2_bins.shape
-    per = C // nsub
-    col = jnp.arange(L, dtype=jnp.int32)
+    col = jnp.arange(data.shape[1], dtype=jnp.int32)
     live = (col < mean_len).astype(data.dtype)[None, :]
     mu = (data * live).sum(axis=1, keepdims=True) / jnp.float32(mean_len)
     data = data - mu * live
     with jax.named_scope("dedisp.rfft"):
         X = jnp.fft.rfft(data, n=n_fft, axis=1)  # [C, F]
-    F = X.shape[1]
-    k = jnp.arange(F, dtype=jnp.int32)
     didx = jnp.arange(dec_len, dtype=jnp.int32) * jnp.int32(dec_stride)
+    Xp, k_hi, k_lo = _factored_view(X)
 
-    if phase_mode == "factored":
-        M = _fact_split(F)
-        Fh = -(-F // M)
-        k_hi = jnp.arange(Fh, dtype=jnp.int32)
-        k_lo = jnp.arange(M, dtype=jnp.int32)
-        Xp = jnp.pad(X, ((0, 0), (0, Fh * M - F))).reshape(C, Fh, M)
-
-        def body(carry, xs):
-            s1, s2 = xs
-            hi1 = _phase(s1 * jnp.int32(M), k_hi, n_fft)
-            lo1 = _phase(s1, k_lo, n_fft)
-            xsub = (Xp * hi1[:, :, None] * lo1[:, None, :]) \
-                .reshape(nsub, per, Fh, M).sum(axis=1)
-            hi2 = _phase(s2 * jnp.int32(M), k_hi, n_fft)
-            lo2 = _phase(s2, k_lo, n_fft)
-            xts = (xsub[None] * hi2[..., None] * lo2[..., None, :]) \
-                .sum(axis=1)
-            xts = jnp.take(xts.reshape(-1, Fh * M), didx, axis=1)
-            return carry, (xts.real.astype(jnp.float32),
-                           xts.imag.astype(jnp.float32))
-    else:
-        def body(carry, xs):
-            s1, s2 = xs
-            ph1 = _phase(s1, k, n_fft)
-            ph2 = _phase(s2, k, n_fft)
-            xsub = (X * ph1).reshape(nsub, per, F).sum(axis=1)
-            xts = (xsub[None, :, :] * ph2).sum(axis=1)
-            xts = jnp.take(xts, didx, axis=1)
-            return carry, (xts.real.astype(jnp.float32),
-                           xts.imag.astype(jnp.float32))
+    def body(carry, xs):
+        s1, s2 = xs
+        xts = _two_stage_factored(Xp, k_hi, k_lo, s1, s2, nsub, n_fft)
+        xts = jnp.take(xts, didx, axis=1)
+        return carry, (xts.real.astype(jnp.float32),
+                       xts.imag.astype(jnp.float32))
 
     _, (re, im) = jax.lax.scan(body, 0, (stage1_bins, stage2_bins))
     return re.reshape(G * g, dec_len), im.reshape(G * g, dec_len)
@@ -390,6 +269,5 @@ def sweep_chunk_spectra_impl(
 
 sweep_chunk_spectra = jax.jit(
     sweep_chunk_spectra_impl,
-    static_argnames=("nsub", "n_fft", "dec_stride", "dec_len", "mean_len",
-                     "phase_mode"),
+    static_argnames=("nsub", "n_fft", "dec_stride", "dec_len", "mean_len"),
 )

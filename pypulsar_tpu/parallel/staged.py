@@ -778,8 +778,10 @@ def sweep_ddplan_2d(
         finalize_sweep,
         make_sharded_sweep_chunk_2d,
         padded_group_count,
+        resolve_engine,
     )
 
+    engine = resolve_engine(engine)
     src = _make_source(source)
     nd = mesh.shape["dm"]
     nt = mesh.shape["time"]
@@ -955,8 +957,12 @@ def iter_dedispersed_chunks(
     device-count independent the yielded rows stay bit-identical to the
     unsharded stream (the multi-chip byte-parity contract)."""
     from pypulsar_tpu.ops.transfer import pull_host
-    from pypulsar_tpu.parallel.sweep import dedisperse_series_chunk
+    from pypulsar_tpu.parallel.sweep import (
+        dedisperse_series_chunk,
+        resolve_engine,
+    )
 
+    engine = resolve_engine(engine)
     factor = max(1, int(downsamp))
     dms = np.asarray(dms, dtype=np.float64)
     probe = _ReaderSource(reader)

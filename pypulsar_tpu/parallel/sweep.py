@@ -59,31 +59,26 @@ from pypulsar_tpu.tune import knobs
 
 DEFAULT_WIDTHS = (1, 2, 4, 8, 16, 32)
 
-ENGINES = ("gather", "scan", "fourier", "tree")
+ENGINES = ("gather", "fourier")
 
 
 def resolve_engine(engine: str = "auto") -> str:
-    """Pick the chunk-kernel formulation.
+    """Pick the chunk-kernel formulation, once, where a public entry
+    takes ``engine="auto"``; the chunk kernels below take the resolved
+    name.
 
-    'fourier' (ops/fourier_dedisperse.py) is the default on TPU: the
+    'fourier' (ops/fourier_dedisperse.py) is what a TPU runs: the
     recorded v5e A/B (BENCHNOTES.md) measured the gather path at ~26 GB/s
     effective (3% of HBM roofline) while the Fourier phase-multiply path
-    streams at bandwidth. 'gather' stays the default off-TPU (CPU XLA
-    handles the vmapped dynamic_slice fine, and it is the bit-parity
-    reference formulation). 'tree' (ops/tree_dedisperse.py) shares adds
-    between ALL trials through log2(nchan) pairwise merge levels — the
-    production-DM-count engine (round 16); opt-in (never auto-picked:
-    its win depends on trial count/density, see the README engine
-    matrix). Override with PYPULSAR_TPU_SWEEP_ENGINE.
+    streams at bandwidth. 'gather' is the bit-parity reference
+    formulation the tests compare against, and what runs off-TPU (CPU
+    XLA handles the vmapped dynamic_slice fine).
     """
     if engine != "auto":
         if engine not in ENGINES:
             raise ValueError(f"unknown sweep engine {engine!r}; "
                              f"expected one of {ENGINES + ('auto',)}")
         return engine
-    env = knobs.env_str("PYPULSAR_TPU_SWEEP_ENGINE")
-    if env and env != "auto":  # "auto" in the env var falls through
-        return resolve_engine(env)
     # resolve through the gang-lease registry (PL002): under a lease the
     # engine choice must reflect the leased chip, not whatever backend
     # device 0 happens to be. A backend that cannot be asked is an error,
@@ -92,6 +87,14 @@ def resolve_engine(engine: str = "auto") -> str:
 
     platform = lease_devices()[0].platform
     return "fourier" if platform == "tpu" else "gather"
+
+
+def _check_resolved(engine: str) -> None:
+    """The chunk kernels take a resolved engine: 'auto' is decided by
+    :func:`resolve_engine` at the public entry, never inside a trace."""
+    if engine not in ENGINES:
+        raise ValueError(f"chunk kernels take a resolved engine, one of "
+                         f"{ENGINES}; got {engine!r}")
 
 
 def choose_group_size(
@@ -301,38 +304,6 @@ def _slice_rows(rows, starts, length):
     )
 
 
-def _shift_segment_sum(rows, starts, length, seg: int):
-    """Fused shift + segment-sum: rows[N, L] with per-row starts ->
-    out[N // seg, length], out[s] = sum of seg consecutive shifted rows.
-
-    Scan-based alternative to ``_slice_rows(...).reshape(...).sum(axis=1)``:
-    one dynamic_slice per scan step accumulating into the output, which
-    lowers to contiguous copies instead of the vmapped gather and never
-    materializes the [N, length] intermediate. The recorded v5e A/B
-    (BENCHNOTES.md) has both formulations far below HBM bandwidth; the
-    Fourier engine supersedes them on TPU."""
-    N = rows.shape[0]
-    nseg = N // seg
-    starts = starts.astype(jnp.int32)
-
-    def body(acc, ci):
-        seg_rows = jax.lax.dynamic_slice_in_dim(rows, ci * seg, seg, 0)
-        seg_starts = jax.lax.dynamic_slice_in_dim(starts, ci * seg, seg, 0)
-
-        def inner(acc_row, k):
-            row = jax.lax.dynamic_slice(
-                seg_rows, (k, seg_starts[k]), (1, length))[0]
-            return acc_row + row, None
-
-        row0 = jax.lax.dynamic_slice(
-            seg_rows, (0, seg_starts[0]), (1, length))[0]
-        acc_row, _ = jax.lax.scan(inner, row0, jnp.arange(1, seg))
-        return acc, (ci, acc_row)
-
-    _, (_, out) = jax.lax.scan(body, 0, jnp.arange(nseg))
-    return out
-
-
 def _sweep_chunk_impl(
     data,
     stage1_bins,
@@ -352,38 +323,23 @@ def _sweep_chunk_impl(
     belong to this chunk (the payload), so streamed chunks don't double-count
     overlap samples.
 
-    ``engine``: 'gather' (vmapped dynamic_slice), 'scan' (sequential
-    dynamic_slice accumulation), 'fourier' (phase-multiply fast path,
-    ops/fourier_dedisperse.py — the TPU default via resolve_engine), or
-    'auto'. All three agree to f32 rounding (tests/test_sweep.py).
+    ``engine`` (resolved, see :func:`resolve_engine`): 'gather' (vmapped
+    dynamic_slice, the reference) or 'fourier' (phase-multiply fast
+    path, ops/fourier_dedisperse.py). They agree to f32 rounding
+    (tests/test_sweep.py).
 
     Returns per-trial (sum[D], sumsq[D], maxbox[D, W], argbox[D, W]).
     """
-    engine = resolve_engine(engine)
-    if engine == "tree":
-        # the tree engine's merge tables are HOST-built (data-dependent
-        # dedup) — it dispatches from the Python wrappers (sweep_chunk /
-        # dedisperse_series_chunk / the sharded factories), never from
-        # inside a traced impl
-        raise ValueError(
-            "engine='tree' cannot run inside a traced chunk impl; "
-            "dispatch through sweep_chunk/dedisperse_series_chunk or "
-            "the make_sharded_* factories")
+    _check_resolved(engine)
     if engine == "fourier":
         from pypulsar_tpu.ops.fourier_dedisperse import (
             fourier_chunk_len,
             sweep_chunk_fourier_impl,
         )
 
-        # static shift bounds for the LUT phase tables: every sweep path
-        # sizes data as out_len + slack2 + max_shift1, so the stage-1
-        # bound falls out of the (static) chunk shape
-        max_s1 = max(int(data.shape[1]) - out_len - slack2, 0)
         return sweep_chunk_fourier_impl(
             data, stage1_bins, stage2_bins, nsub, out_len, widths,
-            stat_len, fourier_chunk_len(data.shape[1]),
-            max_shift1=max_s1, max_shift2=slack2,
-        )
+            stat_len, fourier_chunk_len(data.shape[1]))
     C, L = data.shape
     G, g, S = stage2_bins.shape
     per = C // nsub
@@ -392,12 +348,8 @@ def _sweep_chunk_impl(
     def per_group(carry, xs):
         shift1, shift2 = xs
         with jax.named_scope("dedisp.stage1"):
-            if engine == "scan":
-                # scan-based formulation (see _shift_segment_sum)
-                sub = _shift_segment_sum(data, shift1, L1, per)  # [S, L1]
-            else:
-                sliced = _slice_rows(data, shift1, L1)  # [C, L1]
-                sub = sliced.reshape(nsub, per, L1).sum(axis=1)  # [S, L1]
+            sliced = _slice_rows(data, shift1, L1)  # [C, L1]
+            sub = sliced.reshape(nsub, per, L1).sum(axis=1)  # [S, L1]
         with jax.named_scope("dedisp.stage2"):
             ts = jax.vmap(
                 lambda sh: _slice_rows(sub, sh, out_len).sum(axis=0))(
@@ -418,58 +370,26 @@ def _sweep_chunk_impl(
     )
 
 
-@plane_jit(static_argnames=("nsub", "out_len", "slack2", "widths",
-                            "stat_len", "engine"), stage="sweep")
-def _sweep_chunk_jit(data, stage1_bins, stage2_bins, nsub, out_len, slack2,
-                     widths, stat_len, engine="gather"):
-    return _sweep_chunk_impl(
-        data, stage1_bins, stage2_bins, nsub, out_len, slack2, widths,
-        stat_len, engine=engine
-    )
+# the single-device chunk program (see _sweep_chunk_impl). Its name stays
+# jit__sweep_chunk_jit: traces, PERF.md and the ledger's breakdown read it
+sweep_chunk = plane_jit(
+    _sweep_chunk_impl,
+    static_argnames=("nsub", "out_len", "slack2", "widths", "stat_len",
+                     "engine"),
+    stage="sweep", name="_sweep_chunk_jit")
 
 
-def sweep_chunk(data, stage1_bins, stage2_bins, nsub, out_len, slack2, widths,
-                stat_len, engine="gather"):
-    """Single-device chunk sweep (see _sweep_chunk_impl). A thin Python
-    dispatcher (not itself jitted): the gather/scan/fourier engines run
-    as one jitted program; the tree engine first builds (cached) host
-    merge tables from the exact shift values, then runs its own jitted
-    scans (ops/tree_dedisperse.py)."""
-    engine = resolve_engine(engine)
-    if engine == "tree":
-        from pypulsar_tpu.ops.tree_dedisperse import sweep_chunk_tree
-
-        return sweep_chunk_tree(data, stage1_bins, stage2_bins, out_len,
-                                tuple(widths), stat_len)
-    return _sweep_chunk_jit(data, stage1_bins, stage2_bins, nsub, out_len,
-                            slack2, widths, stat_len, engine=engine)
-
-
-def dedisperse_series_chunk(data, stage1_bins, stage2_bins, nsub,
+def _dedisperse_series_impl(data, stage1_bins, stage2_bins, nsub,
                             out_len: int, slack2: int, engine="gather"):
     """Two-stage subband dedispersed SERIES [D, out_len] for one chunk —
     :func:`_sweep_chunk_impl` with the fused detection swapped for the
     raw per-trial time series. The chunk kernel of the streamed .dat
     writer (staged.write_dats_streamed): PRESTO-prepsubband semantics
     (subband dedispersion with the sweep's own stage bins), so the
-    written series is exactly what the sweep's detections saw. Python
-    dispatcher like :func:`sweep_chunk` (the tree engine builds host
-    tables before its jitted scans)."""
-    engine = resolve_engine(engine)
-    if engine == "tree":
-        from pypulsar_tpu.ops.tree_dedisperse import dedisperse_series_tree
-
-        return dedisperse_series_tree(data, stage1_bins, stage2_bins,
-                                      out_len)
-    return _dedisperse_series_jit(data, stage1_bins, stage2_bins, nsub,
-                                  out_len, slack2, engine)
-
-
-def _dedisperse_series_impl(data, stage1_bins, stage2_bins, nsub,
-                            out_len: int, slack2: int, engine="gather"):
-    """Traceable body of :func:`dedisperse_series_chunk` (shared by the
-    single-device program and the mesh-sharded factory)."""
-    engine = resolve_engine(engine)
+    written series is exactly what the sweep's detections saw. Shared by
+    the single-device program (:func:`dedisperse_series_chunk`) and the
+    mesh-sharded factory."""
+    _check_resolved(engine)
     if engine == "fourier":
         from pypulsar_tpu.ops.fourier_dedisperse import (
             dedisperse_series_fourier_impl,
@@ -499,7 +419,8 @@ def _dedisperse_series_impl(data, stage1_bins, stage2_bins, nsub,
     return ts.reshape(G * g, out_len)
 
 
-_dedisperse_series_jit = plane_jit(
+# named jit__dedisperse_series_jit for the same readers
+dedisperse_series_chunk = plane_jit(
     _dedisperse_series_impl,
     static_argnames=("nsub", "out_len", "slack2", "engine"),
     stage="sweep", name="_dedisperse_series_jit")
@@ -514,16 +435,6 @@ def make_sharded_sweep_chunk(mesh: Mesh, nsub, out_len, slack2, widths,
     hot loop — candidates are reduced host-side after streaming. The group
     count must divide the 'dm' axis size (use make_sweep_plan(pad_groups_to=...)).
     """
-    engine = resolve_engine(engine)
-    if engine == "tree":
-        # per-device host-built tables (rows bit-identical to the
-        # unsharded tree engine — per-trial merge structure is fixed)
-        from pypulsar_tpu.ops.tree_dedisperse import (
-            make_sharded_tree_sweep_chunk,
-        )
-
-        return make_sharded_tree_sweep_chunk(mesh, out_len, tuple(widths),
-                                             stat_len)
     return _sharded_sweep_chunk(mesh, nsub, out_len, slack2, tuple(widths),
                                 stat_len, engine)
 
@@ -562,13 +473,6 @@ def make_sharded_series_chunk(mesh: Mesh, nsub, out_len, slack2,
     consumer sees are BIT-identical to the unsharded kernel's — per-group
     math is device-count independent. The group count must divide the
     'dm' axis size (make_sweep_plan(pad_groups_to=...))."""
-    engine = resolve_engine(engine)
-    if engine == "tree":
-        from pypulsar_tpu.ops.tree_dedisperse import (
-            make_sharded_tree_series_chunk,
-        )
-
-        return make_sharded_tree_series_chunk(mesh, out_len)
     return _sharded_series_chunk(mesh, nsub, out_len, slack2, engine)
 
 
@@ -603,12 +507,6 @@ def make_sharded_sweep_chunk_2d(
     Input: data[C, T] sharded as P(None, 'time'); stage tables sharded P('dm').
     T must equal local_payload * mesh.shape['time'].
     """
-    engine = resolve_engine(engine)
-    if engine == "tree":
-        raise ValueError(
-            "engine='tree' supports the 1-D 'dm' mesh only (its merge "
-            "tables are host-built per device); use gather/scan/fourier "
-            "on the dm x time mesh")
     W = max(widths)
     out_len = local_payload + W
     nt = mesh.shape["time"]
@@ -1342,11 +1240,6 @@ def sweep_resident(spectra, dms, nsub=64, group_size=32, widths=DEFAULT_WIDTHS,
     axis inside the same single program.
     """
     engine = resolve_engine(engine)
-    if engine == "tree":
-        raise ValueError(
-            "sweep_resident's single compiled program cannot host the "
-            "tree engine (host-built merge tables); use the streamed "
-            "path (sweep_spectra/sweep_stream) with engine='tree'")
     freqs = np.asarray(spectra.freqs, dtype=np.float64)
     if group_size <= 0:
         group_size = choose_group_size(dms, freqs, spectra.dt, nsub)
@@ -1482,7 +1375,7 @@ def _warm_sweep(*, dms, freqs, dt, nsub=64, group_size=0,
                               plan.stage1_bins.dtype)
     s2 = jax.ShapeDtypeStruct(plan.stage2_bins.shape,
                               plan.stage2_bins.dtype)
-    return int(_sweep_chunk_jit.warm(
+    return int(sweep_chunk.warm(
         data, s1, s2, plan.nsub, out_len, plan.max_shift2,
         tuple(plan.widths), int(chunk_payload),
         engine=resolve_engine(engine)))

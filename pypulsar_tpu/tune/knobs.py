@@ -27,14 +27,14 @@ Numeric knobs tolerate a typo'd env value by falling through to the
 next layer (the repo-wide "a bad knob must never abort a fleet"
 contract, inherited from resilience.health.env_float). String knobs
 pass the raw value through untouched — selection knobs like
-``PYPULSAR_TPU_SWEEP_ENGINE`` keep their own loud validation.
+``PYPULSAR_TPU_SHIFT_BACKEND`` keep their own loud validation.
 
 Science-invariance contract: a knob that can change *results* (engine
 selection, decimate mode, shift backend …) is declared
 ``invariant=False`` and is NEVER searched or cached — tuning may only
 move throughput knobs. ``variant_engines`` narrows that per engine:
-``PYPULSAR_TPU_SWEEP_CHUNK`` is byte-invariant for the gather/scan/tree
-engines (measured: identical .dat bytes across chunk lengths) but
+``PYPULSAR_TPU_SWEEP_CHUNK`` is byte-invariant for the gather
+engine (measured: identical .dat bytes across chunk lengths) but
 changes f32 rounding under ``fourier`` (chunk-length-dependent FFT
 rounding, the same fact parallel/staged.py fingerprints), so the sweep
 search domain drops it when the resolved engine is ``fourier``.
@@ -317,15 +317,11 @@ env_knob("PYPULSAR_TPU_SWEEP_CHUNK", "int", 1 << 18, "sweep",
          help="streaming FFT chunk length in samples (rounded up to a "
               "power of two); the round-5 v5e A/B found 2^18 +41% over "
               "2^17. Tuned values reach only the byte-invariant "
-              "series/handoff paths (gather/scan/tree; fourier's FFT "
+              "series/handoff paths (gather; fourier's FFT "
               "rounding is chunk-dependent, so its search domain drops "
               "the knob) — the single-pulse DETECTOR resolves this "
               "knob env-only (its per-chunk statistics make the chunk "
               "part of its results)")
-env_knob("PYPULSAR_TPU_SWEEP_ENGINE", "str", None, "sweep",
-         invariant=False,
-         help="chunk-kernel formulation override (auto/fourier/gather/"
-              "scan/tree) — results-affecting, never searched")
 env_knob("PYPULSAR_TPU_HOST_DOWNSAMP", "str", None, "sweep",
          invariant=False,
          help="force the staged sweep's pre-ship downsample on (1) or "
@@ -333,8 +329,6 @@ env_knob("PYPULSAR_TPU_HOST_DOWNSAMP", "str", None, "sweep",
 env_knob("PYPULSAR_TPU_DATS_RESIDENT_LIMIT", "float", 2e9, "sweep",
          help="raw-file bytes above which --write-dats streams instead "
               "of building the series in memory")
-env_knob("PYPULSAR_TPU_TREE_PLAN_CACHE", "int", 8, "sweep",
-         help="tree-engine host merge-table cache entries")
 
 # -- accel ------------------------------------------------------------------
 env_knob("PYPULSAR_TPU_ACCEL_BATCH", "int", 32, "accel",

@@ -44,9 +44,13 @@ ACCEL_ARGS = ["-z", "20", "-n", "2", "-s", "3"]
 HANDOFF_ARGS = ["--accel-search", "--accel-zmax", "20",
                 "--accel-numharm", "2", "--accel-sigma", "3",
                 "--accel-batch", "4"]
+# every chain contract below holds WITHIN an engine (cross-engine tables
+# differ at f32 rounding): gather is what `auto` picks on the tests' CPU
+# backend, fourier what it picks on the chip
+both_engines = pytest.mark.parametrize("engine", ["gather", "fourier"])
 
 
-def _run_dat_roundtrip(fil, outbase, monkeypatch, extra_accel=()):
+def _run_dat_roundtrip(fil, outbase, monkeypatch, engine, extra_accel=()):
     """Reference chain: sweep --write-dats (streamed writer) ->
     accelsearch --batch over the .dats."""
     from pypulsar_tpu.cli import accelsearch as cli_accel
@@ -54,7 +58,7 @@ def _run_dat_roundtrip(fil, outbase, monkeypatch, extra_accel=()):
 
     monkeypatch.setenv("PYPULSAR_TPU_DATS_RESIDENT_LIMIT", "0")
     assert cli_sweep.main([fil, "-o", outbase, *SWEEP_ARGS,
-                           "--write-dats"]) == 0
+                           "--engine", engine, "--write-dats"]) == 0
     dats = sorted(glob.glob(f"{outbase}_DM*.dat"))
     assert len(dats) == 8
     assert cli_accel.main([*dats, "--batch", "4", *ACCEL_ARGS,
@@ -62,26 +66,28 @@ def _run_dat_roundtrip(fil, outbase, monkeypatch, extra_accel=()):
     return sorted(glob.glob(f"{outbase}_DM*_ACCEL_20.cand"))
 
 
+@both_engines
 @pytest.mark.parametrize("device_prep", [True, False])
 def test_stream_handoff_bit_identical_to_dat_roundtrip(tmp_path,
                                                        monkeypatch,
-                                                       device_prep):
+                                                       device_prep, engine):
     """The acceptance contract of the round-6 tentpole: the streamed
     sweep->accel path produces candidate tables BIT-IDENTICAL to the
-    .dat write + re-read chain, for both prep paths, and recovers the
-    injected pulsar."""
+    .dat write + re-read chain (the same chunk kernel feeds both), for
+    both prep paths, and recovers the injected pulsar."""
     monkeypatch.chdir(tmp_path)
     fil = _pulsar_fil(tmp_path)
     from pypulsar_tpu.cli import sweep as cli_sweep
 
     prep_flags = ([] if device_prep else ["--no-device-prep"])
-    a_cands = _run_dat_roundtrip(fil, "a", monkeypatch,
+    a_cands = _run_dat_roundtrip(fil, "a", monkeypatch, engine,
                                  extra_accel=prep_flags)
     assert a_cands
 
     handoff_prep = ([] if device_prep else ["--no-accel-device-prep"])
     assert cli_sweep.main([fil, "-o", "b", *SWEEP_ARGS, *HANDOFF_ARGS,
-                           "--accel-only", *handoff_prep]) == 0
+                           "--engine", engine, "--accel-only",
+                           *handoff_prep]) == 0
     for fa in a_cands:
         fb = "b" + os.path.basename(fa)[1:]
         assert os.path.exists(fb), fb
@@ -106,7 +112,9 @@ def test_stream_handoff_bit_identical_to_dat_roundtrip(tmp_path,
         "injected pulsar not recovered"
 
 
-def test_stream_handoff_write_dats_tee_identical(tmp_path, monkeypatch):
+@both_engines
+def test_stream_handoff_write_dats_tee_identical(tmp_path, monkeypatch,
+                                                 engine):
     """--accel-search --write-dats tees the IDENTICAL .dat bytes the
     streamed writer would have produced (the tee is the same chunk
     stream, not a second implementation)."""
@@ -115,10 +123,11 @@ def test_stream_handoff_write_dats_tee_identical(tmp_path, monkeypatch):
     fil = _pulsar_fil(tmp_path)
     from pypulsar_tpu.cli import sweep as cli_sweep
 
-    assert cli_sweep.main([fil, "-o", "w", *SWEEP_ARGS,
+    eng = ["--engine", engine]
+    assert cli_sweep.main([fil, "-o", "w", *SWEEP_ARGS, *eng,
                            "--write-dats"]) == 0
     assert cli_sweep.main([fil, "-o", "t", *SWEEP_ARGS, *HANDOFF_ARGS,
-                           "--accel-only", "--write-dats"]) == 0
+                           *eng, "--accel-only", "--write-dats"]) == 0
     dats = sorted(glob.glob("w_DM*.dat"))
     assert len(dats) == 8
     for fw in dats:
@@ -131,7 +140,9 @@ def test_stream_handoff_write_dats_tee_identical(tmp_path, monkeypatch):
         assert lw == lt
 
 
-def test_stream_handoff_kill_resume_bit_identical(tmp_path, monkeypatch):
+@both_engines
+def test_stream_handoff_kill_resume_bit_identical(tmp_path, monkeypatch,
+                                                  engine):
     """A run killed mid-search (BaseException after the first batch — the
     serial fallback must NOT swallow it) resumes with
     --accel-skip-existing: finished trials are skipped, the rest are
@@ -142,9 +153,10 @@ def test_stream_handoff_kill_resume_bit_identical(tmp_path, monkeypatch):
     from pypulsar_tpu.cli import sweep as cli_sweep
     from pypulsar_tpu.fourier import accelsearch as accel_mod
 
+    run_args = [*SWEEP_ARGS, *HANDOFF_ARGS, "--engine", engine,
+                "--accel-only"]
     # uninterrupted reference
-    assert cli_sweep.main([fil, "-o", "r", *SWEEP_ARGS, *HANDOFF_ARGS,
-                           "--accel-only"]) == 0
+    assert cli_sweep.main([fil, "-o", "r", *run_args]) == 0
     ref = {os.path.basename(f)[1:]: open(f, "rb").read()
            for f in sorted(glob.glob("r_DM*_ACCEL_20.cand"))}
     assert len(ref) == 8
@@ -160,15 +172,14 @@ def test_stream_handoff_kill_resume_bit_identical(tmp_path, monkeypatch):
 
     monkeypatch.setattr(accel_mod, "accel_search_batch", dying_batch)
     with pytest.raises(KeyboardInterrupt):
-        cli_sweep.main([fil, "-o", "k", *SWEEP_ARGS, *HANDOFF_ARGS,
-                        "--accel-only"])
+        cli_sweep.main([fil, "-o", "k", *run_args])
     monkeypatch.setattr(accel_mod, "accel_search_batch", real_batch)
     done = sorted(glob.glob("k_DM*_ACCEL_20.cand"))
     assert 0 < len(done) < 8  # the kill landed mid-run
 
     # resume: finished trials skipped, the rest searched
-    assert cli_sweep.main([fil, "-o", "k", *SWEEP_ARGS, *HANDOFF_ARGS,
-                           "--accel-only", "--accel-skip-existing"]) == 0
+    assert cli_sweep.main([fil, "-o", "k", *run_args,
+                           "--accel-skip-existing"]) == 0
     got = {os.path.basename(f)[1:]: open(f, "rb").read()
            for f in sorted(glob.glob("k_DM*_ACCEL_20.cand"))}
     assert got == ref
@@ -528,45 +539,33 @@ def _cand_bytes(prefix):
             for f in sorted(glob.glob(f"{prefix}_DM*_ACCEL_20.*cand"))}
 
 
+@both_engines
 @pytest.mark.parametrize("T,extra", [
     (16384, []),                      # single chunk, power-of-two
     (15000, ["--chunk", "4096"]),     # non-pow2 out_len + partial tail
 ])
 def test_spectral_handoff_bit_identical_to_streamed(tmp_path, monkeypatch,
-                                                    T, extra):
+                                                    T, extra, engine):
     """The round-15 parity gate: `--spectral` (stitched regime, the
     default) writes candidate tables BIT-identical to the streamed
     device-prep handoff — including a non-power-of-two series length
     and a trailing partial chunk, the geometries where the decimated
     shortcut is structurally impossible and the stitch must carry the
-    exact overlap-save windows."""
+    exact overlap-save windows. Under either engine: the stitch consumes
+    the SAME chunk kernel the streamed path pulls to host, so engine
+    choice cannot open a gap."""
     monkeypatch.chdir(tmp_path)
     fil = _pulsar_fil(tmp_path, T=T)
     from pypulsar_tpu.cli import sweep as cli_sweep
 
+    eng = ["--engine", engine]
     assert cli_sweep.main([fil, "-o", "s", *SWEEP_ARGS, *HANDOFF_ARGS,
-                           "--accel-only", *extra]) == 0
+                           *eng, "--accel-only", *extra]) == 0
     assert cli_sweep.main([fil, "-o", "f", *SWEEP_ARGS, *SPECTRAL_ARGS,
-                           *extra]) == 0
+                           *eng, *extra]) == 0
     ref, got = _cand_bytes("s"), _cand_bytes("f")
     assert len(ref) == 16  # .cand + .txtcand per trial
     assert got == ref
-
-
-def test_spectral_handoff_fourier_engine_identical(tmp_path, monkeypatch):
-    """Same gate under the TPU-default fourier engine (the stitch
-    consumes the SAME chunk kernel the streamed path pulls to host, so
-    engine choice cannot open a gap)."""
-    monkeypatch.chdir(tmp_path)
-    fil = _pulsar_fil(tmp_path)
-    from pypulsar_tpu.cli import sweep as cli_sweep
-
-    eng = ["--engine", "fourier"]
-    assert cli_sweep.main([fil, "-o", "s", *SWEEP_ARGS, *HANDOFF_ARGS,
-                           "--accel-only", *eng]) == 0
-    assert cli_sweep.main([fil, "-o", "f", *SWEEP_ARGS, *SPECTRAL_ARGS,
-                           *eng]) == 0
-    assert _cand_bytes("f") == _cand_bytes("s")
 
 
 def test_spectral_slice_budget_and_stitch_counters(tmp_path, monkeypatch):
@@ -594,7 +593,9 @@ def test_spectral_slice_budget_and_stitch_counters(tmp_path, monkeypatch):
     assert s.counters.get("specfuse.bytes_on_device") == 8 * 8 * 16384
 
 
-def test_spectral_kill_resume_at_stitch_boundary(tmp_path, monkeypatch):
+@both_engines
+def test_spectral_kill_resume_at_stitch_boundary(tmp_path, monkeypatch,
+                                                 engine):
     """A kill AT THE NEW STAGE BOUNDARY (the specfuse.after_stitch
     fault point, second DM slice) resumes with --accel-skip-existing:
     the first slice's finished .cands are skipped, the rest are fused
@@ -606,8 +607,8 @@ def test_spectral_kill_resume_at_stitch_boundary(tmp_path, monkeypatch):
     from pypulsar_tpu.resilience import faultinject
     from pypulsar_tpu.resilience.faultinject import InjectedKill
 
-    assert cli_sweep.main([fil, "-o", "r", *SWEEP_ARGS,
-                           *SPECTRAL_ARGS]) == 0
+    run_args = [*SWEEP_ARGS, *SPECTRAL_ARGS, "--engine", engine]
+    assert cli_sweep.main([fil, "-o", "r", *run_args]) == 0
     ref = _cand_bytes("r")
     assert len(ref) == 16
 
@@ -617,14 +618,14 @@ def test_spectral_kill_resume_at_stitch_boundary(tmp_path, monkeypatch):
                        str(4 * spectral_trial_bytes(16384)))
     try:
         with pytest.raises(InjectedKill):
-            cli_sweep.main([fil, "-o", "k", *SWEEP_ARGS, *SPECTRAL_ARGS,
+            cli_sweep.main([fil, "-o", "k", *run_args,
                             "--fault-inject",
                             "kill:specfuse.after_stitch:2"])
     finally:
         faultinject.reset()
     done = _cand_bytes("k")
     assert 0 < len(done) < 16  # first slice landed, second did not
-    assert cli_sweep.main([fil, "-o", "k", *SWEEP_ARGS, *SPECTRAL_ARGS,
+    assert cli_sweep.main([fil, "-o", "k", *run_args,
                            "--accel-skip-existing"]) == 0
     assert _cand_bytes("k") == ref
 
@@ -742,150 +743,6 @@ def test_spectral_decimate_optin_elides_fft_pairs(tmp_path, monkeypatch):
         return k > 0.5 and abs(k - round(k)) < 0.02
 
     assert any(is_harmonic(c) and c.sig > 10 for c in cands[:10])
-
-
-# ---------------------------------------------------------------------------
-# tree engine through the handoff chain (round 16): the shared-work
-# engine must feed every stage unchanged — same within-engine byte
-# contracts the fourier engine carries
-# ---------------------------------------------------------------------------
-
-
-TREE_SWEEP_ARGS = [*SWEEP_ARGS, "--engine", "tree"]
-
-
-def test_tree_handoff_bit_identical_to_dat_roundtrip(tmp_path,
-                                                     monkeypatch):
-    """The round-6 chain contract under engine='tree': the streamed
-    sweep->accel handoff's candidate tables are BIT-identical to the
-    .dat write + re-read chain (same tree chunk kernel feeds both), and
-    the injected pulsar is recovered."""
-    monkeypatch.chdir(tmp_path)
-    monkeypatch.setenv("PYPULSAR_TPU_DATS_RESIDENT_LIMIT", "0")
-    fil = _pulsar_fil(tmp_path)
-    from pypulsar_tpu.cli import accelsearch as cli_accel
-    from pypulsar_tpu.cli import sweep as cli_sweep
-
-    assert cli_sweep.main([fil, "-o", "a", *TREE_SWEEP_ARGS,
-                           "--write-dats"]) == 0
-    dats = sorted(glob.glob("a_DM*.dat"))
-    assert len(dats) == 8
-    assert cli_accel.main([*dats, "--batch", "4", *ACCEL_ARGS]) == 0
-    a_cands = sorted(glob.glob("a_DM*_ACCEL_20.cand"))
-    assert a_cands
-
-    assert cli_sweep.main([fil, "-o", "b", *TREE_SWEEP_ARGS,
-                           *HANDOFF_ARGS, "--accel-only"]) == 0
-    for fa in a_cands:
-        fb = "b" + os.path.basename(fa)[1:]
-        assert open(fa, "rb").read() == open(fb, "rb").read(), fa
-        ta, tb = fa[:-5] + ".txtcand", fb[:-5] + ".txtcand"
-        assert open(ta).read() == open(tb).read(), ta
-
-    from pypulsar_tpu.io.prestocand import read_rzwcands
-
-    T = 16384 * 5e-4
-    cands = read_rzwcands("b_DM40.00_ACCEL_20.cand")
-    f0 = 1.0 / 0.1024
-
-    def is_harmonic(c):
-        k = (c.r / T) / f0
-        return k > 0.5 and abs(k - round(k)) < 0.02
-
-    assert any(is_harmonic(c) and c.sig > 10 for c in cands[:10]), \
-        "injected pulsar not recovered under engine=tree"
-
-
-@pytest.mark.parametrize("T,extra", [
-    (16384, []),                      # single chunk, power-of-two
-    (15000, ["--chunk", "4096"]),     # non-pow2 out_len + partial tail
-])
-def test_tree_spectral_bit_identical_to_streamed(tmp_path, monkeypatch,
-                                                 T, extra):
-    """'tree feeds specfuse unchanged': `--engine tree --spectral`
-    candidate tables are BYTE-identical to the tree-engine streamed
-    handoff at every tested geometry — the same within-engine chain
-    invariance the fourier engine's round-15 gate pinned. (Cross-ENGINE
-    tables differ by f32 summation order for every engine pair — the
-    measured 0/16 finding recorded in BENCHNOTES round 16 — so the byte
-    contract is per engine, as it always was.)"""
-    monkeypatch.chdir(tmp_path)
-    fil = _pulsar_fil(tmp_path, T=T)
-    from pypulsar_tpu.cli import sweep as cli_sweep
-
-    assert cli_sweep.main([fil, "-o", "s", *TREE_SWEEP_ARGS,
-                           *HANDOFF_ARGS, "--accel-only", *extra]) == 0
-    assert cli_sweep.main([fil, "-o", "f", *TREE_SWEEP_ARGS,
-                           *SPECTRAL_ARGS, *extra]) == 0
-    ref, got = _cand_bytes("s"), _cand_bytes("f")
-    assert len(ref) == 16
-    assert got == ref
-
-
-@pytest.mark.parametrize("numdms,mesh_k", [(8, 4), (6, 4)])
-def test_tree_spectral_sharded_byte_identical(tmp_path, monkeypatch,
-                                              numdms, mesh_k):
-    """`--engine tree --spectral --mesh k`: per-device tree tables,
-    P('dm')-sharded stitch and search — candidate tables BYTE-identical
-    to the 1-device tree streamed run, incl. the 6-trials-on-4-chips
-    padding case; the tree counters land with per-device stamps (the
-    PR 6 lease contract)."""
-    require_virtual_mesh(mesh_k)
-    monkeypatch.chdir(tmp_path)
-    fil = _pulsar_fil(tmp_path)
-    from pypulsar_tpu.cli import sweep as cli_sweep
-    from pypulsar_tpu.obs.summarize import load_records, summarize
-
-    args = ["--lodm", "0", "--dmstep", "10", "--numdms", str(numdms),
-            "-s", "8", "--group-size", "4", "--threshold", "8",
-            "--engine", "tree"]
-    assert cli_sweep.main([fil, "-o", "s1", *args, *HANDOFF_ARGS,
-                           "--accel-only"]) == 0
-    assert cli_sweep.main([fil, "-o", "sk", *args, *SPECTRAL_ARGS,
-                           "--mesh", str(mesh_k),
-                           "--telemetry", "sk.jsonl"]) == 0
-    ref, got = _cand_bytes("s1"), _cand_bytes("sk")
-    assert len(ref) == 2 * numdms
-    assert got == ref
-    s = summarize(load_records("sk.jsonl"))
-    assert s.counters.get("tree.adds_total", 0) > 0
-    assert s.counters.get("device0.tree.adds_total", 0) > 0
-    assert s.counters.get(f"device{mesh_k - 1}.tree.adds_total", 0) > 0
-    assert s.gauges.get("tree.merge_levels", {}).get("max", 0) == 5
-
-
-def test_tree_spectral_kill_resume_at_stitch_boundary(tmp_path,
-                                                      monkeypatch):
-    """Kill at the specfuse.after_stitch boundary under engine='tree',
-    resume with --accel-skip-existing: final tables bit-identical to an
-    uninterrupted tree run (the existing harness, new engine)."""
-    monkeypatch.chdir(tmp_path)
-    fil = _pulsar_fil(tmp_path)
-    from pypulsar_tpu.cli import sweep as cli_sweep
-    from pypulsar_tpu.parallel.specfuse import spectral_trial_bytes
-    from pypulsar_tpu.resilience import faultinject
-    from pypulsar_tpu.resilience.faultinject import InjectedKill
-
-    assert cli_sweep.main([fil, "-o", "r", *TREE_SWEEP_ARGS,
-                           *SPECTRAL_ARGS]) == 0
-    ref = _cand_bytes("r")
-    assert len(ref) == 16
-
-    monkeypatch.setenv("PYPULSAR_TPU_SPECFUSE_HBM",
-                       str(4 * spectral_trial_bytes(16384)))
-    try:
-        with pytest.raises(InjectedKill):
-            cli_sweep.main([fil, "-o", "k", *TREE_SWEEP_ARGS,
-                            *SPECTRAL_ARGS, "--fault-inject",
-                            "kill:specfuse.after_stitch:2"])
-    finally:
-        faultinject.reset()
-    done = _cand_bytes("k")
-    assert 0 < len(done) < 16
-    assert cli_sweep.main([fil, "-o", "k", *TREE_SWEEP_ARGS,
-                           *SPECTRAL_ARGS,
-                           "--accel-skip-existing"]) == 0
-    assert _cand_bytes("k") == ref
 
 
 def test_spectral_survey_dag_argv_composition():

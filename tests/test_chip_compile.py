@@ -127,10 +127,10 @@ def test_fourier_sweep_chunk(one_chip, on_tpu):
     """The single-pulse pass's chunk program: Fourier dedispersion of
     64 trials + the Pallas boxcar kernel, and it fits with the stream's
     four pending chunk buffers beside the executing program."""
-    from pypulsar_tpu.parallel.sweep import _sweep_chunk_jit
+    from pypulsar_tpu.parallel.sweep import sweep_chunk
 
     plan, payload, out_len, need = _sweep_geometry()
-    compiled = _sweep_chunk_jit._jit.lower(
+    compiled = sweep_chunk._jit.lower(
         *_chunk_args(plan, need, one_chip, one_chip),
         NSUB, out_len, plan.max_shift2, tuple(plan.widths), payload,
         engine="fourier").compile()
@@ -154,10 +154,10 @@ def test_pallas_boxcar_at_chunk_shape(one_chip):
 
 def test_fourier_series_chunk(one_chip):
     """The accel handoff's dedispersion: the same chunk, the series out."""
-    from pypulsar_tpu.parallel.sweep import _dedisperse_series_jit
+    from pypulsar_tpu.parallel.sweep import dedisperse_series_chunk
 
     plan, _payload, out_len, need = _sweep_geometry()
-    compiled = _dedisperse_series_jit._jit.lower(
+    compiled = dedisperse_series_chunk._jit.lower(
         *_chunk_args(plan, need, one_chip, one_chip),
         NSUB, out_len, plan.max_shift2, "fourier").compile()
     temp, args = _device_bytes(compiled)
@@ -290,6 +290,22 @@ def test_sharded_sweep_step_four_chips(mesh4, on_tpu):
     assert temp + args + 4 * 4 * NCHAN * need < V5E_HBM_BYTES
 
 
+def test_sharded_series_chunk_four_chips(mesh4):
+    """`--gang 4`, the accel handoff's dedispersion: the series chunk
+    with trial groups over 'dm', and no collective either."""
+    from pypulsar_tpu.parallel.sweep import make_sharded_series_chunk
+
+    plan, _payload, out_len, need = _sweep_geometry(mesh4)
+    fn = make_sharded_series_chunk(mesh4, NSUB, out_len, plan.max_shift2,
+                                   engine="fourier")
+    compiled = fn._jit.lower(*_chunk_args(
+        plan, need, NamedSharding(mesh4, P()),
+        NamedSharding(mesh4, P("dm")))).compile()
+    assert not [c for c in COLLECTIVES if c in compiled.as_text()]
+    temp, args = _device_bytes(compiled)  # per device
+    assert temp + args < V5E_HBM_BYTES
+
+
 def test_sharded_accel_search_four_chips(topo, mesh4):
     """`--gang 4`: the handoff batch (32 spectra under a gang of 4)
     sharded over the same mesh through prep and the deepest stage."""
@@ -306,19 +322,3 @@ def test_sharded_accel_search_four_chips(topo, mesh4):
         assert not [c for c in COLLECTIVES if c in compiled.as_text()]
         temp, argb = _device_bytes(compiled)
         assert temp + argb < V5E_HBM_BYTES
-
-
-@pytest.mark.xfail(
-    strict=True,
-    reason="today's verdict (jax 0.9.0 / libtpu 0.0.34): MosaicError — "
-           "'Slice shape along dimension 0 must be aligned to tiling "
-           "(8), but is 1' at the single-row DMA of _gather_sum_kernel; "
-           "the kernel is opt-in and off the survey path "
-           "(ops/pallas_dedisperse.py docstring)")
-def test_pallas_gather_sum_is_refused(one_chip):
-    from pypulsar_tpu.ops.pallas_dedisperse import _pallas_gather_sum
-
-    jax.jit(lambda d, r, s: _pallas_gather_sum(d, r, s, 4096)).lower(
-        _sds((64, 8192), jnp.float32, one_chip),
-        _sds((8, 16), jnp.int32, one_chip),
-        _sds((8, 16), jnp.int32, one_chip)).compile()

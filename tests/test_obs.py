@@ -313,36 +313,6 @@ def test_incremental_counter_flush(tmp_path, monkeypatch):
     assert s.counters["h2d.bytes"] == 333  # last partial flush wins
 
 
-def test_tlmsum_tree_dedispersion_rollup(tmp_path, capsys):
-    """The round-16 tree-engine counters get their own tlmsum roll-up
-    line (merge depth, shared-work adds, merge-state bytes), and the
-    per-device stamps land in the per-device section — a trace without
-    tree counters renders no such line."""
-    path = str(tmp_path / "tree.jsonl")
-    with telemetry.session(path, tool="sweep"):
-        telemetry.gauge("tree.merge_levels", 10)
-        telemetry.counter("tree.adds_total", 24491 * 16384)
-        telemetry.counter("tree.bytes_on_device", 290_000_000)
-        telemetry.counter("device0.tree.adds_total", 200_000_000)
-    from pypulsar_tpu.obs.summarize import main as tlmsum_main
-
-    assert tlmsum_main([path]) == 0
-    out = capsys.readouterr().out
-    line = [ln for ln in out.splitlines() if "tree dedispersion" in ln]
-    assert line, out
-    assert "merge levels=10" in line[0]
-    assert "shared-work adds=" in line[0]
-    assert "merge-state bytes on device=" in line[0]
-    dev = [ln for ln in out.splitlines() if ln.startswith("#   device 0")]
-    assert dev and "tree.adds_total" in dev[0]
-
-    plain = str(tmp_path / "plain.jsonl")
-    with telemetry.session(plain, tool="sweep"):
-        telemetry.counter("sweep.chunks", 1)
-    assert tlmsum_main([plain]) == 0
-    assert "tree dedispersion" not in capsys.readouterr().out
-
-
 def test_tlmsum_autotuning_rollup(tmp_path, capsys):
     """The round-17 tune.* telemetry contract gets its own tlmsum
     roll-up: trials/hit/miss counters plus the winning config per stage

@@ -90,7 +90,8 @@ def test_sweep_matches_numpy_twin():
     np.testing.assert_array_equal(res.peak_sample, ref_ab[: len(dms)])
 
 
-def test_sweep_snr_parity_with_dc_offset():
+@pytest.mark.parametrize("engine", ["gather", "fourier"])
+def test_sweep_snr_parity_with_dc_offset(engine):
     """The contract bound must hold for realistic offset data (8-bit PSRFITS
     levels ~100x sigma), not just zero-mean noise: the engine's internal
     per-channel baseline subtraction makes f32 rounding relative to the
@@ -98,7 +99,8 @@ def test_sweep_snr_parity_with_dc_offset():
     freqs, data = make_obs()
     data = data + np.float32(96.0)  # constant DC: SNR exactly invariant
     dms = np.linspace(0.0, 160.0, 48)
-    res = sweep_spectra(Spectra(freqs, 1e-3, data), dms, nsub=16, group_size=8)
+    res = sweep_spectra(Spectra(freqs, 1e-3, data), dms, nsub=16, group_size=8,
+                        engine=engine)
     plan = make_sweep_plan(dms, freqs, 1e-3, nsub=16, group_size=8)
     ref_snr, ref_ab = twin_sweep_stats(data.astype(np.float64), plan, True)
     np.testing.assert_allclose(res.snr, ref_snr[: len(dms)], rtol=5e-6, atol=1e-4)
@@ -107,35 +109,41 @@ def test_sweep_snr_parity_with_dc_offset():
     assert abs(res.mean.mean() - 96.0 * len(freqs)) < 1.0
 
 
-def test_sweep_recovers_injection():
+@pytest.mark.parametrize("engine", ["gather", "fourier"])
+def test_sweep_recovers_injection(engine):
     dm_true, t0 = 80.0, 700
     freqs, data = make_obs(dm=dm_true, t0=t0)
     dms = np.linspace(0.0, 160.0, 81)  # 2 pc/cm^3 steps
-    res = sweep_spectra(Spectra(freqs, 1e-3, data), dms, nsub=16, group_size=8)
+    res = sweep_spectra(Spectra(freqs, 1e-3, data), dms, nsub=16, group_size=8,
+                        engine=engine)
     best = res.best(1)[0]
     assert abs(best["dm"] - dm_true) <= 4.0
     assert abs(best["sample"] - t0) <= 2
     assert best["snr"] > 15.0
 
 
-def test_chunked_equals_unchunked():
+@pytest.mark.parametrize("engine", ["gather", "fourier"])
+def test_chunked_equals_unchunked(engine):
     freqs, data = make_obs(T=4096)
     dms = np.linspace(0.0, 120.0, 32)
     spec = Spectra(freqs, 1e-3, data)
-    full = sweep_spectra(spec, dms, nsub=16, group_size=8)
-    chunked = sweep_spectra(spec, dms, nsub=16, group_size=8, chunk_payload=1024)
+    full = sweep_spectra(spec, dms, nsub=16, group_size=8, engine=engine)
+    chunked = sweep_spectra(spec, dms, nsub=16, group_size=8,
+                            chunk_payload=1024, engine=engine)
     np.testing.assert_allclose(chunked.snr, full.snr, rtol=1e-4, atol=1e-4)
     np.testing.assert_array_equal(chunked.peak_sample, full.peak_sample)
 
 
-def test_sharded_sweep_matches_single_device():
+@pytest.mark.parametrize("engine", ["gather", "fourier"])
+def test_sharded_sweep_matches_single_device(engine):
     assert len(jax.devices()) == 8, "conftest must provide 8 virtual devices"
     freqs, data = make_obs()
     dms = np.linspace(0.0, 120.0, 64)
     spec = Spectra(freqs, 1e-3, data)
-    single = sweep_spectra(spec, dms, nsub=16, group_size=8)
+    single = sweep_spectra(spec, dms, nsub=16, group_size=8, engine=engine)
     mesh = make_mesh(axis_names=("dm",))
-    sharded = sweep_spectra(spec, dms, nsub=16, group_size=8, mesh=mesh)
+    sharded = sweep_spectra(spec, dms, nsub=16, group_size=8, mesh=mesh,
+                            engine=engine)
     np.testing.assert_allclose(sharded.snr, single.snr, rtol=1e-4, atol=1e-4)
     np.testing.assert_array_equal(sharded.peak_sample, single.peak_sample)
 
@@ -154,7 +162,8 @@ def test_plan_geometry():
     assert plan.stage2_bins[-1].max() >= plan.stage2_bins[0].max()
 
 
-def test_sharded_2d_matches_single_device():
+@pytest.mark.parametrize("engine", ["gather", "fourier"])
+def test_sharded_2d_matches_single_device(engine):
     """dm x time mesh with ppermute halo exchange == single-device result."""
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -172,7 +181,8 @@ def test_sharded_2d_matches_single_device():
     assert overlap < local_payload
 
     fn2d = make_sharded_sweep_chunk_2d(mesh, plan.nsub, local_payload, overlap,
-                                       plan.max_shift2, plan.widths)
+                                       plan.max_shift2, plan.widths,
+                                       engine=engine)
     darr = jax.device_put(jnp.asarray(data), NamedSharding(mesh, P(None, "time")))
     s1 = jax.device_put(jnp.asarray(plan.stage1_bins), NamedSharding(mesh, P("dm")))
     s2 = jax.device_put(jnp.asarray(plan.stage2_bins), NamedSharding(mesh, P("dm")))
@@ -184,7 +194,7 @@ def test_sharded_2d_matches_single_device():
     padded = jnp.pad(jnp.asarray(data), ((0, 0), (0, need - T)))
     s0, ss0, mb0, ab0 = sweep_chunk(
         padded, jnp.asarray(plan.stage1_bins), jnp.asarray(plan.stage2_bins),
-        plan.nsub, out_len, plan.max_shift2, plan.widths, T)
+        plan.nsub, out_len, plan.max_shift2, plan.widths, T, engine=engine)
     np.testing.assert_allclose(np.asarray(s), np.asarray(s0), rtol=1e-4, atol=1e-3)
     np.testing.assert_allclose(np.asarray(ss), np.asarray(ss0), rtol=1e-4, atol=1e-2)
     np.testing.assert_allclose(np.asarray(mb), np.asarray(mb0), rtol=1e-4, atol=1e-3)
@@ -209,37 +219,24 @@ def test_stream_rejects_short_interior_block():
         sweep_stream(plan, bad_blocks(), chunk)
 
 
-def test_chunked_short_remainder():
+@pytest.mark.parametrize("engine", ["gather", "fourier"])
+def test_chunked_short_remainder(engine):
     # T % chunk smaller than min_overlap: the penultimate block is short but
     # contains all remaining data, which is legal (end-of-data padding)
     freqs, data = make_obs(T=3 * 1024 + 32)
     dms = np.linspace(0.0, 120.0, 16)
     spec = Spectra(freqs, 1e-3, data)
-    full = sweep_spectra(spec, dms, nsub=16, group_size=8)
-    chunked = sweep_spectra(spec, dms, nsub=16, group_size=8, chunk_payload=1024)
+    full = sweep_spectra(spec, dms, nsub=16, group_size=8, engine=engine)
+    chunked = sweep_spectra(spec, dms, nsub=16, group_size=8,
+                            chunk_payload=1024, engine=engine)
     np.testing.assert_allclose(chunked.snr, full.snr, rtol=1e-4, atol=1e-4)
 
 
-def test_shift_segment_sum_matches_slice_rows():
-    """The scan-based fused shift+segment-sum equals the vmapped gather."""
-    import jax.numpy as jnp
-    from pypulsar_tpu.parallel.sweep import _shift_segment_sum, _slice_rows
-
-    rng = np.random.RandomState(7)
-    N, L, length, seg = 32, 500, 300, 8
-    rows = jnp.asarray(rng.randn(N, L).astype(np.float32))
-    starts = jnp.asarray(rng.randint(0, L - length, size=N).astype(np.int32))
-    ref = np.asarray(_slice_rows(rows, starts, length)).reshape(
-        N // seg, seg, length).sum(axis=1)
-    got = np.asarray(_shift_segment_sum(rows, starts, length, seg))
-    np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.parametrize("engine", ["scan", "fourier", "tree"])
+@pytest.mark.parametrize("engine", ["fourier"])
 def test_sweep_engine_parity(engine):
-    """Every chunk-kernel engine reproduces the gather formulation."""
+    """The chip's chunk kernel reproduces the gather formulation."""
     import jax.numpy as jnp
-    from pypulsar_tpu.parallel.sweep import _sweep_chunk_impl
+    from pypulsar_tpu.parallel.sweep import _sweep_chunk_impl, sweep_chunk
 
     rng = np.random.RandomState(3)
     C, T, nsub, group = 32, 2048, 8, 4
@@ -255,59 +252,28 @@ def test_sweep_engine_parity(engine):
             jnp.asarray(plan.stage2_bins))
     kw = dict(nsub=plan.nsub, out_len=out_len, slack2=plan.max_shift2,
               widths=plan.widths, stat_len=1024)
-    from pypulsar_tpu.parallel.sweep import sweep_chunk
-
     ref = [np.asarray(x) for x in _sweep_chunk_impl(*args, **kw)]
-    # dispatch through the public wrapper: the tree engine builds its
-    # host merge tables there (a traced impl cannot host them)
     got = [np.asarray(x) for x in sweep_chunk(*args, engine=engine, **kw)]
     for a, b in zip(ref, got):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-4)
 
 
-@pytest.mark.parametrize("mode", ["direct", "lut"])
-def test_fourier_phase_mode_parity(mode):
-    """The factored (default), direct, and lut phase formulations agree to
-    f32 rounding — all share the exact int32-wraparound index math and
-    differ only by one extra complex multiply (~3e-7 relative)."""
-    import jax.numpy as jnp
-    from pypulsar_tpu.ops.fourier_dedisperse import (
-        fourier_chunk_len, sweep_chunk_fourier_impl)
-
-    rng = np.random.RandomState(5)
-    C, nsub, group = 32, 8, 4
-    freqs = 1500.0 - 4.0 * np.arange(C)
-    dms = np.linspace(0.0, 60.0, 8)
-    plan = make_sweep_plan(dms, freqs, 1e-3, nsub=nsub, group_size=group)
-    W = max(plan.widths)
-    out_len = 1024 + W
-    need = out_len + plan.max_shift2 + plan.max_shift1
-    data = jnp.asarray(rng.randn(C, need).astype(np.float32))
-    args = (data, jnp.asarray(plan.stage1_bins),
-            jnp.asarray(plan.stage2_bins), plan.nsub, out_len, plan.widths,
-            1024, fourier_chunk_len(need))
-    kw = dict(max_shift1=plan.max_shift1, max_shift2=plan.max_shift2)
-    ref = [np.asarray(x) for x in
-           sweep_chunk_fourier_impl(*args, phase_mode="factored", **kw)]
-    got = [np.asarray(x) for x in
-           sweep_chunk_fourier_impl(*args, phase_mode=mode, **kw)]
-    for a, b in zip(ref, got):
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-4)
-
-
-def test_sweep_stream_fourier_engine_end_to_end():
-    """Streamed multi-chunk sweep under engine='fourier' matches 'gather'."""
+@pytest.mark.parametrize("T, payload", [(6000, 2048), (6100, 1000)])
+def test_sweep_stream_fourier_engine_end_to_end(T, payload):
+    """Streamed multi-chunk sweep under engine='fourier' matches 'gather',
+    also at a non-power-of-two chunk payload with a trailing partial
+    chunk (6100 / 1000)."""
     from pypulsar_tpu.core.spectra import Spectra
 
     rng = np.random.RandomState(7)
-    C, T = 32, 6000
+    C = 32
     freqs = 1500.0 - 4.0 * np.arange(C)
     data = rng.randn(C, T).astype(np.float32)
     dms = np.linspace(0.0, 60.0, 16)
     spec = Spectra(freqs, 1e-3, data)
-    a = sweep_spectra(spec, dms, nsub=8, group_size=4, chunk_payload=2048,
+    a = sweep_spectra(spec, dms, nsub=8, group_size=4, chunk_payload=payload,
                       engine="gather")
-    b = sweep_spectra(spec, dms, nsub=8, group_size=4, chunk_payload=2048,
+    b = sweep_spectra(spec, dms, nsub=8, group_size=4, chunk_payload=payload,
                       engine="fourier")
     np.testing.assert_allclose(b.snr, a.snr, rtol=1e-4, atol=1e-4)
     np.testing.assert_array_equal(b.peak_sample, a.peak_sample)
@@ -337,9 +303,14 @@ def test_fourier_engine_snr_tolerance():
     assert rel.max() <= 2e-6, f"fourier SNR rel err {rel.max():.2e} > 2e-6"
 
 
-def test_checkpoint_kill_and_resume_bit_exact(tmp_path):
+@pytest.mark.parametrize("engine", ["gather", "fourier"])
+def test_checkpoint_kill_and_resume_bit_exact(tmp_path, engine):
     """A sweep killed mid-stream and resumed from its checkpoint reproduces
-    the uninterrupted result bit-for-bit (VERDICT r2 item 7)."""
+    the uninterrupted result bit-for-bit (VERDICT r2 item 7), and the
+    engine is part of the checkpoint's fingerprint context: a run under
+    the other engine does not resume it."""
+    import shutil
+
     from pypulsar_tpu.parallel.sweep import SweepCheckpoint, sweep_stream
 
     rng = np.random.RandomState(11)
@@ -359,7 +330,7 @@ def test_checkpoint_kill_and_resume_bit_exact(tmp_path):
             pos += payload
 
     ref = sweep_stream(plan, blocks(), payload, chan_major=True,
-                       baseline=baseline)
+                       baseline=baseline, engine=engine)
 
     class Killed(Exception):
         pass
@@ -376,11 +347,26 @@ def test_checkpoint_kill_and_resume_bit_exact(tmp_path):
         # max_pending=1 so at least one chunk drains (and checkpoints)
         # before the stream dies
         sweep_stream(plan, killing_blocks(4), payload, chan_major=True,
-                     baseline=baseline, checkpoint=ckpt, max_pending=1)
+                     baseline=baseline, checkpoint=ckpt, max_pending=1,
+                     engine=engine)
     assert os.path.exists(ck_path), "checkpoint file not written"
 
+    # the other engine, handed a copy of this checkpoint, restarts from
+    # the first sample (the engines differ at f32 rounding, so resumed
+    # accumulators would show) and still equals its own uninterrupted run
+    other = "gather" if engine == "fourier" else "fourier"
+    ck_other = str(tmp_path / "other.ckpt.npz")
+    shutil.copy(ck_path, ck_other)
+    o_ref = sweep_stream(plan, blocks(), payload, chan_major=True,
+                         baseline=baseline, engine=other)
+    o_got = sweep_stream(plan, blocks(), payload, chan_major=True,
+                         baseline=baseline, engine=other,
+                         checkpoint=SweepCheckpoint(ck_other, every=1))
+    np.testing.assert_array_equal(o_got.snr, o_ref.snr)
+    np.testing.assert_array_equal(o_got.mean, o_ref.mean)
+
     res = sweep_stream(plan, blocks(), payload, chan_major=True,
-                       baseline=baseline,
+                       baseline=baseline, engine=engine,
                        checkpoint=SweepCheckpoint(ck_path, every=1))
     np.testing.assert_array_equal(res.snr, ref.snr)
     np.testing.assert_array_equal(res.peak_sample, ref.peak_sample)
@@ -416,7 +402,8 @@ def test_choose_group_size_scales_with_trial_density():
                                 float(freqs.min())) <= 1.0 * dt
 
 
-def test_checkpoint_resume_with_chunk_peaks(tmp_path):
+@pytest.mark.parametrize("engine", ["gather", "fourier"])
+def test_checkpoint_resume_with_chunk_peaks(tmp_path, engine):
     """keep_chunk_peaks persists through a kill-and-resume: the multi-
     event list matches the uninterrupted run exactly, and a checkpoint
     written without peaks is not resumed into a peak run."""
@@ -443,7 +430,8 @@ def test_checkpoint_resume_with_chunk_peaks(tmp_path):
             pos += payload
 
     ref = sweep_stream(plan, blocks(), payload, chan_major=True,
-                       baseline=baseline, keep_chunk_peaks=True)
+                       baseline=baseline, keep_chunk_peaks=True,
+                       engine=engine)
     ref_events = ref.events(5.0)
     assert len({e["sample"] // payload for e in ref_events}) >= 2
 
@@ -461,11 +449,12 @@ def test_checkpoint_resume_with_chunk_peaks(tmp_path):
         sweep_stream(plan, killing_blocks(3), payload, chan_major=True,
                      baseline=baseline, keep_chunk_peaks=True,
                      checkpoint=SweepCheckpoint(ck, every=1),
-                     max_pending=1)
+                     max_pending=1, engine=engine)
     assert os.path.exists(ck)
     res = sweep_stream(plan, blocks(), payload, chan_major=True,
                        baseline=baseline, keep_chunk_peaks=True,
-                       checkpoint=SweepCheckpoint(ck, every=1))
+                       checkpoint=SweepCheckpoint(ck, every=1),
+                       engine=engine)
     np.testing.assert_array_equal(res.chunk_snr, ref.chunk_snr)
     np.testing.assert_array_equal(res.chunk_sample, ref.chunk_sample)
     assert res.events(5.0) == ref_events
@@ -476,14 +465,16 @@ def test_checkpoint_resume_with_chunk_peaks(tmp_path):
         sweep_stream(plan, killing_blocks(3), payload, chan_major=True,
                      baseline=baseline,
                      checkpoint=SweepCheckpoint(ck2, every=1),
-                     max_pending=1)
+                     max_pending=1, engine=engine)
     res2 = sweep_stream(plan, blocks(), payload, chan_major=True,
                         baseline=baseline, keep_chunk_peaks=True,
-                        checkpoint=SweepCheckpoint(ck2, every=1))
+                        checkpoint=SweepCheckpoint(ck2, every=1),
+                        engine=engine)
     np.testing.assert_array_equal(res2.chunk_snr, ref.chunk_snr)
 
 
-def test_checkpoint_fingerprint_mismatch_restarts(tmp_path):
+@pytest.mark.parametrize("engine", ["gather", "fourier"])
+def test_checkpoint_fingerprint_mismatch_restarts(tmp_path, engine):
     """A checkpoint from different sweep parameters is ignored."""
     from pypulsar_tpu.parallel.sweep import SweepCheckpoint, sweep_stream
 
@@ -506,14 +497,18 @@ def test_checkpoint_fingerprint_mismatch_restarts(tmp_path):
 
     ck = str(tmp_path / "x.npz")
     sweep_stream(plan_a, blocks(plan_a), payload, chan_major=True,
-                 checkpoint=SweepCheckpoint(ck, every=1, cleanup=False))
-    ref_b = sweep_stream(plan_b, blocks(plan_b), payload, chan_major=True)
+                 checkpoint=SweepCheckpoint(ck, every=1, cleanup=False),
+                 engine=engine)
+    ref_b = sweep_stream(plan_b, blocks(plan_b), payload, chan_major=True,
+                         engine=engine)
     got_b = sweep_stream(plan_b, blocks(plan_b), payload, chan_major=True,
-                         checkpoint=SweepCheckpoint(ck, every=1))
+                         checkpoint=SweepCheckpoint(ck, every=1),
+                         engine=engine)
     np.testing.assert_array_equal(got_b.snr, ref_b.snr)
 
 
-def test_ddplan_staged_checkpoint_resume(tmp_path):
+@pytest.mark.parametrize("engine", ["gather", "fourier"])
+def test_ddplan_staged_checkpoint_resume(tmp_path, engine):
     """Killing a staged DDplan sweep mid-plan resumes completed steps from
     their done markers and reproduces the uninterrupted result."""
     from pypulsar_tpu.core.spectra import Spectra
@@ -531,7 +526,8 @@ def test_ddplan_staged_checkpoint_resume(tmp_path):
     plan = obs.gen_ddplan(0.0, 400.0)
     assert len(plan.DDsteps) >= 2, "test needs a multi-step plan"
 
-    ref = staged.sweep_ddplan(spec, plan, nsub=8, group_size=4)
+    ref = staged.sweep_ddplan(spec, plan, nsub=8, group_size=4,
+                              engine=engine)
 
     base = str(tmp_path / "stg")
     # interrupt after the first step by making the second step fail once
@@ -548,13 +544,13 @@ def test_ddplan_staged_checkpoint_resume(tmp_path):
     try:
         with pytest.raises(KeyboardInterrupt):
             staged.sweep_ddplan(spec, plan, nsub=8, group_size=4,
-                                checkpoint_path=base)
+                                checkpoint_path=base, engine=engine)
     finally:
         staged._run_step = orig
     assert os.path.exists(base + ".step0.done.npz")
 
     got = staged.sweep_ddplan(spec, plan, nsub=8, group_size=4,
-                              checkpoint_path=base)
+                              checkpoint_path=base, engine=engine)
     assert len(got.steps) == len(ref.steps)
     for sa, sb in zip(got.steps, ref.steps):
         np.testing.assert_array_equal(sa.result.snr, sb.result.snr)
@@ -563,7 +559,8 @@ def test_ddplan_staged_checkpoint_resume(tmp_path):
     assert not os.path.exists(base + ".step0.done.npz"), "markers not cleared"
 
 
-def test_sweep_resident_matches_streamed():
+@pytest.mark.parametrize("engine", ["gather", "fourier"])
+def test_sweep_resident_matches_streamed(engine):
     """The single-dispatch resident sweep is bit-identical to the streamed
     path at the same chunking (same per-chunk kernels, same host-order
     f64 accumulation)."""
@@ -573,15 +570,16 @@ def test_sweep_resident_matches_streamed():
     dms = np.linspace(0.0, 120.0, 32)
     spec = Spectra(freqs, 1e-3, data)
     streamed = sweep_spectra(spec, dms, nsub=16, group_size=8,
-                             chunk_payload=1024)
+                             chunk_payload=1024, engine=engine)
     resident = sweep_resident(spec, dms, nsub=16, group_size=8,
-                              chunk_payload=1024)
+                              chunk_payload=1024, engine=engine)
     np.testing.assert_array_equal(resident.snr, streamed.snr)
     np.testing.assert_array_equal(resident.peak_sample, streamed.peak_sample)
     np.testing.assert_array_equal(resident.mean, streamed.mean)
 
 
-def test_sweep_resident_sharded_matches():
+@pytest.mark.parametrize("engine", ["gather", "fourier"])
+def test_sweep_resident_sharded_matches(engine):
     from pypulsar_tpu.parallel.sweep import sweep_resident
 
     freqs, data = make_obs(T=4096)
@@ -589,9 +587,9 @@ def test_sweep_resident_sharded_matches():
     spec = Spectra(freqs, 1e-3, data)
     mesh = make_mesh(axis_names=("dm",))
     single = sweep_resident(spec, dms, nsub=16, group_size=8,
-                            chunk_payload=2048)
+                            chunk_payload=2048, engine=engine)
     sharded = sweep_resident(spec, dms, nsub=16, group_size=8,
-                             chunk_payload=2048, mesh=mesh)
+                             chunk_payload=2048, mesh=mesh, engine=engine)
     np.testing.assert_allclose(sharded.snr, single.snr, rtol=1e-4, atol=1e-4)
     np.testing.assert_array_equal(sharded.peak_sample, single.peak_sample)
 
@@ -666,7 +664,8 @@ def test_bench_peaks_table_refuses_unknown_device_kind():
         bench.device_hbm_bytes(NoStats())
 
 
-def test_multi_event_chunk_peaks():
+@pytest.mark.parametrize("engine", ["gather", "fourier"])
+def test_multi_event_chunk_peaks(engine):
     """keep_chunk_peaks records one event per (chunk, trial, width): two
     injected pulses in different chunks both appear in events(), while the
     single-best fields keep only the stronger."""
@@ -697,7 +696,8 @@ def test_multi_event_chunk_peaks():
             pos += payload
 
     res = sweep_stream(plan, blocks(), payload, chan_major=True,
-                       baseline=baseline, keep_chunk_peaks=True)
+                       baseline=baseline, keep_chunk_peaks=True,
+                       engine=engine)
     events = res.events(8.0)
     assert events
     # both pulses present at a near-true DM
@@ -711,50 +711,21 @@ def test_multi_event_chunk_peaks():
 
     # without the flag, events() refuses
     res2 = sweep_stream(plan, blocks(), payload, chan_major=True,
-                        baseline=baseline)
+                        baseline=baseline, engine=engine)
     with pytest.raises(ValueError):
         res2.events(8.0)
 
 
-# ---------------------------------------------------------------------------
-# tree dedispersion engine (round 16): exact-shift merge tree + snap
-# ---------------------------------------------------------------------------
-
-
-def test_tree_engine_snr_tolerance():
-    """The tree engine's PUBLISHED parity contract, pinned at the SAME
-    contract geometry as test_fourier_engine_snr_tolerance: engine=
-    'gather' is the bit-exact-SNR reference; the tree engine's balanced
-    pairwise summation agrees to <=2e-6 relative SNR (measured ~1.0e-6
-    here — tighter than the fourier engine's 2.0e-6 at this geometry,
-    because the per-channel shifts are byte-for-bit the same s1+s2 and
-    only the f32 add ORDER differs)."""
-    from pypulsar_tpu.core.spectra import Spectra
-
-    rng = np.random.RandomState(19)
-    C, T = 64, 8192
-    freqs = 1500.0 - 2.0 * np.arange(C)
-    data = rng.randn(C, T).astype(np.float32)
-    data[:, 4000:4004] += 4.0  # a real pulse so peak SNRs are O(10)
-    dms = np.linspace(0.0, 80.0, 32)
-    spec = Spectra(freqs, 1e-3, data)
-    a = sweep_spectra(spec, dms, nsub=16, group_size=8, engine="gather")
-    b = sweep_spectra(spec, dms, nsub=16, group_size=8, engine="tree")
-    rel = np.abs(b.snr - a.snr) / np.maximum(np.abs(a.snr), 1.0)
-    assert rel.max() <= 2e-6, f"tree SNR rel err {rel.max():.2e} > 2e-6"
-    np.testing.assert_array_equal(b.peak_sample, a.peak_sample)
-
-
-def test_tree_exact_shift_snap():
-    """The tentpole's exactness claim: every trial's tree series applies
-    BYTE-FOR-BIT the same per-channel integer shift s1+s2 the direct
-    engine applies — checked against an f64 direct-shift sum (agreement
-    at f32 rounding of the SUM, with zero shift/index error: a
+@pytest.mark.parametrize("engine", ["gather", "fourier"])
+def test_series_chunk_exact_shift(engine):
+    """Every trial's series applies exactly the per-channel integer shift
+    s1+s2 of the plan — checked against an f64 direct-shift sum
+    (agreement at f32 rounding of the SUM, with zero shift/index error: a
     one-sample shift slip would show up as O(1) differences)."""
     from pypulsar_tpu.parallel.sweep import dedisperse_series_chunk
 
     rng = np.random.RandomState(7)
-    C, nsub, group = 48, 8, 4  # non-pow2 nchan: odd-carry merge levels
+    C, nsub, group = 48, 8, 4  # non-pow2 nchan
     freqs = 1500.0 - 4.0 * np.arange(C)
     dms = np.linspace(0.0, 60.0, 10)  # pads to 12 trials
     plan = make_sweep_plan(dms, freqs, 1e-3, nsub=nsub, group_size=group)
@@ -763,7 +734,7 @@ def test_tree_exact_shift_snap():
     data = rng.randn(C, need).astype(np.float32)
     got = np.asarray(dedisperse_series_chunk(
         data, plan.stage1_bins, plan.stage2_bins, plan.nsub, out_len,
-        plan.max_shift2, "tree"))
+        plan.max_shift2, engine))
     per = C // plan.nsub
     tot = (plan.stage1_bins[:, None, :]
            + np.repeat(plan.stage2_bins, per, axis=2)).reshape(-1, C)
@@ -775,226 +746,51 @@ def test_tree_exact_shift_snap():
         np.testing.assert_allclose(got[d], exact, rtol=2e-5, atol=2e-4)
 
 
-def test_tree_streamed_nonpow2_chunks_match_gather():
-    """Streamed multi-chunk tree sweep — non-power-of-two chunk payload
-    AND a trailing partial chunk — matches the gather engine within the
-    engine-parity tolerance, with identical peak samples."""
-    from pypulsar_tpu.core.spectra import Spectra
-
-    rng = np.random.RandomState(7)
-    C, T = 32, 6100  # 6100 / 1000 -> trailing partial chunk
-    freqs = 1500.0 - 4.0 * np.arange(C)
-    data = rng.randn(C, T).astype(np.float32)
-    dms = np.linspace(0.0, 60.0, 16)
-    spec = Spectra(freqs, 1e-3, data)
-    a = sweep_spectra(spec, dms, nsub=8, group_size=4, chunk_payload=1000,
-                      engine="gather")
-    b = sweep_spectra(spec, dms, nsub=8, group_size=4, chunk_payload=1000,
-                      engine="tree")
-    np.testing.assert_allclose(b.snr, a.snr, rtol=1e-4, atol=1e-4)
-    np.testing.assert_array_equal(b.peak_sample, a.peak_sample)
-    np.testing.assert_allclose(b.mean, a.mean, rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.parametrize("n_dms", [64, 44])
-def test_tree_sharded_bit_identical(n_dms):
-    """'dm'-mesh tree sweep is BIT-identical to the unsharded tree sweep
-    — a per-trial row's merge structure is fixed, so per-device tables
-    cannot change any value (a stronger contract than the other engines'
-    allclose). n_dms=44 with group 8 exercises the 6-groups-on-4-devices
-    padding case."""
-    import jax
-
-    assert len(jax.devices()) == 8, "conftest must provide 8 virtual devices"
-    freqs, data = make_obs()
-    dms = np.linspace(0.0, 120.0, n_dms)
-    spec = Spectra(freqs, 1e-3, data)
-    single = sweep_spectra(spec, dms, nsub=16, group_size=8, engine="tree")
-    mesh = make_mesh([4], ("dm",), devices=jax.devices()[:4])
-    sharded = sweep_spectra(spec, dms, nsub=16, group_size=8,
-                            engine="tree", mesh=mesh)
-    np.testing.assert_array_equal(sharded.snr, single.snr)
-    np.testing.assert_array_equal(sharded.peak_sample, single.peak_sample)
-    np.testing.assert_array_equal(sharded.mean, single.mean)
-
-
-def test_tree_checkpoint_kill_and_resume_bit_exact(tmp_path):
-    """Kill+resume under engine='tree' reproduces the uninterrupted
-    result bit-for-bit through the EXISTING checkpoint machinery (the
-    engine is part of the checkpoint fingerprint context, so a tree
-    checkpoint can only resume a tree run)."""
-    from pypulsar_tpu.parallel.sweep import SweepCheckpoint, sweep_stream
-
-    rng = np.random.RandomState(11)
-    C, T, payload = 32, 9000, 2048
-    freqs = 1500.0 - 4.0 * np.arange(C)
-    data = rng.randn(C, T).astype(np.float32)
-    dms = np.linspace(0.0, 60.0, 16)
-    plan = make_sweep_plan(dms, freqs, 1e-3, nsub=8, group_size=4)
-    baseline = data.mean(axis=1, keepdims=True).astype(np.float32)
-
-    def blocks():
-        ov = plan.min_overlap
-        pos = 0
-        while pos < T:
-            n = min(payload + ov, T - pos)
-            yield pos, data[:, pos:pos + n]
-            pos += payload
-
-    ref = sweep_stream(plan, blocks(), payload, chan_major=True,
-                       baseline=baseline, engine="tree")
-
-    class Killed(Exception):
-        pass
-
-    def killing_blocks(n_before_kill):
-        for i, (pos, blk) in enumerate(blocks()):
-            if i >= n_before_kill:
-                raise Killed()
-            yield pos, blk
-
-    ck_path = str(tmp_path / "tree.ckpt.npz")
-    with pytest.raises(Killed):
-        sweep_stream(plan, killing_blocks(3), payload, chan_major=True,
-                     baseline=baseline, engine="tree", max_pending=1,
-                     checkpoint=SweepCheckpoint(ck_path, every=1))
-    assert os.path.exists(ck_path)
-    # a GATHER run must NOT resume the tree checkpoint (engine is in the
-    # fingerprint context) — it restarts and still matches its own ref
-    g_ref = sweep_stream(plan, blocks(), payload, chan_major=True,
-                         baseline=baseline, engine="gather")
-    g_got = sweep_stream(plan, blocks(), payload, chan_major=True,
-                         baseline=baseline, engine="gather",
-                         checkpoint=SweepCheckpoint(ck_path, every=1,
-                                                    cleanup=False))
-    np.testing.assert_array_equal(g_got.snr, g_ref.snr)
-    res = sweep_stream(plan, blocks(), payload, chan_major=True,
-                       baseline=baseline, engine="tree",
-                       checkpoint=SweepCheckpoint(ck_path, every=1))
-    np.testing.assert_array_equal(res.snr, ref.snr)
-    np.testing.assert_array_equal(res.peak_sample, ref.peak_sample)
-    np.testing.assert_array_equal(res.mean, ref.mean)
-
-
-def test_tree_plan_structure_and_cache():
-    """TreePlan structural invariants: exact add accounting beats the
-    two-stage direct count at a dense trial grid, the level count is
-    ceil(log2(nchan)) with odd carries, and the digest cache returns the
-    SAME object for repeated (even device-array) table inputs."""
-    import jax.numpy as jnp
-
-    from pypulsar_tpu.ops.tree_dedisperse import plan_from_bins
-
-    C = 64
-    freqs = 1500.0 - 2.0 * np.arange(C)
-    dms = np.linspace(0.0, 120.0, 256)  # dense: heavy profile sharing
-    plan = make_sweep_plan(dms, freqs, 1e-3, nsub=16, group_size=8)
-    tp = plan_from_bins(plan.stage1_bins, plan.stage2_bins)
-    assert tp.n_levels == 6  # ceil(log2(64))
-    assert len(tp.rows_per_level) == tp.n_levels
-    assert tp.rows == max(C, max(tp.rows_per_level))
-    G, g, S = plan.stage2_bins.shape
-    direct_adds = G * (C - S) + plan.n_trials * (S - 1)
-    assert 0 < tp.adds_per_sample < direct_adds
-    # snap offsets: within the exact total-shift bound, and the top
-    # reference channel pins the minimum at zero
-    assert tp.trial_off.min() == 0
-    assert tp.trial_off.max() <= tp.pad
-    # digest cache: same tables -> same plan object, device arrays too
-    assert plan_from_bins(plan.stage1_bins, plan.stage2_bins) is tp
-    assert plan_from_bins(jnp.asarray(plan.stage1_bins),
-                          jnp.asarray(plan.stage2_bins)) is tp
-
-
-def test_tree_engine_guards():
-    """The tree engine's explicit non-goals fail loudly: the resident
-    single-program sweep, the dm x time 2-D mesh, and a traced
-    _sweep_chunk_impl all raise instead of silently falling back."""
+def test_chunk_kernels_take_a_resolved_engine():
+    """'auto' is decided once, at a public entry (resolve_engine); a
+    chunk kernel handed 'auto' or a deleted engine's name raises and
+    never picks a formulation inside a trace."""
     from pypulsar_tpu.parallel.sweep import (
+        ENGINES,
+        _dedisperse_series_impl,
         _sweep_chunk_impl,
-        make_sharded_sweep_chunk_2d,
-        sweep_resident,
+        resolve_engine,
     )
 
-    freqs, data = make_obs(T=2048)
-    dms = np.linspace(0.0, 120.0, 16)
-    spec = Spectra(freqs, 1e-3, data)
-    with pytest.raises(ValueError, match="streamed"):
-        sweep_resident(spec, dms, nsub=16, group_size=8, engine="tree")
-    mesh = make_mesh([4, 2], ("dm", "time"))
-    plan = make_sweep_plan(dms, freqs, 1e-3, nsub=16, group_size=8,
-                           pad_groups_to=4)
-    with pytest.raises(ValueError, match="1-D 'dm' mesh"):
-        make_sharded_sweep_chunk_2d(mesh, plan.nsub, 1024,
-                                    plan.min_overlap, plan.max_shift2,
-                                    plan.widths, engine="tree")
-    with pytest.raises(ValueError, match="traced"):
-        _sweep_chunk_impl(np.zeros((4, 64), np.float32),
-                          plan.stage1_bins, plan.stage2_bins, nsub=16,
-                          out_len=32, slack2=0, widths=(1,), stat_len=32,
-                          engine="tree")
+    assert ENGINES == ("gather", "fourier")
+    assert resolve_engine("auto") == "gather"  # the tests' CPU backend
+    plan = make_sweep_plan(np.linspace(0.0, 120.0, 16),
+                           1500.0 - 2.0 * np.arange(64), 1e-3, nsub=16,
+                           group_size=8)
+    data = np.zeros((64, 64), np.float32)
+    for engine in "auto scan tree".split():
+        with pytest.raises(ValueError, match="resolved engine"):
+            _sweep_chunk_impl(data, plan.stage1_bins, plan.stage2_bins,
+                              nsub=16, out_len=32, slack2=0, widths=(1,),
+                              stat_len=32, engine=engine)
+        with pytest.raises(ValueError, match="resolved engine"):
+            _dedisperse_series_impl(data, plan.stage1_bins,
+                                    plan.stage2_bins, 16, 32, 0, engine)
+    with pytest.raises(ValueError, match="unknown sweep engine"):
+        resolve_engine("tree")
 
 
-def test_cli_engine_validation(tmp_path, capsys):
+@pytest.mark.parametrize("bad", "fourrier scan tree".split())
+def test_cli_engine_validation(bad, capsys):
     """--engine is validated at ARGPARSE time against the ENGINES
     registry with a difflib closest-match hint (the cli/__main__
-    unknown-tool pattern), and PYPULSAR_TPU_SWEEP_ENGINE gets the same
-    early validation — neither reaches resolve_engine mid-run."""
+    unknown-tool pattern) and never reaches resolve_engine mid-run; the
+    engines that left in PR 29 are refused like any unknown name."""
     from pypulsar_tpu.cli import sweep as cli_sweep
 
     with pytest.raises(SystemExit) as e:
-        cli_sweep.main(["x.fil", "--numdms", "4", "--engine", "fourrier"])
+        cli_sweep.main(["x.fil", "--numdms", "4", "--engine", bad])
     assert e.value.code == 2
     err = capsys.readouterr().err
-    assert "did you mean 'fourier'?" in err
-    assert "tree" in err  # the registry listing includes the new engine
-
-    os.environ["PYPULSAR_TPU_SWEEP_ENGINE"] = "tre"
-    try:
-        with pytest.raises(SystemExit) as e:
-            cli_sweep.main(["x.fil", "--numdms", "4"])
-        assert e.value.code == 2
-        assert "did you mean 'tree'?" in capsys.readouterr().err
-        # an explicit (valid) --engine never consults the env knob, so
-        # the typo must NOT abort such a run at the parse stage: the run
-        # proceeds PAST argparse and the env check, and dies only when
-        # the (nonexistent) input is opened — anything but exit 2
-        with pytest.raises(Exception) as e:
-            cli_sweep.main(["x.fil", "--numdms", "4", "--engine",
-                            "gather"])
-        assert not isinstance(e.value, SystemExit)
-        assert "SWEEP_ENGINE" not in capsys.readouterr().err
-    finally:
-        del os.environ["PYPULSAR_TPU_SWEEP_ENGINE"]
-
-
-def test_dedisp_roofline_tool():
-    """tools/dedisp_roofline.py (round 16): the structural work
-    accounting behind the BENCHNOTES complexity claims — tree adds/cell
-    beat the two-stage direct engine at a dense grid and grow ~log2
-    with nchan at a fixed DM grid while naive grows ~nchan."""
-    import importlib.util
-    import os as _os
-
-    spec = importlib.util.spec_from_file_location(
-        "dedisp_roofline", _os.path.join(
-            _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
-            "tools", "dedisp_roofline.py"))
-    roof = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(roof)
-
-    dm = roof.diagonal_dm(128, 64e-6, 1500.0, 300.0)
-    rec = roof.analyze(128, 256, 4096, dm, nsub=32, group_size=16)
-    a = rec["adds_per_cell"]
-    assert a["tree"] < a["direct_two_stage"] < a["naive"]
-    assert rec["tree"]["merge_levels"] == 7  # ceil(log2(128))
-    assert sum(rec["tree"]["rows_per_level"]) \
-        >= rec["tree"]["adds_per_sample_all_trials"]
-    s = roof.scaling_sweep([64, 128, 256], 256, 4096, dm, 32, 16,
-                           64e-6, 1500.0, 300.0)
-    g = s["growth"]
-    assert g["naive"] > 3.5  # ~nchan over a 4x range
-    assert g["tree"] < 2.0   # ~log2(nchan)
+    assert f"unknown sweep engine {bad!r}" in err
+    assert "auto, gather, fourier" in err  # the registry listing
+    if bad == "fourrier":
+        assert "did you mean 'fourier'?" in err
 
 
 def test_default_chunk_payload_bounds():

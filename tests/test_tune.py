@@ -130,15 +130,13 @@ def test_chunk_knob_resolves_pow2(monkeypatch):
 
 def test_fourier_engine_excludes_chunk_from_search(monkeypatch):
     """Measured (round 17): .dat bytes are chunk-length-invariant for
-    gather/tree but NOT for fourier (FFT rounding is chunk-length-
+    gather but NOT for fourier (FFT rounding is chunk-length-
     dependent, the fact staged.py fingerprints). The searcher must
     therefore never move the chunk under fourier."""
     monkeypatch.delenv("PYPULSAR_TPU_SWEEP_CHUNK", raising=False)
     gather = {k.env for k in knobs.searchable_knobs("sweep", "gather")}
-    tree = {k.env for k in knobs.searchable_knobs("sweep", "tree")}
     fourier = {k.env for k in knobs.searchable_knobs("sweep", "fourier")}
     assert "PYPULSAR_TPU_SWEEP_CHUNK" in gather
-    assert "PYPULSAR_TPU_SWEEP_CHUNK" in tree
     assert "PYPULSAR_TPU_SWEEP_CHUNK" not in fourier
 
 
@@ -274,7 +272,7 @@ def test_cache_roundtrip_and_key_components(cache):
             tune.make_key("sweep", nchan=64, nsamp=60000,
                           dtype="nbits8", engine="gather"),
             tune.make_key("sweep", nchan=64, nsamp=60000,
-                          dtype="nbits32", engine="tree"),
+                          dtype="nbits32", engine="fourier"),
             tune.make_key("accel", nchan=64, nsamp=60000,
                           dtype="nbits32", engine="gather"),
     ):
