@@ -21,7 +21,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from pypulsar_tpu.core.psrmath import DM_CONST_INV
 from pypulsar_tpu.tune import knobs
@@ -277,27 +276,103 @@ def scaled2(data, indep=False):
     return (data - mn) / mx
 
 
+def _flip_negative(i):
+    """Swap float32 bit patterns (as int32) with their order keys: the
+    int32 whose signed order is the floats' (IEEE totalOrder: -NaN < -inf
+    < ... < -0.0 < +0.0 < ... < +inf < NaN). A non-negative float's bits
+    already order; a negative one's order backwards, so its magnitude
+    bits flip. The map is its own inverse."""
+    return jnp.where(i < 0, i ^ jnp.int32(0x7FFFFFFF), i)
+
+
+def _f32_order_key(x):
+    return _flip_negative(jax.lax.bitcast_convert_type(x, jnp.int32))
+
+
+def _select_middle_f32(data):
+    """(sorted[(T-1)//2], sorted[T//2]) of each float32 row of data[C, T],
+    with no sort: an exact selection on the rows' order keys.
+
+    The k-th smallest key is built from its top bit down: with a prefix
+    decided, the next bit is set iff at most k keys lie under prefix|bit,
+    and that count is one fused compare-and-count reduction over the
+    block (the keys are recomputed from ``data`` inside it, never
+    stored): 32 passes that each read the block once, at the memory's
+    pace. The upper middle of an even row comes from the lower by one
+    more pass: the lower again if its ties reach past k, else the least
+    key above it."""
+    C, T = data.shape
+    k = (T - 1) // 2
+    sign = jnp.uint32(0x80000000)
+
+    def as_signed(u):  # unsigned rank order -> the keys' signed order
+        return jax.lax.bitcast_convert_type(u ^ sign, jnp.int32)
+
+    def decide(i, prefix):
+        trial = prefix | (sign >> i.astype(jnp.uint32))
+        below = (_f32_order_key(data) < as_signed(trial)[:, None]).sum(
+            axis=-1, dtype=jnp.int32)
+        return jnp.where(below <= k, trial, prefix)
+
+    lo = as_signed(jax.lax.fori_loop(0, 32, decide,
+                                     jnp.zeros((C,), jnp.uint32)))
+    if T % 2:
+        hi = lo
+    else:
+        key = _f32_order_key(data)
+        at_most = (key <= lo[:, None]).sum(axis=-1, dtype=jnp.int32)
+        above = jnp.where(key > lo[:, None], key,
+                          jnp.iinfo(jnp.int32).max).min(axis=-1)
+        hi = jnp.where(at_most > k + 1, lo, above)
+    return tuple(jax.lax.bitcast_convert_type(_flip_negative(key), jnp.float32)
+                 for key in (lo, hi))
+
+
+def row_median(data):
+    """Median of each row of data[C, T], value for value ``np.median``:
+    the middle order statistic, or ``lo*0.5 + hi*0.5`` of the two middle
+    ones as ``jnp.median`` combines them, and NaN for a row that holds
+    one. (``jnp.median`` weighs an odd row's middle as ``v*1 + v*0`` and
+    so reads NaN where the median is infinite; this reads the infinity,
+    as NumPy does.) A zero median reads +0.0 whichever zeros the row
+    holds.
+
+    float32 rows are selected from, not sorted (:func:`_select_middle_f32`):
+    of a sorted row the median uses one or two elements, and a block of
+    the sweep is 1024 rows of 2^18. Any other dtype takes ONE sort and
+    indexes its middle. The choice reads the dtype alone."""
+    T = data.shape[-1]
+    if data.dtype == jnp.float32:
+        lo, hi = _select_middle_f32(data)
+        has_nan = jnp.isnan(data).any(axis=-1)
+    else:
+        if not jnp.issubdtype(data.dtype, jnp.inexact):
+            data = data.astype(jnp.float32)
+        srt = jnp.sort(data, axis=-1)  # NaNs sort last
+        lo, hi = srt[:, (T - 1) // 2], srt[:, T // 2]
+        has_nan = jnp.isnan(srt[:, -1])
+    med = lo if T % 2 else lo * 0.5 + hi * 0.5
+    med = jnp.where(med == 0, jnp.zeros_like(med), med)
+    return jnp.where(has_nan, jnp.asarray(jnp.nan, med.dtype), med)
+
+
 def channel_maskvals(data, maskval="median-mid80"):
     """Per-channel fill value for masking (reference formats/spectra.py:211-224).
 
     'median-mid80': median of the channel with top & bottom 10% of sorted
-    samples removed (n = round(0.1*T); full median when n rounds to 0).
+    samples removed (n = round(0.1*T)). The trim is symmetric, so the
+    middle of sorted[n:T-n] is the middle of sorted[0:T]: elements
+    (T-1)//2 and T//2 of the same sorted row either way. The trimmed
+    median IS the plain median, and both are :func:`row_median` (a row
+    that holds a NaN reads NaN under either name; the sweep's dataguard
+    lets none in).
     """
-    C, T = data.shape
     if maskval == "mean":
         return jnp.mean(data, axis=-1)
-    if maskval == "median":
-        return jnp.median(data, axis=-1)
-    if maskval == "median-mid80":
-        n = int(np.round(0.1 * T))
-        if n == 0:
-            return jnp.median(data, axis=-1)
-        # the two sorts of the mask fill (median re-sorts the middle)
-        with jax.named_scope("mask.sort_mid80"):
-            srt = jnp.sort(data, axis=-1)[:, n:-n]
-        with jax.named_scope("mask.median"):
-            return jnp.median(srt, axis=-1)
-    return jnp.full((C,), maskval, dtype=data.dtype)
+    if maskval in ("median", "median-mid80"):
+        with jax.named_scope("mask.select_median"):
+            return row_median(data)
+    return jnp.full(data.shape[:1], maskval, dtype=data.dtype)
 
 
 @partial(jax.jit, static_argnames=("maskval",))

@@ -306,6 +306,7 @@ class _MaskedSource:
                 # off), so pos + arange(L) would overflow for positions
                 # past 2^31 samples; base + (rem + arange(L)) // pts is
                 # exact for any file length (rem < pts, base < nint)
+                telemetry.counter("mask.fill_blocks")
                 block = _masked_block(
                     transfer.ship(block, jnp.float32), self._table,
                     min(pos // self._pts, nint - 1), pos % self._pts,

@@ -164,6 +164,35 @@ def test_fourier_series_chunk(one_chip):
     assert temp + args < V5E_HBM_BYTES
 
 
+def test_mask_fill_selects_without_a_sort(one_chip):
+    """The sweep stage's mask fill at the streamed block's shape (one
+    chunk: payload + overlap samples of every channel, 34 one-second
+    intervals of a 2^19-sample pointing): the per-channel median is an
+    exact selection, so neither what is lowered nor what the chip's
+    compiler makes of it holds a sort (two of them were a third of the
+    survey cell's device time, PERF.md PR 30)."""
+    import re
+
+    from pypulsar_tpu.parallel.staged import _masked_block
+
+    plan, payload, _out_len, need = _sweep_geometry()
+    assert payload + plan.min_overlap == need
+    pts = int(round(1.0 / TSAMP))
+    lowered = _masked_block._jit.lower(
+        _sds((NCHAN, need), jnp.float32, one_chip),
+        _sds((34, NCHAN), jnp.bool_, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
+        pts=pts)
+    text = lowered.as_text()
+    assert "stablehlo.while" in text and "stablehlo.sort" not in text
+    compiled = lowered.compile()
+    assert not re.search(r"\bsort\(", compiled.as_text())
+    temp, args = _device_bytes(compiled)
+    out = compiled.memory_analysis().output_size_in_bytes
+    assert out == 4 * NCHAN * need
+    assert temp + args + out < V5E_HBM_BYTES / 2
+
+
 def test_rfifind_block_stats(one_chip):
     from pypulsar_tpu.ops.fourier_dedisperse import fourier_chunk_len
     from pypulsar_tpu.ops.rfifind import _block_stats_impl

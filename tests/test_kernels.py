@@ -113,6 +113,61 @@ def test_masked_parity(maskval):
     np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5)
 
 
+def _maskval_rows(kind, T):
+    rng = np.random.RandomState(1000 + T)
+    x = (rng.randn(4, T) * 50).astype(np.float32)
+    if kind == "2bit":  # what a 2-bit pointing widens to: heavy ties
+        x = rng.randint(0, 4, size=(4, T)).astype(np.float32)
+    elif kind == "constant":
+        x[:] = 2.5
+    elif kind == "signed-zeros":
+        x = rng.randint(-1, 2, size=(4, T)).astype(np.float32)
+        x[x == 0] = rng.choice(np.float32([-0.0, 0.0]), size=(x == 0).sum())
+    elif kind == "inf":
+        x[:, 0] = np.inf
+        x[1::2, -1] = -np.inf  # T == 1: rows of -inf and of +inf
+    elif kind == "nan":
+        x[1, T // 2] = np.nan
+    return x
+
+
+@pytest.mark.parametrize("maskval", ["median", "median-mid80"])
+@pytest.mark.parametrize(
+    "kind", ["normal", "2bit", "constant", "signed-zeros", "inf", "nan"])
+@pytest.mark.parametrize("T", [1, 2, 9, 10, 11, 1000, 4097])
+def test_channel_maskvals_selects_the_sorted_middle(T, kind, maskval):
+    """The fill value is selected, not sorted for, and keeps its bits:
+    equal to the float32 median of the sorted, trimmed row for odd and
+    even lengths, with and without a trim (n = 0 up to T = 4), under
+    ties, infinities and signed zeros; a row holding a NaN reads NaN."""
+    x = _maskval_rows(kind, T)
+    n = int(np.round(0.1 * T)) if maskval == "median-mid80" else 0
+    with np.errstate(invalid="ignore"):  # the mean of -inf and +inf
+        want = np.median(np.sort(x, axis=-1)[:, n:T - n], axis=-1)
+    assert want.dtype == np.float32
+    want[np.isnan(x).any(axis=-1)] = np.nan
+    got = np.asarray(kernels.channel_maskvals(jnp.asarray(x), maskval))
+    assert got.dtype == np.float32
+    np.testing.assert_array_equal(got, want)
+    if kind == "nan":
+        assert np.isnan(got[1]) and not np.isnan(got[[0, 2, 3]]).any()
+
+
+@pytest.mark.parametrize("dtype", [jnp.int32, jnp.bfloat16])
+def test_channel_maskvals_other_dtypes_take_one_sort(dtype):
+    """Any dtype but float32 sorts once and reads the middle of that."""
+    import jax
+
+    x = np.random.RandomState(3).randint(0, 50, size=(4, 10))
+    fn = jax.jit(lambda d: kernels.channel_maskvals(d, "median-mid80"))
+    arg = jnp.asarray(x, dtype)
+    np.testing.assert_array_equal(
+        np.asarray(fn(arg), np.float32), np.median(x, axis=-1))
+    assert fn.lower(arg).as_text().count("stablehlo.sort") == 1
+    f32 = jnp.asarray(x, jnp.float32)
+    assert "stablehlo.sort" not in fn.lower(f32).as_text()
+
+
 def test_zero_dm_parity():
     data = make_data()
     np.testing.assert_allclose(
