@@ -708,25 +708,42 @@ def compare_tables(out_a: str, out_b: str, stem: str) -> None:
 def check_four_devices_worked(outdir: str, summ, gang: bool) -> None:
     """All four real device ids carry work. A gang shows it in the leaf
     device spans of the sharded sweep and accel search (tlmsum's
-    per-device roll-up); a fleet of 1-chip leases in where the scheduler
-    placed its stages (the `dev` stamp of the survey.stage.* spans)."""
+    per-device roll-up); a fleet of 1-chip leases in what the scheduler
+    leased: seconds by chip (`survey.lease_chip_s.chip<N>`), and a sweep
+    lease on every chip (the `chips` of the `survey.lease` spans)."""
     from pypulsar_tpu.obs.summarize import load_records
 
     busy = {int(d): round(v[0], 2) for d, v in summ.device_busy.items()}
-    placed: dict = {}
+    prefix = "survey.lease_chip_s.chip"
+    leased = {int(k[len(prefix):]): round(v, 2)
+              for k, v in summ.counters.items() if k.startswith(prefix)}
+    swept: set = set()
     for path in glob.glob(os.path.join(outdir, "tlm", "fleet*.jsonl")):
         for rec in load_records(path):
-            if rec.get("type") == "span" and str(
-                    rec.get("name", "")).startswith("survey.stage."):
-                for d in (rec.get("attrs") or {}).get("dev") or ():
-                    placed[int(d)] = round(
-                        placed.get(int(d), 0.0) + float(rec["dur"]), 2)
+            attrs = rec.get("attrs") or {}
+            if rec.get("name") == "survey.lease" \
+                    and attrs.get("stage") == "sweep":
+                swept.update(int(d) for d in attrs.get("chips") or ())
     say(f"per-device roll-up (tlmsum): leaf device-span seconds by "
-        f"device id {json.dumps(busy)}; stage seconds placed by the "
-        f"scheduler {json.dumps(dict(sorted(placed.items())))}")
-    seen = busy if gang else placed
+        f"device id {json.dumps(busy)}; seconds leased by chip "
+        f"{json.dumps(dict(sorted(leased.items())))}; chips that held a "
+        f"sweep lease {sorted(swept)}")
+    seen = busy if gang else leased
     if sorted(seen) != [0, 1, 2, 3] or not all(seen.values()):
         raise PhaseFailed(f"not all four device ids carried work: {seen}")
+    if not gang and sorted(swept) != [0, 1, 2, 3]:
+        raise PhaseFailed(f"a chip never held a sweep lease: {sorted(swept)}")
+
+
+def check_no_compile(summ) -> None:
+    """A pass over shapes and chips an earlier pass of this process ran:
+    nothing is built, at the plane's door or at JAX's."""
+    counts = {k: int(summ.counters.get(k, 0))
+              for k in ("compile.cache_miss", "jit.compiles",
+                        "compile.chip_load")}
+    say(f"second pass: {json.dumps(counts)}")
+    if counts["compile.cache_miss"] or counts["jit.compiles"]:
+        raise PhaseFailed(f"a warm pass compiled: {counts}")
 
 
 def check_sharded_intermediates(fil: str, sizes: dict) -> None:
@@ -815,6 +832,13 @@ def run_four_chips(args) -> None:
             for f in fils:
                 checks.run("recovery-fleet", check_recovery, outf,
                            os.path.splitext(os.path.basename(f))[0], fsizes)
+            # the same fleet again: whichever chips the leases fall on,
+            # every program is there
+            outf2 = os.path.join(args.workdir, "out_fleet4_again")
+            if checks.run("survey-fleet4-again", run_survey, fils, outf2,
+                          fsizes, devices=4, gang="auto") is not None:
+                checks.run("no-compile-fleet4-again", check_no_compile,
+                           telemetry_summary(outf2))
     checks.finish()
 
 

@@ -312,6 +312,9 @@ class PlaneJit:
                      if self._static else jax.jit(named))
         self._uid = next(_WRAPPER_IDS)
         self._compiled: Dict[Tuple, Any] = {}
+        # program key (the registry key without its chip) -> the Event
+        # the first thread to build it sets when it is done
+        self._first: Dict[Tuple, Any] = {}
         self._lock = threading.Lock()
         self._aot = bool(aot)
         try:
@@ -348,6 +351,23 @@ class PlaneJit:
                      _device_kind(), shape_key, cfg)).encode()
         return key, hashlib.sha1(blob).hexdigest(), dynamics
 
+    @staticmethod
+    def _program_key(key) -> Optional[Tuple]:
+        """``key`` without its chip, when the program is one chip's and
+        the thread is pinned to a lease's chip (``jax.default_device``):
+        every array leaf is a host input or sits on that chip ("host"),
+        so the executable is the same on every chip of the kind and the
+        one compiled first can be loaded onto the others. None for an
+        unpinned thread (one lease: nothing to share) and for a key that
+        names a placement (a gang's mesh, an array on another chip)."""
+        (statics, dyn_keys), _, cfg = key
+        if not isinstance(jax.config.jax_default_device, jax.Device):
+            return None
+        for _, _, leaves in dyn_keys:
+            if any(leaf[0] == "a" and leaf[3] != "host" for leaf in leaves):
+                return None
+        return (statics, dyn_keys), cfg
+
     # -- dispatch ----------------------------------------------------------
 
     def __call__(self, *args, **kwargs):
@@ -378,10 +398,45 @@ class PlaneJit:
             return self._jit(*args, **kwargs)
 
     def _compile(self, key, digest, args, kwargs):
+        """The executable for ``key``. A one-chip program that another
+        chip of this kind has built, or is building (four lanes in
+        lockstep miss together: one builds, the others wait for it), is
+        not compiled again: the persistent cache holds one entry for all
+        chips (:func:`_chips_share_entries`), and this chip's lowering
+        reads it and loads the binary under its own device assignment.
+        One compile a program, not one a chip."""
+        program = self._program_key(key)
+        first = waited = None
+        if program is not None and _chips_share_entries():
+            with self._lock:
+                waited = self._first.get(program)
+                if waited is None:  # this thread builds it
+                    first = self._first[program] = threading.Event()
+        if waited is not None:
+            waited.wait()
+            with self._lock:
+                if key in self._compiled:  # this chip's own, meanwhile
+                    return self._compiled[key]
+        try:
+            # ONE call site for every build: the frames above a lowering
+            # are in its cache key (metadata), so a second line here
+            # would be a second key for the same program
+            return self._build(key, digest, args, kwargs,
+                               sibling=waited is not None)
+        finally:
+            if first is not None:
+                first.set()
+
+    def _build(self, key, digest, args, kwargs, sibling=False):
+        """Lower and compile under ``key``. ``sibling``: another chip has
+        built this program; if the persistent cache then serves it,
+        nothing was compiled and the load is counted as one
+        (``compile.chip_load``), not as a miss."""
         configure_persistent_cache()
         meta: Dict[str, Any] = {}
         cross_host = _probe_marker(digest, meta)
         label = self._stage or self.__name__
+        _tls.persistent_hit = False
         t0 = time.perf_counter()
         try:
             compiled = self._jit.lower(*args, **kwargs).compile()
@@ -391,13 +446,21 @@ class PlaneJit:
                 self._aot = False  # this fn will never lower; stop trying
             return None
         dt = time.perf_counter() - t0
+        if sibling and _tls.persistent_hit:
+            telemetry.counter("compile.chip_load")
+            telemetry.counter("compile.chip_load_ms", dt * 1e3)
+            with self._lock:
+                return self._compiled.setdefault(key, compiled)
         telemetry.counter("compile.cache_miss")
         telemetry.counter("compile.ms", dt * 1e3)
         if cross_host:
             telemetry.counter("compile.persistent_hit")
         # first-dispatch span: steady-state hits stay span-free, so
         # tlmsum's compilation roll-up shows first-vs-steady directly
-        telemetry.record_span(f"compile.first.{label}", dt)
+        chip = getattr(jax.config.jax_default_device, "id", None)
+        telemetry.record_span(
+            f"compile.first.{label}", dt,
+            **({} if chip is None else {"chip": chip, "fn": self.__name__}))
         _write_marker(meta, {
             "fn": self.__name__, "stage": self._stage,
             "jax": jax.__version__, "device_kind": _device_kind(),
@@ -432,6 +495,111 @@ class PlaneJit:
 
 
 _kind_cache: Dict[str, str] = {}
+
+# ---------------------------------------------------------------------------
+# one persistent-cache entry a one-chip program, whichever chip asks
+#
+# JAX keeps the device assignment in the persistent cache's key on every
+# backend but the GPU's (jax/_src/cache_key.py), so a program built for
+# chip 0 is a miss on chip 3 and every lane of a fleet compiles it again.
+# The binary is the same on every chip of a kind, and the runtime loads a
+# serialized executable under the device assignment it is handed (what a
+# cache hit does anyway). So the plane hashes a one-replica, one-partition
+# program under the process's FIRST local device whichever chip asks: that
+# chip's key is letter for letter what it was (one lease, the default
+# device), the others read its entry. Nothing is serialized again in
+# process: the CPU backend cannot serialize an executable that it loaded
+# from the cache (it executes with "Function ... not found"). Whether this
+# runtime really runs such a load on the asking chip is tried once a
+# process, on a two-line program, before any key is shared.
+
+# persistent_hit: JAX's cache served this thread; probing: it runs the probe
+_tls = threading.local()
+_share = {"ok": None}  # None: not tried yet
+_share_lock = threading.Lock()
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+def _on_cache_event(name: str, **kw) -> None:
+    if name == _CACHE_HIT_EVENT:  # recorded on the compiling thread
+        _tls.persistent_hit = True
+
+
+def _hash_under_first_device() -> None:
+    """Wrap JAX's hash of the compile options (once): a single-device
+    assignment is hashed as the first local device's while sharing is on."""
+    import copy
+
+    import numpy as np
+    from jax._src import cache_key
+    from jax._src.lib import xla_client as xc
+
+    held = cache_key._hash_serialized_compile_options
+    first_id = jax.local_devices()[0].id
+
+    def hash_options(hash_obj, options, strip_device_assignment=False):
+        da = options.device_assignment
+        if (_share["ok"] or getattr(_tls, "probing", False)) \
+                and not strip_device_assignment and da is not None \
+                and da.replica_count() == 1 and da.computation_count() == 1:
+            options = copy.deepcopy(options)
+            options.device_assignment = xc.DeviceAssignment.create(
+                np.array([[first_id]]))
+        return held(hash_obj, options, strip_device_assignment)
+
+    cache_key._hash_serialized_compile_options = hash_options
+
+
+def _probe_shared_entry() -> bool:
+    """Build a two-line program for the first local chip, ask for it on
+    the second: True when the cache served it there, it ran on that chip,
+    and computed the same."""
+    import numpy as np
+
+    devs = jax.local_devices()[:2]
+    if len(devs) < 2:
+        return False
+
+    def _plane_chip_probe(x):
+        return x * 3.0 + 1.0
+
+    x = np.arange(8, dtype=np.float32)
+    outs = []
+    for d in devs:
+        _tls.persistent_hit = False
+        with jax.default_device(d):
+            outs.append(jax.jit(_plane_chip_probe).lower(x).compile()(x))
+    served = _tls.persistent_hit
+    return bool(served and outs[1].devices() == {devs[1]}
+                and np.array_equal(np.asarray(outs[0]), np.asarray(outs[1])))
+
+
+def _chips_share_entries() -> bool:
+    """True when one-chip programs share one persistent-cache entry over
+    this process's chips: the cache is on and the runtime passed
+    :func:`_probe_shared_entry` (tried once a process, under the shared
+    key, while the other lanes wait; a runtime that fails it keeps a key
+    a chip, as before)."""
+    if _share["ok"] is None:
+        with _share_lock:
+            if _share["ok"] is None:
+                ok = False
+                if configure_persistent_cache():
+                    # the other lanes wait on this lock for the verdict:
+                    # they must not build under a key a chip meanwhile
+                    _hash_under_first_device()
+                    jax.monitoring.register_event_listener(  # psrlint: ignore[PL013] -- registers a callback, dispatches nothing
+                        _on_cache_event)
+                    _tls.probing = True  # this thread hashes shared keys
+                    try:
+                        ok = _probe_shared_entry()
+                    except Exception:  # noqa: BLE001 - then a key a chip
+                        ok = False
+                    finally:
+                        _tls.probing = False
+                _share["ok"] = ok
+                telemetry.event("compile.chips_share_entries", ok=ok)
+    return bool(_share["ok"])
 
 
 def _device_kind() -> str:

@@ -143,6 +143,9 @@ class TraceSummary:
         # multi-host fleet, round 18) — per-HOST utilization, the level
         # above per-device
         self.host_busy: Dict[str, List] = {}
+        # [seconds, count] of the scheduler's sink-only survey.obs spans
+        # (an observation, first lease asked for to terminal state)
+        self.obs_wall: List = [0.0, 0]
         # host id -> {event tail: count} for the fleet-membership
         # events (survey.obs_adopted / obs_ceded / host_strike /
         # stale_write_rejected), keyed by the host they indict
@@ -189,6 +192,9 @@ class TraceSummary:
                                                    [0.0, 0])
                 ent[0] += float(rec.get("dur", 0.0))
                 ent[1] += 1
+            if rec.get("name") == "survey.obs":
+                self.obs_wall[0] += float(rec.get("dur", 0.0))
+                self.obs_wall[1] += 1
             host = (rec.get("attrs") or {}).get("host")
             if host is not None and not self._obs_trace and str(
                     rec.get("name", "")).startswith("survey.stage."):
@@ -343,6 +349,8 @@ def combine_summaries(summaries: List[TraceSummary]) -> TraceSummary:
             ent = out.host_busy.setdefault(h, [0.0, 0])
             ent[0] += secs
             ent[1] += count
+        out.obs_wall[0] += s.obs_wall[0]
+        out.obs_wall[1] += s.obs_wall[1]
         for h, evs in s.host_events.items():
             ent = out.host_events.setdefault(h, {})
             for k, n in evs.items():
@@ -525,9 +533,28 @@ def render(s: TraceSummary, file: TextIO, top: int = 20) -> None:
         p(line + f"  lease wait "
                  f"{s.counters.get('survey.lease_wait_s', 0.0):.3f}s")
         prefix = "survey.lease_chip_s."
+        by_chip = {}
         for k, v in sorted(s.counters.items()):
-            if k.startswith(prefix):
+            if k.startswith(prefix + "chip"):
+                by_chip[int(k[len(prefix + "chip"):])] = v
+            elif k.startswith(prefix):
                 p(f"#   {k[len(prefix):]:<10s} {v:9.2f} chip-s")
+        if by_chip:
+            # lane roll-up: the same leased seconds by chip, how often a
+            # lease fell on another chip than the observation's last, and
+            # what an observation took among its neighbours
+            line = "# lanes: " + "  ".join(
+                f"chip{d} {v:.2f}s" for d, v in sorted(by_chip.items()))
+            line += (f"  moves "
+                     f"{int(s.counters.get('survey.lease_moves', 0))}")
+            g = s.gauges.get("survey.lanes_in_flight")
+            if g:
+                line += f"  in flight max {int(g.get('max', 0))}"
+            if s.obs_wall[1]:
+                line += (f"  survey.obs mean "
+                         f"{s.obs_wall[0] / s.obs_wall[1]:.2f}s "
+                         f"({s.obs_wall[1]})")
+            p(line)
     # per-host roll-up (round 18): the multi-host fleet's utilization
     # and membership churn — busy seconds per host from the scheduler's
     # host-stamped stage spans, adoption/cede/strike counts per host

@@ -697,13 +697,17 @@ def _ring_span(name: str, attrs, aggregate: bool = True):
         flightrec.record(rec)
 
 
-def record_span(name: str, seconds: float) -> None:
-    """Directly account ``seconds`` to span ``name`` (profiling.record
-    back-compat; no nesting info)."""
+def record_span(name: str, seconds: float, *, aggregate: bool = True,
+                **attrs) -> None:
+    """Directly account ``seconds`` to span ``name``, ending now
+    (profiling.record back-compat; no nesting info). ``attrs`` go to the
+    sink with it; ``aggregate=False`` keeps it out of the flat per-stage
+    totals, as :func:`span` does."""
     s = _session
     if s is None:
         return
-    s._finish_span(name, s._now() - seconds, float(seconds), None, 0, {})
+    s._finish_span(name, s._now() - seconds, float(seconds), None, 0, attrs,
+                   aggregate)
 
 
 def counter(name: str, inc: float = 1) -> None:

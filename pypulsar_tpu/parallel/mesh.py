@@ -84,6 +84,29 @@ def device_lease(devices):
         _tls.lease = prev
 
 
+def placement() -> tuple:
+    """This thread's placement: its ``jax.default_device`` and its
+    lease. Both are thread-local, so a helper thread a stage starts
+    (the ship-ahead worker) sees neither unless it is handed them:
+    capture here on the stage's thread, re-enter with
+    :func:`adopt_placement` on the helper's."""
+    return jax.config.jax_default_device, current_lease()
+
+
+@contextlib.contextmanager
+def adopt_placement(held: tuple):
+    """Run the block where the thread that called :func:`placement`
+    ran: a lease on chip 3 ships and preps on chip 3, not on the
+    process's first chip. With nothing pinned it is a no-op."""
+    default, lease = held
+    with contextlib.ExitStack() as stack:
+        if default is not None:
+            stack.enter_context(jax.default_device(default))
+        if lease:
+            stack.enter_context(device_lease(lease))
+        yield
+
+
 def current_lease() -> Optional[tuple]:
     """The active thread's leased device tuple, or None outside a lease."""
     return getattr(_tls, "lease", None)

@@ -117,9 +117,14 @@ def prefetch(items: Iterable, depth: int = 2, name: str = "prefetch",
     # heartbeat entry — not the producer thread's nonexistent one
     # (round 21; the PR 7 attribution caveat this closes)
     trace_ctx = telemetry.current_context()
+    # and its placement (the lease's chip, thread-local as well): what
+    # the worker ships and preps must land on the stage's chip
+    from pypulsar_tpu.parallel import mesh
+
+    held = mesh.placement()
 
     def worker():
-        with telemetry.adopt_context(trace_ctx):
+        with telemetry.adopt_context(trace_ctx), mesh.adopt_placement(held):
             try:
                 for item in items:
                     if stop.is_set():  # consumer gone: stop producing

@@ -311,6 +311,10 @@ class FleetScheduler:
         # the FIFO claim line that keeps wide gangs starvation-free
         self._free_ids = set(range(self.devices))
         self._claims: List[Tuple[object, List[int]]] = []
+        # obs index -> its leases so far (first ask, every chip held, the
+        # chips of the last lease, moves): where the next one-chip lease
+        # is asked for, and what the survey.obs span reports
+        self._obs_leases: Dict[int, dict] = {}
         self._stage_cost: Dict[str, List[float]] = {}  # name -> [s, n]
         self.result = FleetResult()
         self._manifests: List[Optional[ObsManifest]] = []
@@ -676,6 +680,16 @@ class FleetScheduler:
         funnels through here, which is why the service-mode terminal
         hook also rides it: the daemon's tenant books settle on the
         same edges the multi-host plane does."""
+        with self._lock:
+            held = self._obs_leases.pop(obs_i, None)
+        if held is not None:
+            # the observation among its neighbours: first lease asked
+            # for to terminal state, every chip it held, how often a
+            # lease fell on another chip than the one before (sink-only)
+            telemetry.record_span(
+                "survey.obs", time.perf_counter() - held["t_ask"],
+                aggregate=False, obs=self.obs[obs_i].name, state=state,
+                chips=sorted(held["chips"]), moves=held["moves"])
         cb = self.on_obs_terminal
         if cb is not None:
             try:
@@ -1503,12 +1517,19 @@ class FleetScheduler:
                        f"device chain")
         return k, f"gang x{k}: {idle} idle chips, cost unmeasured yet"
 
-    def _acquire_devices(self, k: int) -> Optional[List[int]]:
+    def _acquire_devices(self, k: int,
+                         prefer: Optional[int] = None) -> Optional[List[int]]:
         """Block until k lease ids are free and claim them. FIFO with
         full reservation: an older waiting claim reserves freed chips
         (up to its need) before any younger claim may take them, so a
         wide gang cannot starve behind 1-chip traffic. Returns None
         when the fleet is unwinding (fatal).
+
+        A one-chip claim takes ``prefer`` when that chip is free at the
+        grant (the chip of the observation's last lease: placement then
+        repeats from stage to stage and from run to run, and what a
+        stage left on the chip stays near) and the lowest free chip
+        otherwise; it never waits for the preferred one.
 
         The claim SHRINKS if devices are quarantined while it waits —
         a gang asking for chips that no longer exist must retry at the
@@ -1532,8 +1553,13 @@ class FleetScheduler:
                             break
                         rem -= min(n[0], rem)  # older claims reserve
                     if grant:
-                        ids = sorted(self._free_ids)[:need[0]]
+                        ids = ([prefer] if need[0] == 1
+                               and prefer in self._free_ids
+                               else sorted(self._free_ids)[:need[0]])
                         self._free_ids.difference_update(ids)
+                        telemetry.gauge(
+                            "survey.lanes_in_flight",
+                            self.devices - len(self._free_ids))
                         return ids
                     self._cv.wait(0.1)
             finally:
@@ -1546,6 +1572,8 @@ class FleetScheduler:
             self._free_ids.update(
                 i for i in ids
                 if not self._health.is_quarantined(i))
+            telemetry.gauge("survey.lanes_in_flight",
+                            self.devices - len(self._free_ids))
             self._cv.notify_all()
 
     def _n_jax_devices(self) -> Optional[int]:
@@ -1585,7 +1613,22 @@ class FleetScheduler:
         lease(s), account it, run :meth:`_run_leased` under it."""
         k, reason = self._gang_size(task)
         t_ask = time.perf_counter()
-        ids = self._acquire_devices(k)
+        with self._cv:
+            held = self._obs_leases.setdefault(
+                task.obs_i, {"t_ask": t_ask, "chips": set(), "last": None,
+                             "moves": 0, "holding": None})
+            # the chip of its last lease; before its first, the chip of
+            # its place in the fleet, so four beams on four chips land
+            # where they landed in the run before
+            prefer = (held["last"][0] if held["last"]
+                      else task.obs_i % self.devices)
+            # the stage this one waited for is done, but its thread may
+            # not have given the chip back yet (it queues its successor
+            # before it unwinds): let it, or this claim takes a
+            # neighbour's chip and every beam in lockstep moves one on
+            while held["holding"] in task.stage.deps and not self._stop:
+                self._cv.wait(0.05)
+        ids = self._acquire_devices(k, prefer)
         if ids is None:  # fleet unwinding while we waited
             return
         t_lease = time.perf_counter()
@@ -1596,6 +1639,14 @@ class FleetScheduler:
             reason += f"; shrunk to {k} while waiting"
         task.last_dev_ids = list(ids)
         task.last_real_dev_ids = None
+        with self._lock:
+            # a move: no chip in common with the lease before
+            moved = bool(held["last"]) and not set(ids) & set(held["last"])
+            held["moves"] += moved
+            held["chips"].update(ids)
+            held["last"] = list(ids)
+            held["holding"] = task.stage.name
+        telemetry.counter("survey.lease_moves", int(moved))
         try:
             # the lease as the pool sees it: k chips held from grant to
             # release, whatever the stage does with them (sink-only, the
@@ -1605,10 +1656,13 @@ class FleetScheduler:
                                 wait_s=round(wait_s, 6)):
                 self._run_leased(task, k, ids, reason)
         finally:
-            chip_s = k * (time.perf_counter() - t_lease)
-            telemetry.counter("survey.lease_chip_s", chip_s)
+            held_s = time.perf_counter() - t_lease
+            telemetry.counter("survey.lease_chip_s", k * held_s)
             telemetry.counter(f"survey.lease_chip_s.{task.stage.name}",
-                              chip_s)
+                              k * held_s)
+            for i in ids:  # the same seconds by chip
+                telemetry.counter(f"survey.lease_chip_s.chip{i}", held_s)
+            held["holding"] = None
             self._release_devices(ids)
 
     def _run_leased(self, task: _Task, k: int, ids: List[int],
@@ -1648,7 +1702,8 @@ class FleetScheduler:
         dispatches meet in the batch broker and fuse instead of
         serializing on separate exclusive leases.  Claims are skipped
         for gangs (k > 1), non-broker stages, when the broker/lanes are
-        off, and whenever the resource guard is refusing launches."""
+        off, while a chip of the pool stands free, and whenever the
+        resource guard is refusing launches."""
         if k != 1 or task.stage.name not in _BROKER_UNITS:
             return []
         if not broker_mod.enabled() or broker_mod.lane_width() <= 1:
@@ -1658,7 +1713,10 @@ class FleetScheduler:
         width = broker_mod.lane_width()
         mates: List[_Task] = []
         with self._lock:
-            if self._stop:
+            if self._stop or self._free_ids:
+                # a free chip runs a queued task at once and alone: a
+                # lane is for tasks that would otherwise wait for this
+                # lease's chip (with one lease: every queued one)
                 return []
             for t in self._tasks.values():
                 if len(mates) >= width - 1:
@@ -1668,6 +1726,12 @@ class FleetScheduler:
                 if t.stage.name != task.stage.name:
                     continue
                 if self.plane is not None and t.obs_i not in self._owned:
+                    continue
+                if self._obs_leases.get(t.obs_i, {}).get(
+                        "holding") in t.stage.deps:
+                    # queued by a stage whose thread has not given its
+                    # chip back yet (beams in lockstep finish together):
+                    # that chip is free in a moment, and the task's own
                     continue
                 # claim: run out of band, leave a stale queue entry
                 # that _worker_step consumes by seq match
@@ -1846,8 +1910,10 @@ class FleetScheduler:
         thread: with several leases every stage thread is pinned to its
         lease's chip and the plane keys executables by that placement, so
         a warmer running unpinned would compile programs no stage ever
-        finds. Only that chip's lane finds what the pool compiles (S7);
-        with one lease nothing is pinned, here as there."""
+        finds. What the pool compiles for that chip the other chips'
+        lanes load (the plane hands a one-chip program from the chip
+        that compiled it to the others); with one lease nothing is
+        pinned, here as there."""
         import contextlib
 
         healthy = self._healthy_ids()
@@ -1884,6 +1950,15 @@ class FleetScheduler:
             with self._lock:
                 for i in range(len(self.obs)):
                     if i in warmed:
+                        continue
+                    if len(self.obs) > 1 and i < self.devices:
+                        # the first wave of a fleet starts at once, every
+                        # observation on a chip of its own: its stages
+                        # build what they need, and a pool racing them
+                        # only decides by the hair of a start whose
+                        # frames key an entry and whether a later run of
+                        # the same fleet builds the pool's guess mid-step
+                        warmed.add(i)
                         continue
                     states = [self._tasks[(i, s.name)].state
                               for s in self.stages]

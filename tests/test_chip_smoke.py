@@ -112,6 +112,11 @@ def test_four_chip_phase_on_virtual_devices(toy, monkeypatch, capsys):
     assert "per-device roll-up" in out and "byte identity" in out
     assert "sharded intermediates" in out and "on 4 devices" in out
     assert "fleet of 4 on 4 chips" in out
+    # the fleet leg reads the per-chip lease counters, and its second pass
+    # compiles nothing on any chip
+    assert "chips that held a sweep lease [0, 1, 2, 3]" in out
+    assert 'second pass: {"compile.cache_miss": 0, "jit.compiles": 0' in out
+    assert "check no-compile-fleet4-again: ok" in out
 
 
 def test_require_chips_refuses_fewer_than_asked(monkeypatch):

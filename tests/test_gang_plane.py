@@ -380,9 +380,15 @@ def test_lease_chip_seconds_are_k_times_the_lease_wall(gang_run):
         # the counter closes a few lines after the span does
         assert c[f"survey.lease_chip_s.{stage}"] == pytest.approx(
             k * r["dur"], rel=0.02, abs=0.02)
+    # the same seconds twice over: by stage, and (PR 31) by chip
+    by_chip = {n: v for n, v in c.items()
+               if n.startswith("survey.lease_chip_s.chip")}
+    assert sorted(by_chip) == [f"survey.lease_chip_s.chip{i}"
+                               for i in range(4)]
+    assert c["survey.lease_chip_s"] == pytest.approx(sum(by_chip.values()))
     assert c["survey.lease_chip_s"] == pytest.approx(
         sum(v for n, v in c.items()
-            if n.startswith("survey.lease_chip_s.")))
+            if n.startswith("survey.lease_chip_s.") and n not in by_chip))
 
 
 def test_leased_chip_seconds_stay_inside_what_the_pool_offered(gang_run):
@@ -438,6 +444,9 @@ def test_one_chip_run_counts_leases_too(tmp_path):
         c = tlm.counter_totals()
     assert result.ok
     assert set(n for n in c if n.startswith("survey.lease_chip_s.")) == {
-        "survey.lease_chip_s.a", "survey.lease_chip_s.c"}
+        "survey.lease_chip_s.a", "survey.lease_chip_s.c",
+        "survey.lease_chip_s.chip0"}
+    assert c["survey.lease_chip_s.chip0"] == pytest.approx(
+        c["survey.lease_chip_s"])
     assert c["survey.lease_chip_s"] <= c["survey.pool_chip_s"]
     assert c["survey.pool_chip_s"] == pytest.approx(result.wall)
