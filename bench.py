@@ -137,7 +137,7 @@ def parse_args(argv=None):
     ap.add_argument("--batch", type=int, default=None,
                     help="with --accel: also measure the BATCHED search "
                          "(this many spectra against the shared template "
-                         "bank in one dispatch per stage)")
+                         "bank in one dispatch a chunk)")
     ap.add_argument("--spectral", action="store_true",
                     help="with --accel: run the round-10 spectral-fusion "
                          "pipeline A/B instead of the raw engine bench — "
@@ -1068,7 +1068,7 @@ def run_accel(args):
 
     # --- batched search over the shared template bank (VERDICT r3 item 2:
     # the 4096-trial workload searches B spectra per configuration; the
-    # banks are DM-independent so one dispatch per stage serves them all).
+    # banks are DM-independent so one dispatch a chunk serves them all).
     # OOM halves the batch and retries.
     batch_extras = {}
     value = cells_per_sec

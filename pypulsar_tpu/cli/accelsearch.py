@@ -358,7 +358,7 @@ def _run(args, cfg):
         from pypulsar_tpu.fourier.accelsearch import accel_search_batch
 
         # groups of same-geometry spectra search in one device dispatch
-        # per stage; a (bins, T), prep-kind, or full-group boundary flushes
+        # a chunk; a (bins, T), prep-kind, or full-group boundary flushes
         group: list = []  # (infile, payload, T, kind); kind in {norm,series}
 
         def flush():

@@ -23,12 +23,15 @@ convolutions*:
   through a dense DFT matmul that allocates O(L^2)).
 - Harmonic summing searches the grid of the *highest* summed harmonic and
   adds subharmonics by stretch-gather (see accel_search's docstring for
-  the geometry). Each stage H in (1, 2, 4, 8) builds its own plane from
-  scratch — a full ladder costs sum(H) = 15 correlation+stretch passes
-  per span (stages have different grids, so partial sums cannot be
-  reused across them).
+  the geometry). The stages H in (1, 2, 4, 8) share ONE segment grid and
+  one harmonic ladder a segment: stage 2H's plane is stage H's plus its
+  odd subharmonics, so the running sum takes the ratios 1; 1/2; 1/4,
+  3/4; 1/8, 3/8, 5/8, 7/8 and each stage detects on it after its rung —
+  8 correlation+stretch passes per span (the distinct ratios), not
+  sum(H) = 15; a stage differs only in the columns it is valid on and
+  its threshold (see _ladder_scan).
 - Detection is on-device: 4-neighbour local-max + threshold + ``lax.top_k``
-  per segment; only O(K) winners (with their 3x3 neighbourhoods for
+  per (segment, stage); only O(K) winners (with their 3x3 neighbourhoods for
   sub-bin refinement) ever reach the host. Host-side refinement fits a
   parabola in r and z and converts powers to equivalent-Gaussian
   significance in float64.
@@ -280,128 +283,126 @@ def _build_spec_pad_batch(re, im, front, pad):
     return jnp.stack([sp.real, sp.imag], axis=1)
 
 
-@functools.lru_cache(maxsize=64)
-def _make_stage_runner(segw: int, Z: int, Wn: int, topk: int,
-                       bank_meta: Tuple[Tuple[int, int, int, int], ...]):
-    """One compiled program for an ENTIRE harmonic stage.
+def _ladder_scan(spec_pad, tfs, idxs, grid_lo, lo, hi, thresh, seg_ids, *,
+                 segw: int, Z: int, Wn: int, topk: int, rungs,
+                 batched: bool):
+    """The search of ``seg_ids`` segments of one [Np] complex spectrum
+    (or, ``batched``, of a [B, Np] batch: the same body under vmap): ONE
+    ``lax.scan`` over the one segment grid, whose body walks the harmonic
+    ladder once and detects after each rung.
 
-    The naive driver dispatches (segments x subharmonics) small device
-    calls, each paying dispatch latency that can dwarf the math. Here all
-    segments run inside one lax.scan: slice starts are
-    affine in the segment index (``start = off0 + si * step``, exact
-    because the stage's top_lo and segw are divisible by H), the
-    subharmonic loop unrolls at trace time, and detection emits fixed
-    top-k records per (segment, w), so a stage is ONE dispatch.
+    At one top-harmonic position ``r0 + col/2`` stage 2H's sum over
+    ``b/2H`` holds, for every even ``b``, exactly stage H's term: the
+    same ratio bank, the same slice start ``rho * r0``, the same stretch
+    index ``round(rho * col)``. So on a common grid stage 2H's plane is
+    stage H's plus the odd subharmonics: the running sum takes ratio 1
+    (stage 1 detects), 1/2 (stage 2), 1/4 and 3/4 (stage 4), the odd
+    eighths (stage 8), and every ratio bank is correlated once a
+    segment. What differs between stages is where each is valid and its
+    threshold: ``lo[s] <= r_top < hi[s]`` is a column mask on what stage
+    ``s`` DETECTS, never on the running sum.
 
-    ``bank_meta[b-1] = (off0, step, hw, L)``; the returned callable takes
-    (spec_pad, tfs, idxs, top_lo, top_hi, thresh, seg_ids) with tfs/idxs
-    matching bank_meta order. ``seg_ids`` is the int32 array of segment
-    indices to scan — ``arange(n_seg)`` for a full pass, or the coarse
-    pass's hit segments for a coarse-to-fine refine (results land in
-    seg_ids order; only its LENGTH keys compilation).
+    ``rungs[s]`` holds the ``(off0, step, hw, L)`` of the banks stage
+    ``s`` adds; ``tfs`` / ``idxs`` are flat in the same order. Slice
+    starts are affine in the segment index (``off0 + si * step``, exact
+    because the grid's origin and ``segw`` are divisible by every
+    stage's H). Returns (vals, zi, ri, neigh), each
+    ``[n_seg, n_stages, Wn, topk, ...]``, batched with B after the
+    stage axis.
     """
+    batch = (functools.partial(jax.vmap, in_axes=(0, None), out_axes=1)
+             if batched else (lambda f: f))
+    col = jnp.arange(2 * segw, dtype=jnp.int32)
 
-    def run(spec_pad2, tfs, idxs, top_lo, top_hi, thresh, seg_ids):
-        # complex never crosses the jit boundary (the ops/transfer.py
-        # convention): the padded spectrum and the template banks arrive
-        # as [2, ...] float planes
-        spec_pad = join_planes(spec_pad2[0], spec_pad2[1])
-
-        def body(carry, si):
-            r0 = top_lo + si * segw
-            width = jnp.minimum(segw, top_hi - r0)
-            plane = jnp.zeros((Z * Wn, 2 * segw), jnp.float32)
-            for (off0, step, hw, L), tf2, idx in zip(bank_meta, tfs, idxs):
+    def search_segment(spec, si):
+        """One spectrum's [Np] ladder over segment ``si``."""
+        r0 = grid_lo + si * segw
+        plane = jnp.zeros((Z * Wn, 2 * segw), jnp.float32)
+        bank = zip(tfs, idxs)
+        outs = []
+        for s, rung in enumerate(rungs):
+            for off0, step, _hw, L in rung:
+                tf2, idx = next(bank)
                 with jax.named_scope("accel.correlate"):
-                    tf = join_planes(tf2[0], tf2[1])
-                    start = off0 + si * step
-                    sl = jax.lax.dynamic_slice(spec_pad, (start,), (L,))
+                    tf = join_planes(tf2[0], tf2[1])  # [rows, L]
+                    sl = jax.lax.dynamic_slice(spec, (off0 + si * step,),
+                                               (L,))
                     cf = jnp.fft.fft(sl)
                     corr = jnp.fft.ifft(cf[None, :] * tf, axis=1)
                     p = (jnp.abs(corr) ** 2).astype(jnp.float32)
                     p = p.reshape(p.shape[0] // 2, 2 * L)
                 with jax.named_scope("accel.harmonic_sum"):
                     plane = plane + jnp.take(p, idx, axis=1)
-            col = jnp.arange(2 * segw, dtype=jnp.int32)
-            plane = jnp.where(col[None, :] < 2 * width, plane,
-                              jnp.float32(-jnp.inf))
-            outs = []
+            valid = (col >= 2 * (lo[s] - r0)) & (col < 2 * (hi[s] - r0))
+            stage_plane = jnp.where(valid[None, :], plane,
+                                    jnp.float32(-jnp.inf))
+            per_w = []
             for wi in range(Wn):
                 with jax.named_scope("accel.detect"):
-                    outs.append(_detect_impl(plane[wi::Wn], thresh, topk))
-            vals = jnp.stack([o[0] for o in outs])
-            zi = jnp.stack([o[1] for o in outs])
-            ri = jnp.stack([o[2] for o in outs])
-            neigh = jnp.stack([o[3] for o in outs])
-            return carry, (vals, zi, ri, neigh)
+                    per_w.append(_detect_impl(stage_plane[wi::Wn],
+                                              thresh[s], topk))
+            # [Wn, k, ...] for each of (vals, zi, ri, neigh)
+            outs.append([jnp.stack(f) for f in zip(*per_w)])
+        return tuple(jnp.stack(f) for f in zip(*outs))  # [S, Wn, k, ...]
 
-        _, res = jax.lax.scan(body, 0, seg_ids)
-        return res
+    search = batch(search_segment)
 
-    return plane_jit(run, stage="accel", name="accel_stage")
+    def body(carry, si):
+        return carry, search(spec_pad, si)
+
+    _, res = jax.lax.scan(body, 0, seg_ids)
+    return res
 
 
 @functools.lru_cache(maxsize=64)
-def _make_stage_runner_batch(segw: int, Z: int, Wn: int, topk: int,
-                             bank_meta: Tuple[Tuple[int, int, int, int], ...],
-                             mesh_devs: Tuple = ()):
-    """Batched stage runner (VERDICT r3 item 2): B spectra correlate
-    against the SHARED template bank in one dispatch.
+def _make_ladder_runner(segw: int, Z: int, Wn: int, topk: int, rungs,
+                        batched: bool = True, mesh_devs: Tuple = ()):
+    """One compiled program for the ENTIRE search of a spectrum (or of a
+    batch chunk): every segment and every harmonic stage in one dispatch
+    (:func:`_ladder_scan`). The naive driver dispatches (segments x
+    stages x subharmonics) small device calls, each paying dispatch
+    latency that can dwarf the math.
 
-    The bank FFTs and stretch indices are DM-independent — across a
-    4096-trial batch only the spectrum changes — so the segment slice
-    becomes a [B, L] batched FFT, the correlation a [B, rows, L]
-    broadcast multiply against the one [rows, L] bank, and detection a
-    vmap of the serial detector. Larger FFT batches are exactly what the
-    TPU FFT lowering needs (the serial path measured 121 GFLOP/s at
-    rows=2Z; the batch axis multiplies the batch size by B).
+    The returned callable takes (spec_pad2, tfs, idxs, grid_lo, lo, hi,
+    thresh, seg_ids): the padded spectrum and the template banks as
+    float planes (complex never crosses the jit boundary, the
+    ops/transfer.py convention), the per-stage validity bounds and
+    thresholds as ``[n_stages]`` arrays, and the int32 segment indices
+    to scan — ``arange(n_seg)`` for a full pass, or the coarse pass's
+    hit segments for a coarse-to-fine refine (results land in seg_ids
+    order; only its LENGTH keys compilation).
+
+    ``batched`` False is the serial program ``accel_stage``: one [2, Np]
+    spectrum, results without the batch axis. Batched (VERDICT r3 item
+    2), B spectra correlate against the SHARED banks: the bank FFTs and
+    stretch indices are DM-independent — across a 4096-trial batch only
+    the spectrum changes — so the segment slice is a [B, L] batched
+    FFT, the correlation a [B, rows, L] broadcast multiply against the
+    one [rows, L] bank, and detection a vmap of the detector. Larger FFT
+    batches are exactly what the TPU FFT lowering needs.
 
     A non-empty ``mesh_devs`` (a tuple of jax devices — resolved by the
     caller through the gang lease, never ``jax.devices()[:k]``, so two
     gang-leased observations cannot collide on chips 0..k-1)
     additionally shard_maps the batch axis over the 'dm' axis of a mesh
     built on exactly those devices (each device holds B/k spectra and
-    the full bank — zero cross-device communication; candidates gather
+    the full banks — zero cross-device communication; candidates gather
     on host), the same layout the sweep uses.
     """
+    scan = functools.partial(_ladder_scan, segw=segw, Z=Z, Wn=Wn, topk=topk,
+                             rungs=rungs, batched=batched)
 
-    def run(spec_pad2, tfs, idxs, top_lo, top_hi, thresh, seg_ids):
-        spec_pad = join_planes(spec_pad2[:, 0], spec_pad2[:, 1])  # [B, Np]
-        B = spec_pad.shape[0]
+    # explicit signatures: the plane binds a call's arguments by name
+    if not batched:
+        def run_one(spec_pad2, tfs, idxs, grid_lo, lo, hi, thresh, seg_ids):
+            return scan(join_planes(spec_pad2[0], spec_pad2[1]),
+                        tfs, idxs, grid_lo, lo, hi, thresh, seg_ids)
 
-        def body(carry, si):
-            r0 = top_lo + si * segw
-            width = jnp.minimum(segw, top_hi - r0)
-            plane = jnp.zeros((B, Z * Wn, 2 * segw), jnp.float32)
-            for (off0, step, hw, L), tf2, idx in zip(bank_meta, tfs, idxs):
-                with jax.named_scope("accel.correlate"):
-                    tf = join_planes(tf2[0], tf2[1])  # [rows, L]
-                    start = off0 + si * step
-                    sl = jax.lax.dynamic_slice(spec_pad, (0, start), (B, L))
-                    cf = jnp.fft.fft(sl, axis=1)  # [B, L]
-                    corr = jnp.fft.ifft(cf[:, None, :] * tf[None, :, :],
-                                        axis=2)
-                    p = (jnp.abs(corr) ** 2).astype(jnp.float32)
-                    p = p.reshape(B, p.shape[1] // 2, 2 * L)
-                with jax.named_scope("accel.harmonic_sum"):
-                    plane = plane + jnp.take(p, idx, axis=2)
-            col = jnp.arange(2 * segw, dtype=jnp.int32)
-            plane = jnp.where(col[None, None, :] < 2 * width, plane,
-                              jnp.float32(-jnp.inf))
-            outs = []
-            for wi in range(Wn):
-                with jax.named_scope("accel.detect"):
-                    outs.append(
-                        jax.vmap(_detect_impl, in_axes=(0, None, None))(
-                            plane[:, wi::Wn], thresh, topk))
-            vals = jnp.stack([o[0] for o in outs], axis=1)   # [B, Wn, k]
-            zi = jnp.stack([o[1] for o in outs], axis=1)
-            ri = jnp.stack([o[2] for o in outs], axis=1)
-            neigh = jnp.stack([o[3] for o in outs], axis=1)
-            return carry, (vals, zi, ri, neigh)
+        return plane_jit(run_one, stage="accel", name="accel_stage")
 
-        _, res = jax.lax.scan(body, 0, seg_ids)
-        return res  # each [n_seg, B, Wn, ...]
+    def run(spec_pad2, tfs, idxs, grid_lo, lo, hi, thresh, seg_ids):
+        return scan(join_planes(spec_pad2[:, 0], spec_pad2[:, 1]),
+                    tfs, idxs, grid_lo, lo, hi, thresh, seg_ids)
 
     if not mesh_devs:
         return plane_jit(run, stage="accel", name="accel_stage_batch")
@@ -411,15 +412,15 @@ def _make_stage_runner_batch(segw: int, Z: int, Wn: int, topk: int,
 
     mesh = Mesh(np.array(list(mesh_devs)), ("dm",))
 
-    def run_sharded(spec_pad2, tfs, idxs, top_lo, top_hi, thresh, seg_ids):
+    def run_sharded(spec_pad2, tfs, idxs, grid_lo, lo, hi, thresh, seg_ids):
         shd = jax.shard_map(
             run, mesh=mesh,
-            in_specs=(P("dm"), P(), P(), P(), P(), P(), P()),
-            out_specs=P(None, "dm"),
+            in_specs=(P("dm"), P(), P(), P(), P(), P(), P(), P()),
+            out_specs=P(None, None, "dm"),
             check_vma=False,
         )
-        return shd(spec_pad2, tfs, idxs,
-                   jnp.int32(top_lo), jnp.int32(top_hi), thresh, seg_ids)
+        return shd(spec_pad2, tfs, idxs, jnp.int32(grid_lo), lo, hi, thresh,
+                   seg_ids)
 
     # one wrapper per mesh (this factory is memoised on mesh_devs): its
     # AOT executables belong to exactly those chips, and the sharded
@@ -528,46 +529,54 @@ def _cached_ratio_bank(rho_num, rho_den, zs, ws, segw, min_halfwidth):
     return bank
 
 
-def _stage_range(H: int, rlo: int, rhi: int, N: int, segw: int):
-    """(top_lo, top_hi, n_seg) of harmonic stage ``H``'s segment grid
-    (shared by the serial and batched drivers and their coarse passes —
-    segment indices must map one-to-one between passes)."""
-    top_lo = H * rlo
-    top_hi = min(H * rhi, N - 1)
-    n_seg = -(-(top_hi - top_lo) // segw) if top_hi > top_lo else 0
-    return top_lo, top_hi, n_seg
+def _ladder_grid(stages, rlo: int, rhi: int, N: int, segw: int):
+    """(grid_lo, n_seg, lo, hi) of the ONE segment grid every harmonic
+    stage is searched on (shared by the serial and batched drivers and
+    their coarse passes — segment indices must map one-to-one between
+    passes). The origin is divisible by every stage's H, so each ratio's
+    slice start ``rho * r0`` is exact; stage ``stages[s]`` is valid on
+    top-harmonic positions ``lo[s] <= r_top < hi[s]``, which the scan
+    applies as a column mask."""
+    hmax = max(stages)
+    grid_lo = hmax * (rlo // hmax)
+    grid_hi = min(hmax * rhi, N - 1)
+    n_seg = -(-(grid_hi - grid_lo) // segw) if grid_hi > grid_lo else 0
+    lo = np.array([H * rlo for H in stages], dtype=np.int32)
+    hi = np.array([min(H * rhi, N - 1) for H in stages], dtype=np.int32)
+    return grid_lo, n_seg, lo, hi
 
 
-def _coarse_segment_sel(N, T, cfg: AccelSearchConfig, stages, rlo, rhi,
-                        segw, front, Np, thresh, hit_fn):
-    """Coarse-pass segment preselection shared by both drivers: rerun
-    :func:`_search_setup` on the coarse z grid (identical padding
-    geometry — asserted — so segment indices map one-to-one), then ask
-    ``hit_fn(H, banks_coarse, n_z_rows, thresh_val, seg_ids)`` — the
-    driver's own stage executor — for a per-segment hit mask at the
-    reduced threshold. Returns {H: hit segment ids}."""
+def _select_segments(N, T, cfg: AccelSearchConfig, front, Np, n_seg,
+                     thresh_vals, hit_fn):
+    """(ids, scan_ids) shared by both drivers: the segments whose hits
+    are unpacked and the segment list the fine pass scans. Single-pass,
+    both are every segment. With ``cfg.coarse_dz`` the coarse pass
+    decides: rerun :func:`_search_setup` on the coarse z grid (identical
+    padding geometry — asserted — so segment indices map one-to-one),
+    then ask ``hit_fn(banks_coarse, n_z_rows, thresh_vals, seg_ids)`` —
+    the driver's own ladder executor — which segments hold a hit of ANY
+    stage at the reduced thresholds (there is one grid, so the fine pass
+    scans the union over the stages), padded to a compiled length."""
+    ids = np.arange(n_seg)
+    if cfg.coarse_dz <= cfg.dz:
+        return ids, ids
     ccfg = dataclasses.replace(cfg, dz=cfg.coarse_dz, coarse_dz=0.0)
     (zs_c, _wc, _sc, _gc, _rl, _rh, banks_c, front_c, Np_c,
      _nc, _tc) = _search_setup(N, T, ccfg)
     if (front_c, Np_c) != (front, Np):
         raise AssertionError("coarse/fine padding geometry diverged")
-    sel = {}
-    for H in stages:
-        _lo, _hi, n_seg = _stage_range(H, rlo, rhi, N, segw)
-        if not n_seg:
-            continue
-        hits = hit_fn(H, banks_c, len(zs_c),
-                      cfg.coarse_power_frac * thresh[H], np.arange(n_seg))
-        sel[H] = np.nonzero(hits)[0]
-    return sel
+    hits = hit_fn(banks_c, len(zs_c), cfg.coarse_power_frac * thresh_vals,
+                  ids)
+    ids = np.nonzero(hits)[0]
+    return ids, _pad_pow2(ids, n_seg)
 
 
 def _pad_pow2(ids: np.ndarray, n_seg: int) -> np.ndarray:
     """Pad a segment-id list to the next power-of-two length (capped at
-    the stage's ``n_seg``) by repeating the last id. Refine-pass hit
+    the grid's ``n_seg``) by repeating the last id. Refine-pass hit
     counts vary per spectrum, and every distinct ``seg_ids`` LENGTH is
     one XLA compile — pow2 padding
-    bounds the compile count at log2(n_seg) shapes per stage geometry.
+    bounds the compile count at log2(n_seg) shapes per search geometry.
     The cap keeps a near-full selection from scanning MORE segments than
     the single-pass search would (and its length is the shape a full
     pass compiles anyway). Duplicate positions produce duplicate raw
@@ -631,19 +640,28 @@ def _search_setup(N: int, T: float, cfg: AccelSearchConfig):
     return zs, ws, stages, segw, rlo, rhi, banks, front, Np, numindep, thresh
 
 
-def _stage_banks(banks, H: int, top_lo: int, segw: int, front: int):
-    """(bank_meta, tfs, idxs) for one harmonic stage — device copies of
-    this stage's <= H ratio banks (see accel_search's residency note)."""
+def _ladder_banks(banks, stages, grid_lo: int, segw: int, front: int):
+    """(rungs, tfs, idxs): device copies of the search's ratio banks,
+    each once, in ladder order — stage H adds the ratios b/H in lowest
+    terms (1; 1/2; 1/4, 3/4; the odd eighths). ``rungs[s]`` holds the
+    ``(off0, step, hw, L)`` of stage ``s``'s additions, ``tfs`` / ``idxs``
+    are flat in the same order (residency: accel_search's run_ladder)."""
     from fractions import Fraction
 
-    bank_meta, tfs, idxs = [], [], []
-    for b in range(1, H + 1):
-        tf, hw, L, idx = banks[Fraction(b, H)]
-        bank_meta.append((front + (b * top_lo) // H - hw,
-                          (b * segw) // H, hw, L))
-        tfs.append(jnp.asarray(tf))  # [2, rows, L] float planes
-        idxs.append(jnp.asarray(idx))
-    return bank_meta, tfs, idxs
+    rungs, tfs, idxs = [], [], []
+    for H in stages:
+        rung = []
+        for b in range(1, H + 1):
+            rho = Fraction(b, H)
+            if rho.denominator != H:
+                continue  # a lower rung's bank, already in the sum
+            tf, hw, L, idx = banks[rho]
+            rung.append((front + (b * grid_lo) // H - hw,
+                         (b * segw) // H, hw, L))
+            tfs.append(jnp.asarray(tf))  # [2, rows, L] float planes
+            idxs.append(jnp.asarray(idx))
+        rungs.append(tuple(rung))
+    return tuple(rungs), tuple(tfs), tuple(idxs)
 
 
 def _refine_hits(raw_hits, zs, ws, cfg: AccelSearchConfig,
@@ -652,15 +670,14 @@ def _refine_hits(raw_hits, zs, ws, cfg: AccelSearchConfig,
     hits: parabola sub-cell peaks in r and z, trials-corrected Gaussian
     sigma, then greedy duplicate removal by fundamental proximity."""
     cands: List[AccelCandidate] = []
-    for H, wi, r0, vals, zi, ri, neigh, width in raw_hits:
+    for H, wi, r0, vals, zi, ri, neigh in raw_hits:
         # vectorized pre-filter: most top-k slots are -inf (below the
         # detection threshold) and the Python loop below runs per
         # (spectrum, stage, segment, k) — 10^7-scale at survey batch
         # sizes if every slot is visited. float64 so the threshold
         # compare matches the old per-element float(p) <= thresh exactly
         vals = np.asarray(vals, dtype=np.float64)
-        keep = np.isfinite(vals) & (vals > thresh[H]) \
-            & (np.asarray(ri) < 2 * width)
+        keep = np.isfinite(vals) & (vals > thresh[H])
         for j in np.nonzero(keep)[0]:
             p = float(vals[j])
             nb = neigh[j].astype(np.float64)
@@ -728,70 +745,62 @@ def accel_search(
      numindep, thresh) = _search_setup(N, T, cfg)
     Z, Wn = len(zs), len(ws)
 
+    grid_lo, n_seg, lo, hi = _ladder_grid(stages, rlo, rhi, N, segw)
+    if not n_seg:
+        return []
     # pad the spectrum: conjugate reflection in front (bin -k of a real
     # input's FFT is conj(bin k)) so templates overhanging the lowest bins
     # correlate against physically correct values; zeros past Nyquist
     spec_pad2 = _build_spec_pad(jnp.asarray(f_re), jnp.asarray(f_im),
                                 front, int(max(Np - N, 8)))
+    thresh_vals = np.array([thresh[H] for H in stages])
 
-    def run_stage(H, banks_src, Zrows, thresh_val, seg_ids):
-        """One harmonic stage over ``seg_ids``; device residency bounded
-        per stage: only this stage's <= H ratio banks live in HBM at once
-        (a full jerk bank set across all stages would be tens of GB at
-        survey parameters). Slice starts are affine in the segment index
-        — start = off0 + si*step, exact because H divides both top_lo and
-        segw — so the whole pass runs as one compiled lax.scan (one
-        dispatch; see _make_stage_runner); the stage's tfs/idxs device
-        buffers free on return, before the next stage allocates."""
-        top_lo, top_hi, _ = _stage_range(H, rlo, rhi, N, segw)
-        bank_meta, tfs, idxs = _stage_banks(banks_src, H, top_lo, segw,
-                                            front)
-        runner = _make_stage_runner(segw, Zrows, Wn, cfg.topk,
-                                    tuple(bank_meta))
-        with telemetry.span("accel_stage", H=int(H),
-                            n_seg=int(len(seg_ids))):
+    def run_ladder(banks_src, Zrows, tvals, seg_ids):
+        """Every harmonic stage over ``seg_ids``, each [len(seg_ids),
+        n_stages, Wn, ...]. Device residency is the search's distinct
+        ratio banks, each once (what the deepest stage alone needs; a
+        jerk bank set is GB-scale at survey parameters); the whole pass
+        is one compiled lax.scan (one dispatch; see _make_ladder_runner)
+        and the tfs/idxs device buffers free on return."""
+        rungs, tfs, idxs = _ladder_banks(banks_src, stages, grid_lo, segw,
+                                         front)
+        runner = _make_ladder_runner(segw, Zrows, Wn, cfg.topk, rungs,
+                                     batched=False)
+        telemetry.counter("accel.bank_passes", len(seg_ids) * len(tfs))
+        with telemetry.span("accel_stage", stages=len(stages),
+                            banks=len(tfs), n_seg=int(len(seg_ids))):
             return pull_host(*runner(
-                spec_pad2, tuple(tfs), tuple(idxs), top_lo, top_hi,
-                jnp.float32(thresh_val),
+                spec_pad2, tfs, idxs, grid_lo, lo, hi,
+                tvals.astype(np.float32),
                 jnp.asarray(seg_ids, dtype=jnp.int32)))
 
-    def coarse_hits(H, banks_c, Zc, thresh_val, seg_ids):
-        vals, _zi, _ri, _ne = run_stage(H, banks_c, Zc, thresh_val, seg_ids)
-        return np.isfinite(vals).any(axis=(1, 2))
+    def coarse_hits(banks_c, Zc, thresh_c, seg_ids):
+        vals, _zi, _ri, _ne = run_ladder(banks_c, Zc, thresh_c, seg_ids)
+        return np.isfinite(vals).any(axis=(1, 2, 3))
 
-    # optional coarse pass (cfg.coarse_dz): the same stages on a coarse z
-    # grid at a reduced power threshold select which segments the fine
+    # optional coarse pass (cfg.coarse_dz): the same ladder on a coarse z
+    # grid at reduced power thresholds selects which segments the fine
     # pass scans
-    seg_sel = None
-    if cfg.coarse_dz > cfg.dz:
-        seg_sel = _coarse_segment_sel(N, T, cfg, stages, rlo, rhi, segw,
-                                      front, Np, thresh, coarse_hits)
+    ids, scan_ids = _select_segments(N, T, cfg, front, Np, n_seg,
+                                     thresh_vals, coarse_hits)
+    if not len(ids):
+        return []
 
-    raw_hits = []  # (stage, w idx, seg r0, vals, zidx, colidx, neigh, width)
-    for H in stages:
-        top_lo, top_hi, n_seg = _stage_range(H, rlo, rhi, N, segw)
-        if not n_seg:
-            continue
-        ids = np.arange(n_seg) if seg_sel is None else seg_sel[H]
-        if not len(ids):
-            continue
-        vals, zi, ri, neigh = run_stage(
-            H, banks, Z, thresh[H],
-            ids if seg_sel is None else _pad_pow2(ids, n_seg))
-        for pos in range(len(ids)):
-            si = int(ids[pos])
-            r0 = top_lo + si * segw
-            width = min(segw, top_hi - r0)
+    vals, zi, ri, neigh = run_ladder(banks, Z, thresh_vals, scan_ids)
+    raw_hits = []  # (stage, w idx, seg r0, vals, zidx, colidx, neigh)
+    for s, H in enumerate(stages):
+        for pos, si in enumerate(ids):
+            r0 = grid_lo + int(si) * segw
             for wi in range(Wn):
-                raw_hits.append((H, wi, r0, vals[pos, wi], zi[pos, wi],
-                                 ri[pos, wi], neigh[pos, wi], width))
+                raw_hits.append((H, wi, r0, vals[pos, s, wi], zi[pos, s, wi],
+                                 ri[pos, s, wi], neigh[pos, s, wi]))
 
     return _refine_hits(raw_hits, zs, ws, cfg, numindep, thresh)
 
 
 def _stage_chunk_bytes(tfs, Z: int, Wn: int, segw: int) -> int:
-    """Estimated device bytes PER BATCHED SPECTRUM for one harmonic
-    stage's scan body: every ratio bank (``tfs`` entry, [2, rows, L])
+    """Estimated device bytes PER BATCHED SPECTRUM for the ladder's
+    scan body: every ratio bank (``tfs`` entry, [2, rows, L])
     materializes a [rows, L] complex64 correlation plus its FFT-input
     product (16 B/cell live at once), the |.|^2 power (4 B/cell), and
     the [Z*Wn, 2*segw] gathered plane (two f32 copies around the
@@ -822,18 +831,18 @@ def accel_search_batch(
     ``(re, im)`` tuple of real [B, N] plane arrays — the complex-boundary
     convention (ops/transfer) that lets device-resident spectra from
     ``kernels.prep_spectra_batch`` feed the search without a host round
-    trip. Every
-    harmonic stage correlates all B spectra against the one device-
-    resident bank in a single dispatch (_make_stage_runner_batch), so
-    the bank FFT cost, the dispatch latency, and the TPU's preference
+    trip. The
+    whole harmonic ladder correlates all B spectra against the one set
+    of device-resident banks in a single dispatch (_make_ladder_runner),
+    so the bank FFT cost, the dispatch latency, and the TPU's preference
     for large FFT batches all amortize over the batch. Returns one
     sifted candidate list per input spectrum, in order — identical to
     ``[accel_search(f, T, config) for f in ffts]`` (parity-tested).
 
-    The batch axis is internally processed in per-stage chunks sized so
-    the stage's working set fits ``hbm_budget_bytes`` (default: the
+    The batch axis is internally processed in chunks sized so the
+    scan's working set fits ``hbm_budget_bytes`` (default: the
     ``PYPULSAR_TPU_ACCEL_HBM`` env var or 5e9). The full batch of padded
-    spectra stays device-resident across stages (B*Np complex ~ 17 MB
+    spectra stays device-resident across chunks (B*Np complex ~ 17 MB
     per 2^21-bin spectrum); only the scan working set is chunked.
 
     ``mesh_devices`` > 0 shards the batch over that many devices
@@ -884,7 +893,7 @@ def accel_search_batch(
         hbm_budget_bytes = int(
             knobs.env_float("PYPULSAR_TPU_ACCEL_HBM"))
 
-    # the padded spectra themselves stay device-resident across stages
+    # the padded spectra themselves stay device-resident across chunks
     # (~8*Np bytes each); a batch large enough to blow half the budget on
     # residency alone is processed in top-level slices (each slice still
     # amortizes the banks over its spectra)
@@ -905,14 +914,18 @@ def accel_search_batch(
     spec_pad2 = _build_spec_pad_batch(jnp.asarray(re_a), jnp.asarray(im_a),
                                       front, int(max(Np - N, 8)))
 
-    def run_stage_chunks(H, banks_src, Zrows, thresh_val, seg_ids):
-        """Yield (c0, nb, vals, zi, ri, neigh) per batch chunk for one
-        harmonic stage scanned over ``seg_ids``; the chunk size respects
-        the per-device HBM budget and the stage's bank buffers free when
-        the generator is exhausted."""
-        top_lo, top_hi, _ = _stage_range(H, rlo, rhi, N, segw)
-        bank_meta, tfs, idxs = _stage_banks(banks_src, H, top_lo, segw,
-                                            front)
+    grid_lo, n_seg, lo, hi = _ladder_grid(stages, rlo, rhi, N, segw)
+    if not n_seg:
+        return [[] for _ in range(B)]
+    thresh_vals = np.array([thresh[H] for H in stages])
+
+    def run_ladder_chunks(banks_src, Zrows, tvals, seg_ids):
+        """Yield (c0, nb, vals, zi, ri, neigh) per batch chunk for the
+        whole ladder scanned over ``seg_ids``; the chunk size respects
+        the per-device HBM budget and the bank buffers free when the
+        generator is exhausted."""
+        rungs, tfs, idxs = _ladder_banks(banks_src, stages, grid_lo, segw,
+                                         front)
         # the budget is per device: a sharded chunk splits across the
         # mesh, so the whole chunk may hold mesh_devices x the budget
         per_dev = max(1, hbm_budget_bytes
@@ -920,10 +933,10 @@ def accel_search_batch(
         chunk = max(1, min(B, per_dev * max(1, mesh_devices)))
         if mesh_devices:
             chunk = max(mesh_devices, (chunk // mesh_devices) * mesh_devices)
-        runner = _make_stage_runner_batch(segw, Zrows, Wn, cfg.topk,
-                                          tuple(bank_meta),
-                                          mesh_devs=devices)
+        runner = _make_ladder_runner(segw, Zrows, Wn, cfg.topk, rungs,
+                                     mesh_devs=devices)
         ids_dev = jnp.asarray(seg_ids, dtype=jnp.int32)
+        thresh_dev = tvals.astype(np.float32)
         span_attrs = {}
         if devices:
             span_attrs["dev"] = [int(getattr(d, "id", -1))
@@ -936,65 +949,63 @@ def accel_search_batch(
             # for its shape but never ships dead spectra through the scan
             nc = min(chunk, B - c0)
 
-            def dispatch(lo, hi, c0=c0):
+            def dispatch(lo_b, hi_b, c0=c0):
                 faultinject.trip("accel.stage_dispatch")
-                sl = spec_pad2[c0 + lo:c0 + hi]
-                with telemetry.span("accel_stage_batch", H=int(H),
-                                    batch=int(hi - lo),
+                sl = spec_pad2[c0 + lo_b:c0 + hi_b]
+                telemetry.counter("accel.bank_passes",
+                                  len(seg_ids) * len(tfs))
+                with telemetry.span("accel_stage_batch",
+                                    stages=len(stages), banks=len(tfs),
+                                    batch=int(hi_b - lo_b),
                                     n_seg=int(len(seg_ids)),
                                     **span_attrs):
-                    # [len(seg_ids), nb, Wn, k] each; one batched pull
+                    # [len(seg_ids), n_stages, nb, Wn, k] each; one
+                    # batched pull
                     return pull_host(*runner(
-                        sl, tuple(tfs), tuple(idxs), top_lo, top_hi,
-                        jnp.float32(thresh_val), ids_dev))
+                        sl, tfs, idxs, grid_lo, lo, hi, thresh_dev,
+                        ids_dev))
 
             # the HBM budget is an estimate: a chunk it admitted that
             # still RESOURCE_EXHAUSTs auto-halves with bounded backoff
             # (per-spectrum results are independent — the halves are the
             # chunk, bit-identically). Sharded chunks stay divisible by
             # the mesh via min_size
-            for lo, hi, outs in halving_dispatch(
+            for lo_b, hi_b, outs in halving_dispatch(
                     dispatch, nc, min_size=max(1, mesh_devices),
                     what="accel.stage"):
                 vals, zi, ri, neigh = outs
-                yield c0 + lo, hi - lo, vals, zi, ri, neigh
+                yield c0 + lo_b, hi_b - lo_b, vals, zi, ri, neigh
 
-    def coarse_hits(H, banks_c, Zc, thresh_val, seg_ids):
+    def coarse_hits(banks_c, Zc, thresh_c, seg_ids):
         hit = np.zeros(len(seg_ids), bool)
-        for _c0, _nb, vals, _zi, _ri, _ne in run_stage_chunks(
-                H, banks_c, Zc, thresh_val, seg_ids):
-            hit |= np.isfinite(vals).any(axis=(1, 2, 3))
+        for _c0, _nb, vals, _zi, _ri, _ne in run_ladder_chunks(
+                banks_c, Zc, thresh_c, seg_ids):
+            hit |= np.isfinite(vals).any(axis=(1, 2, 3, 4))
         return hit
 
-    # optional coarse pass (cfg.coarse_dz): stage segments are selected by
-    # the UNION of coarse hits over the whole batch — the per-DM spectra
-    # of one observation concentrate their signal in the same segments,
-    # which is also why the bank sharing works
-    seg_sel = None
-    if cfg.coarse_dz > cfg.dz:
-        seg_sel = _coarse_segment_sel(N, T, cfg, stages, rlo, rhi, segw,
-                                      front, Np, thresh, coarse_hits)
+    # optional coarse pass (cfg.coarse_dz): segments are selected by the
+    # UNION of coarse hits over the stages and the whole batch — the
+    # per-DM spectra of one observation concentrate their signal in the
+    # same segments, which is also why the bank sharing works
+    ids, scan_ids = _select_segments(N, T, cfg, front, Np, n_seg,
+                                     thresh_vals, coarse_hits)
+    if not len(ids):
+        return [[] for _ in range(B)]
 
+    # a spectrum lies in one chunk, so its hits arrive stage by stage
+    # (the order the sift sees ties in)
     raw_per_b: List[list] = [[] for _ in range(B)]
-    for H in stages:
-        top_lo, top_hi, n_seg = _stage_range(H, rlo, rhi, N, segw)
-        if not n_seg:
-            continue
-        ids = np.arange(n_seg) if seg_sel is None else seg_sel[H]
-        if not len(ids):
-            continue
-        for c0, nb, vals, zi, ri, neigh in run_stage_chunks(
-                H, banks, Z, thresh[H],
-                ids if seg_sel is None else _pad_pow2(ids, n_seg)):
-            for pos in range(len(ids)):
-                si = int(ids[pos])
-                r0 = top_lo + si * segw
-                width = min(segw, top_hi - r0)
+    for c0, nb, vals, zi, ri, neigh in run_ladder_chunks(
+            banks, Z, thresh_vals, scan_ids):
+        for s, H in enumerate(stages):
+            for pos, si in enumerate(ids):
+                r0 = grid_lo + int(si) * segw
                 for bl in range(nb):
                     for wi in range(Wn):
                         raw_per_b[c0 + bl].append(
-                            (H, wi, r0, vals[pos, bl, wi], zi[pos, bl, wi],
-                             ri[pos, bl, wi], neigh[pos, bl, wi], width))
+                            (H, wi, r0, vals[pos, s, bl, wi],
+                             zi[pos, s, bl, wi], ri[pos, s, bl, wi],
+                             neigh[pos, s, bl, wi]))
 
     return [_refine_hits(raw, zs, ws, cfg, numindep, thresh)
             for raw in raw_per_b]

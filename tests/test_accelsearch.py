@@ -110,7 +110,7 @@ def test_noise_false_alarm_rate():
 def test_batched_search_matches_serial():
     """accel_search_batch == [accel_search(f) for f] candidate-for-
     candidate (VERDICT r3 item 2): the template banks are DM-independent,
-    so batching B spectra into one dispatch per stage must change no
+    so batching B spectra into one dispatch a chunk must change no
     result."""
     from pypulsar_tpu.fourier.accelsearch import accel_search_batch
 
@@ -963,3 +963,168 @@ def test_device_prep_candidate_contract():
         n_detecting += any(c.sigma > floor + margin for c in hs)
     assert n_detecting >= len(specs) - 2, \
         "battery too weak to exercise the contract"
+
+
+# ---------------------------------------------------------------------------
+# the harmonic ladder: one segment grid, every ratio bank once a segment
+# ---------------------------------------------------------------------------
+
+
+def _ladder_raw_hits(fft, T, cfg):
+    """The serial ladder program's raw device output for ``fft`` with
+    the geometry it ran on: (vals, zi, ri, neigh) each
+    [n_seg, n_stages, Wn, k, ...]."""
+    import jax.numpy as jnp
+
+    from pypulsar_tpu.fourier import accelsearch as acc
+    from pypulsar_tpu.ops.transfer import pull_host, split_complex
+
+    N = len(fft)
+    (zs, ws, stages, segw, rlo, rhi, banks, front, Np, _numindep,
+     thresh) = acc._search_setup(N, T, cfg)
+    grid_lo, n_seg, lo, hi = acc._ladder_grid(stages, rlo, rhi, N, segw)
+    rungs, tfs, idxs = acc._ladder_banks(banks, stages, grid_lo, segw, front)
+    f_re, f_im = split_complex(fft)
+    spec_pad2 = acc._build_spec_pad(jnp.asarray(f_re), jnp.asarray(f_im),
+                                    front, int(max(Np - N, 8)))
+    runner = acc._make_ladder_runner(segw, len(zs), len(ws), cfg.topk,
+                                     rungs, batched=False)
+    tvals = np.array([thresh[H] for H in stages], dtype=np.float32)
+    out = pull_host(*runner(spec_pad2, tfs, idxs, grid_lo, lo, hi, tvals,
+                            jnp.arange(n_seg, dtype=jnp.int32)))
+    geom = dict(zs=zs, ws=ws, stages=stages, segw=segw, rlo=rlo, rhi=rhi,
+                banks=banks, front=front, Np=Np, grid_lo=grid_lo,
+                n_seg=n_seg, lo=lo, hi=hi, thresh=tvals)
+    return out, geom
+
+
+def _scratch_plane(spec_pad, geom, H, si):
+    """Stage ``H``'s plane of segment ``si`` built from nothing in
+    float64: all H ratio banks b/H correlated, stretch-gathered and
+    added, on the common grid; -inf where the stage is not valid."""
+    from fractions import Fraction
+
+    segw, front = geom["segw"], geom["front"]
+    r0 = geom["grid_lo"] + si * segw
+    rows = len(geom["zs"]) * len(geom["ws"])
+    plane = np.zeros((rows, 2 * segw))
+    for b in range(1, H + 1):
+        tf2, hw, L, idx = geom["banks"][Fraction(b, H)]
+        tf = tf2[0].astype(np.float64) + 1j * tf2[1].astype(np.float64)
+        start = front + (b * r0) // H - hw
+        corr = np.fft.ifft(np.fft.fft(spec_pad[start:start + L])[None] * tf,
+                           axis=1)
+        p = (np.abs(corr) ** 2).reshape(rows, 2 * L)
+        plane += p[:, idx]
+    s = geom["stages"].index(H)
+    r_top = r0 + 0.5 * np.arange(2 * segw)
+    valid = (r_top >= geom["lo"][s]) & (r_top < geom["hi"][s])
+    return np.where(valid[None, :], plane, -np.inf)
+
+
+@pytest.mark.parametrize("numharm,fhi,wmax", [
+    (2, None, 0.0), (4, None, 0.0), (8, None, 0.0),
+    (8, 19.0, 0.0),   # the stages' top_hi differ
+    (2, None, 20.0),  # jerk rows interleaved in the plane
+])
+def test_ladder_matches_from_scratch_planes(numharm, fhi, wmax):
+    """Every stage's raw device hits (value, z row, column, 3x3
+    neighbourhood) sit on that stage's plane built FROM SCRATCH in
+    float64 from the same banks on the common grid: the ladder's running
+    sum is the same sum, and the per-stage column mask is the stage's
+    own range. The strongest valid cell of every (segment, stage) over
+    threshold is the device's first hit there."""
+    rng = np.random.RandomState(17)
+    N = (1 << 13) + 1
+    T = 36.3  # rlo = 37: odd, so the grid starts below every stage's lo
+    fft = _drifting_train(rng, 2 * (N - 1), T, f0=11.37, z_true=4.0,
+                          amp=2.0)
+    cfg = AccelSearchConfig(zmax=8.0, dz=2.0, numharm=numharm, fhi=fhi,
+                            sigma_min=2.0, seg_width=1 << 10, wmax=wmax,
+                            topk=16)
+    (vals, zi, ri, neigh), g = _ladder_raw_hits(fft, T, cfg)
+    Wn, front = len(g["ws"]), g["front"]
+    assert g["grid_lo"] == numharm * (37 // numharm) < g["lo"].min()
+    assert vals.shape == (g["n_seg"], len(g["stages"]), Wn, cfg.topk)
+    if fhi:
+        assert len(set(g["hi"].tolist())) == len(g["stages"])
+
+    f = fft.astype(np.complex128)
+    spec_pad = np.concatenate([np.conj(f[1:front + 1][::-1]), f,
+                               np.zeros(g["Np"] - N)])
+    n_hits = 0
+    for si in range(g["n_seg"]):
+        for s, H in enumerate(g["stages"]):
+            ref = _scratch_plane(spec_pad, g, H, si)
+            tol = 2e-5 * max(float(ref[np.isfinite(ref)].max(initial=1.0)),
+                             1.0)
+            for wi in range(Wn):
+                sub = ref[wi::Wn]
+                padded = np.pad(sub, 1, constant_values=-np.inf)
+                v = vals[si, s, wi]
+                ok = np.isfinite(v)
+                # the strongest valid cell is a local maximum: over
+                # threshold it is the first hit, under it there is none
+                peak = sub.max()
+                if peak > g["thresh"][s] + tol:
+                    assert ok[0] and abs(v[0] - peak) <= tol
+                elif peak < g["thresh"][s] - tol:
+                    assert not ok.any()
+                for j in np.nonzero(ok)[0]:
+                    z, c = int(zi[si, s, wi, j]), int(ri[si, s, wi, j])
+                    assert v[j] > g["thresh"][s]
+                    assert abs(v[j] - sub[z, c]) <= tol
+                    want = padded[z:z + 3, c:c + 3]
+                    got = neigh[si, s, wi, j].astype(np.float64)
+                    assert (np.isfinite(got) == np.isfinite(want)).all()
+                    fin = np.isfinite(want)
+                    assert np.abs(got[fin] - want[fin]).max() <= tol
+                    n_hits += 1
+    assert n_hits >= g["n_seg"], "spectrum too quiet to exercise the ladder"
+
+
+def test_ladder_bank_passes_and_stage_mask(tmp_path):
+    """A dispatch correlates every ratio bank ONCE a segment
+    (``accel.bank_passes`` = segments x len(ratios): 8 banks at numharm
+    8, not sum(H) = 15), its span says how many stages and banks it
+    walked, and stage H admits no hit below H * rlo although every
+    stage shares the grid that starts below them all."""
+    from pypulsar_tpu.fourier.accelsearch import accel_search_batch
+    from pypulsar_tpu.obs import telemetry
+
+    rng = np.random.RandomState(23)
+    N = (1 << 13) + 1
+    T = 36.3
+    # a strong train with harmonics below 8 * rlo = 296: bright
+    # columns inside the grid's first segment that only stage 1 (and 2,
+    # 4 above their own lo) may report
+    fft = _drifting_train(rng, 2 * (N - 1), T, f0=1.05, z_true=0.0, amp=3.0)
+    cfg = AccelSearchConfig(zmax=8.0, dz=2.0, numharm=8, sigma_min=2.0,
+                            seg_width=1 << 10, topk=32)
+    (vals, _zi, ri, _neigh), g = _ladder_raw_hits(fft, T, cfg)
+    assert g["grid_lo"] == 32 and g["lo"].tolist() == [37, 74, 148, 296]
+    seen_low = False
+    for s, H in enumerate(g["stages"]):
+        for si in range(g["n_seg"]):
+            ok = np.isfinite(vals[si, s])
+            r_top = g["grid_lo"] + si * g["segw"] + 0.5 * ri[si, s][ok]
+            assert (r_top >= H * g["rlo"]).all()
+            assert (r_top < g["hi"][s]).all()
+            seen_low |= bool((r_top < 296).any())
+    assert seen_low, "no hit under 8 * rlo: the mask was not exercised"
+
+    import json
+
+    trace = tmp_path / "ladder.jsonl"
+    with telemetry.session(str(trace)) as tlm:
+        accel_search_batch(np.stack([fft, fft]), T, cfg)
+        assert tlm.counter_totals()["accel.bank_passes"] == g["n_seg"] * 8
+        accel_search(fft, T, cfg)
+        assert tlm.counter_totals()["accel.bank_passes"] == g["n_seg"] * 16
+    spans = [r for r in map(json.loads, trace.read_text().splitlines())
+             if r.get("name") in ("accel_stage", "accel_stage_batch")]
+    assert sorted(r["name"] for r in spans) == ["accel_stage",
+                                                "accel_stage_batch"]
+    for r in spans:
+        assert r["attrs"]["stages"] == 4 and r["attrs"]["banks"] == 8
+        assert r["attrs"]["n_seg"] == g["n_seg"] and "H" not in r["attrs"]

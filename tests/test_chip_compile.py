@@ -246,7 +246,7 @@ def test_prep_spectra_batch(one_chip):
     assert temp + argb + 3 * out < V5E_HBM_BYTES
 
 
-def _accel_stage(H, batch, mesh_devs, spec_sh, table_sh):
+def _accel_stage(batch, mesh_devs, spec_sh, table_sh):
     from pypulsar_tpu.fourier import accelsearch as acc
 
     cfg = acc.AccelSearchConfig(zmax=ZMAX, dz=2.0, numharm=NUMHARM,
@@ -254,30 +254,32 @@ def _accel_stage(H, batch, mesh_devs, spec_sh, table_sh):
     N = NSAMP // 2 + 1
     (zs, ws, stages, segw, rlo, rhi, banks, front, Np, _numindep,
      _thresh) = acc._search_setup(N, NSAMP * TSAMP, cfg)
-    assert H in stages and N - 1 == 1 << 21
+    assert max(stages) == NUMHARM and N - 1 == 1 << 21
     Z, Wn = len(zs), len(ws)
-    top_lo, top_hi, n_seg = acc._stage_range(H, rlo, rhi, N, segw)
-    bank_meta, tfs, idxs = acc._stage_banks(banks, H, top_lo, segw, front)
+    grid_lo, n_seg, lo, hi = acc._ladder_grid(stages, rlo, rhi, N, segw)
+    rungs, tfs, idxs = acc._ladder_banks(banks, stages, grid_lo, segw, front)
+    assert len(tfs) == 8  # every ratio bank once, not sum(H) = 15
     # the batch chunk accel_search_batch dispatches: what the default
     # per-device PYPULSAR_TPU_ACCEL_HBM budget (5e9) admits of the batch
     per_dev = max(1, int(5e9) // acc._stage_chunk_bytes(tfs, Z, Wn, segw))
     chunk = min(batch, per_dev * max(1, len(mesh_devs)))
-    runner = acc._make_stage_runner_batch(
-        segw, Z, Wn, cfg.topk, tuple(bank_meta), mesh_devs=mesh_devs)
+    runner = acc._make_ladder_runner(
+        segw, Z, Wn, cfg.topk, rungs, mesh_devs=mesh_devs)
     return runner._jit.lower(
         _sds((chunk, 2, Np), jnp.float32, spec_sh),
         tuple(_sds(t.shape, t.dtype, table_sh) for t in tfs),
         tuple(_sds(i.shape, i.dtype, table_sh) for i in idxs),
-        top_lo, top_hi, _sds((), jnp.float32, table_sh),
+        grid_lo,
+        *(_sds((len(stages),), dt, table_sh)
+          for dt in (jnp.int32, jnp.int32, jnp.float32)),
         _sds((n_seg,), jnp.int32, table_sh))
 
 
 def test_accel_search_stage(one_chip):
-    """The deepest harmonic stage (all 8 subharmonic banks) of the
-    zmax-50 search over 2^21-bin spectra, inside the accel HBM budget
-    the batch chunking plans against."""
-    compiled = _accel_stage(NUMHARM, ACCEL_BATCH, (), one_chip,
-                            one_chip).compile()
+    """The whole harmonic ladder (all 8 ratio banks, four detections a
+    segment) of the zmax-50 search over 2^21-bin spectra, inside the
+    accel HBM budget the batch chunking plans against."""
+    compiled = _accel_stage(ACCEL_BATCH, (), one_chip, one_chip).compile()
     temp, args = _device_bytes(compiled)
     assert temp + args < V5E_HBM_BYTES
 
@@ -337,7 +339,7 @@ def test_sharded_series_chunk_four_chips(mesh4):
 
 def test_sharded_accel_search_four_chips(topo, mesh4):
     """`--gang 4`: the handoff batch (32 spectra under a gang of 4)
-    sharded over the same mesh through prep and the deepest stage."""
+    sharded over the same mesh through prep and the harmonic ladder."""
     from pypulsar_tpu.fourier.kernels import _prep_spectra_kernel
 
     batch = 2 * ACCEL_BATCH
@@ -345,8 +347,7 @@ def test_sharded_accel_search_four_chips(topo, mesh4):
     sch, args = _prep_args(batch, shd, rep)
     prep = _prep_spectra_kernel._jit.lower(
         *args, maxlen=sch.maxlen).compile()
-    stage = _accel_stage(NUMHARM, batch, tuple(topo.devices), shd,
-                         rep).compile()
+    stage = _accel_stage(batch, tuple(topo.devices), shd, rep).compile()
     for compiled in (prep, stage):
         assert not [c for c in COLLECTIVES if c in compiled.as_text()]
         temp, argb = _device_bytes(compiled)

@@ -754,4 +754,11 @@ def test_trace_report_reads_spans_and_attrs_from_the_xplane(tmp_path):
     assert tr.scope_of({"tf_op": "jit(accel_stage)/accel.accel_stage/while/"
                                  "body/accel.correlate/fft"}) == (
         "accel.accel_stage", "accel.correlate")
+    # a scope inside a vmapped body is printed wrapped in the transform
+    assert tr.scope_of({"tf_op": "jit(accel_stage_batch)/accel.accel_stage_"
+                                 "batch/while/body/closed_call/"
+                                 "vmap(accel.harmonic_sum)/gather"}) == (
+        "accel.accel_stage_batch", "accel.harmonic_sum")
+    assert tr.scope_of({"tf_op": "jit(f)/vmap(jit(_where))/select_n"}) == (
+        "jit(f)", None)
     assert tr.scope_of({"device_offset_ps": 5}) == (None, None)

@@ -4,34 +4,48 @@ sweep has one — FLOPs+bytes per cell for the batched fft->multiply->ifft
 + stretch-gather, vs the measured 555-577M cells/s — so the gap is
 known, not guessed").
 
-The model walks the EXACT geometry the stage runners execute
-(fourier/accelsearch._make_stage_runner_batch): for every harmonic stage
-H and subharmonic ratio b/H it derives the bank height (rows = 2*Z*Wn
-interleaved half-bin templates), the template half-width (zresponse.
-zw_halfwidth of the ratio-scaled drift), and the power-of-two FFT length
-L_b = fourier_chunk_len(segw*b/H + 4*hw_b) — then counts, per searched
-(r, z) cell:
+The model walks the EXACT geometry the ladder runner executes
+(fourier/accelsearch._ladder_scan): ONE segment grid for all harmonic
+stages, whose origin is the lowest searched bin rounded down to a
+multiple of the top stage, and per segment ONE pass over each distinct
+subharmonic ratio — stage H adds the ratios b/H in lowest terms to the
+running sum (1; 1/2; 1/4, 3/4; the odd eighths: 8 bank passes a segment
+at numharm 8, where the per-stage programs before PR 32 made sum(H) =
+15) — and one detection per stage (4 a segment). For every ratio it
+derives the bank height (rows = 2*Z*Wn interleaved half-bin templates),
+the template half-width (zresponse.zw_halfwidth of the ratio-scaled
+drift), and the power-of-two FFT length L_b = fourier_chunk_len(segw*b/H
++ 4*hw_b) — then counts, per searched (r, z) cell (a stage's cells are
+the columns it is VALID on, H*rlo up to Nyquist, not the whole grid):
 
 - FFT flops (the 5 L log2 L convention): one forward FFT of the slice
   per (spectrum, segment, bank) plus ``rows`` inverse FFTs — the inverse
   transforms dominate everything else by an order of magnitude;
 - non-FFT flops: the broadcast complex multiply (6/elem), |.|^2
-  (3/elem), and the stretch-gather + accumulate (2/cell/bank);
+  (3/elem), the stretch-gather + accumulate (2/cell/bank), and per
+  stage the column mask, the 4-neighbour local-max test and the
+  threshold (10/cell/stage; top_k's selection is not counted);
 - HBM bytes under a no-fusion worst case and a fused best case, with the
   bank reads amortized over the batch (they are batch-invariant — the
   whole point of accel_search_batch).
 
+``benchmark/counts.py``'s ``ACCEL_FLOPS_PER_CELL`` 346.6 is the OLD
+model's figure (every stage built from scratch, 15 passes, at this
+tool's reference geometry): it is the benchmark's yardstick for
+``kernel_roofline_pct`` and the next ``benchmark`` issue's to revisit
+(PERF.md section 7, "cannot see" (2)); this tool now prints what runs.
+
 Practical ceilings come from MEASURED on-chip rates, not datasheet peaks:
 XLA's TPU FFT throughput on this v5e measured 121 GFLOP/s (batched
 irfft) to 204 GFLOP/s (rfft) in the component probe (BENCHNOTES), and
-the HBM roofline is 819 GB/s. The verdict this script prints — and
-BENCHNOTES round 6 commits — is that the measured dispatch-level
-555-577M cells/s sits AT the irfft-rate ceiling (~90-105% of it), i.e.
-the batched stage is FFT-throughput-bound and the remaining CLI-level
-gap (400M incl. I/O) is host/pipeline time, which the round-6 pipelined
-driver attacks. 800M cells/s at the CLI is unreachable without a faster
-FFT (smaller L padding, half-size real transforms, or a bf16 FFT), not
-more overlap.
+the HBM roofline is 819 GB/s. The default ``--measured`` 577M cells/s is
+the dispatch-level rate of the per-stage programs (BENCHNOTES round 6,
+which placed it at 90-105% of THEIR irfft-rate ceiling: that stage was
+FFT-throughput-bound); against the ladder's fewer flops per cell the
+same figure reads far under the ceiling, so pass the ladder's own
+measured rate (PERF.md section 5) to place it. What is left to take out
+of the FFT work is the padding (every L sits one bin past a power of
+two) and half-size real transforms.
 
 Usage: python tools/accel_roofline.py [--n 2097152] [--zmax 200]
            [--numharm 8] [--measured 577e6] [--json]
@@ -99,20 +113,30 @@ def parse_args(argv=None):
 
 def analyze(n, zmax, dz, numharm, segw, min_halfwidth, batch, rlo,
             Wn: int = 1):
-    """Per-stage and total (flops, bytes) per searched cell. Returns a
-    dict of the full accounting."""
+    """Per-rung and total (flops, bytes) per searched cell. Returns a
+    dict of the full accounting. A stage's row holds what ITS rung adds
+    to the ladder (the banks it brings and its own detection); the
+    cells are those the stage is valid on."""
     Z = int(math.floor(2 * zmax / dz)) + 1
     rows = 2 * Z * Wn  # interleaved integer/half-bin template rows
     stages = [h for h in (1, 2, 4, 8) if h <= numharm]
+    hmax = max(stages)
+    grid_lo, grid_hi = hmax * (rlo // hmax), n - 1
+    n_seg = -(-(grid_hi - grid_lo) // segw)
+    cells_seg = Z * Wn * 2 * segw  # plane cells per segment
     per_stage = []
     tot_cells = tot_fft = tot_other = 0.0
     tot_bytes_lo = tot_bytes_hi = 0.0
+    n_banks = 0
     for H in stages:
-        top_lo, top_hi = H * rlo, min(H * (n - 1), n - 1)
-        n_seg = -(-(top_hi - top_lo) // segw)
-        cells_seg = Z * Wn * 2 * segw  # searched cells per segment
-        fft_seg = other_seg = b_lo = b_hi = 0.0
-        for b in range(1, H + 1):
+        fft_seg = b_lo = b_hi = 0.0
+        # the stage's own detection: mask, local max, threshold
+        other_seg = 10.0 * cells_seg
+        b_lo += 4 * cells_seg
+        b_hi += 12 * cells_seg
+        rung = [b for b in range(1, H + 1) if math.gcd(b, H) == 1]
+        n_banks += len(rung)
+        for b in rung:
             hw = zw_halfwidth(zmax * b / H, 0.0, min_halfwidth)
             L = fourier_chunk_len((segw * b) // H + 4 * hw)
             lg = math.log2(L)
@@ -126,13 +150,13 @@ def analyze(n, zmax, dz, numharm, segw, min_halfwidth, batch, rlo,
             b_hi += (8 * L + 16 * L + 8 * rows * L / batch
                      + 16 * rows * L + 4 * rows * L
                      + 4 * cells_seg + 8 * cells_seg)
-        cells = n_seg * cells_seg
+        cells = Z * Wn * 2 * max(n - 1 - H * rlo, 0)
         per_stage.append(dict(
-            H=H, n_seg=n_seg, cells=cells,
-            fft_flops_per_cell=round(fft_seg / cells_seg, 1),
-            other_flops_per_cell=round(other_seg / cells_seg, 1),
-            bytes_per_cell_fused=round(b_lo / cells_seg, 1),
-            bytes_per_cell_worst=round(b_hi / cells_seg, 1),
+            H=H, n_seg=n_seg, cells=cells, banks=len(rung),
+            fft_flops_per_cell=round(n_seg * fft_seg / cells, 1),
+            other_flops_per_cell=round(n_seg * other_seg / cells, 1),
+            bytes_per_cell_fused=round(n_seg * b_lo / cells, 1),
+            bytes_per_cell_worst=round(n_seg * b_hi / cells, 1),
         ))
         tot_cells += cells
         tot_fft += n_seg * fft_seg
@@ -141,6 +165,8 @@ def analyze(n, zmax, dz, numharm, segw, min_halfwidth, batch, rlo,
         tot_bytes_hi += n_seg * b_hi
     return dict(
         Z=Z, rows=rows, stages=stages, per_stage=per_stage,
+        n_seg=n_seg, bank_passes_per_seg=n_banks,
+        detections_per_seg=len(stages),
         total_cells=int(tot_cells),
         fft_flops_per_cell=round(tot_fft / tot_cells, 1),
         other_flops_per_cell=round(tot_other / tot_cells, 1),
@@ -207,10 +233,13 @@ def main(argv=None):
     print(f"# accel (r,z) roofline @ N={a.n}, zmax={a.zmax:.0f}, "
           f"dz={a.dz:g}, H<={a.numharm}, segw={a.segw}, batch={a.batch}")
     print(f"# Z={r['Z']} drift rows x2 interleave = {r['rows']} bank rows")
-    print("# stage   n_seg   cells/spec    FFT fl/cell  other fl/cell  "
+    print(f"# one ladder a segment: {r['n_seg']} segments x "
+          f"{r['bank_passes_per_seg']} bank passes + "
+          f"{r['detections_per_seg']} detections")
+    print("# rung   +banks   cells/spec    FFT fl/cell  other fl/cell  "
           "B/cell fused..worst")
     for s in r["per_stage"]:
-        print(f"#  H={s['H']:<2d} {s['n_seg']:7d} {s['cells']:12d} "
+        print(f"#  H={s['H']:<2d} {s['banks']:7d} {s['cells']:12d} "
               f"{s['fft_flops_per_cell']:12.1f} "
               f"{s['other_flops_per_cell']:14.1f}  "
               f"{s['bytes_per_cell_fused']:8.1f}.."

@@ -231,6 +231,15 @@ def host_lines(planes):
 
 
 _SCOPE = re.compile(r"^[a-z][a-z0-9_]*\.[A-Za-z0-9_.+]+$")
+# a scope entered inside a transformed function is printed wrapped in the
+# transform: accel.correlate under vmap is "vmap(accel.correlate)"
+_TRANSFORMED = re.compile(r"^[a-z_]+\((.*)\)$")
+
+
+def _unwrap(part: str) -> str:
+    while (m := _TRANSFORMED.match(part)):
+        part = m.group(1)
+    return part
 
 
 def scope_of(stats: dict):
@@ -238,7 +247,8 @@ def scope_of(stats: dict):
     operation's ``jit(f)/scope/.../primitive`` path; (None, None) if none."""
     for v in stats.values():
         if isinstance(v, str) and "/" in v and v.startswith(("jit(", "pjit(")):
-            parts = [p for p in v.split("/")[1:-1] if _SCOPE.match(p)]
+            parts = [p for p in map(_unwrap, v.split("/")[1:-1])
+                     if _SCOPE.match(p)]
             if parts:
                 return parts[0], parts[-1]
             return v.split("/")[0], None
