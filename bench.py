@@ -306,7 +306,7 @@ def budget_shapes(C, T_req, plan, hbm_bytes):
     """
     from pypulsar_tpu.parallel.sweep import default_chunk_payload
 
-    payload = default_chunk_payload(plan.min_overlap)
+    payload = default_chunk_payload(plan)
     n = payload + plan.min_overlap  # round-5 chunk-length A/B, BENCHNOTES
     budget = 0.75 * hbm_bytes
     chunk_bytes = 4 * C * n
@@ -844,7 +844,7 @@ def run_stream(args):
     plan = make_sweep_plan(dms, freqs, dt, nsub=nsub, group_size=group)
     from pypulsar_tpu.parallel.sweep import default_chunk_payload
 
-    payload = default_chunk_payload(plan.min_overlap)
+    payload = default_chunk_payload(plan)
     file_gb = file_T * C * fb.nbits / 8 / 1e9
     streamed_gb = T * C * fb.nbits / 8 / 1e9
     nchunks = -(-T // payload)

@@ -130,6 +130,9 @@ class TraceSummary:
         self.counters: Dict[str, float] = {}
         self.gauges: Dict[str, dict] = {}
         self.events: Dict[str, int] = {}
+        # event name -> attrs of the last sweep.chunk_plan /
+        # rfifind.block_plan: what plan/lengths.py planned, and why
+        self.length_plans: Dict[str, dict] = {}
         self.wall: Optional[float] = None
         self.last_device: Optional[dict] = None
         self.n_events = 0
@@ -244,6 +247,8 @@ class TraceSummary:
             self.n_events += 1
             name = rec.get("name", "?")
             self.events[name] = self.events.get(name, 0) + 1
+            if name in ("sweep.chunk_plan", "rfifind.block_plan"):
+                self.length_plans[name] = rec.get("attrs") or {}
             if not self._obs_trace and name in (
                     "survey.obs_adopted", "survey.obs_ceded",
                     "survey.host_strike",
@@ -380,6 +385,7 @@ def combine_summaries(summaries: List[TraceSummary]) -> TraceSummary:
             for k, n in st.items():
                 ent[k] = ent.get(k, 0) + n
         out.tune_winners.update(s.tune_winners)
+        out.length_plans.update(s.length_plans)
         if s.last_device is not None:
             out.last_device = s.last_device
     out.wall = wall
@@ -405,6 +411,33 @@ def expand_trace_args(paths: List[str]) -> List[str]:
         else:
             out.append(fn)
     return out
+
+
+def _render_lengths(s: TraceSummary, p) -> None:
+    def memory(ev):
+        if ev.get("budget_bytes", -1) < 0:
+            return "no memory bound reported"
+        return (f"{_fmt_bytes(ev.get('need_bytes', 0))} counted of "
+                f"{_fmt_bytes(ev['budget_bytes'])} planned for")
+
+    chunk = s.length_plans.get("sweep.chunk_plan")
+    block = s.length_plans.get("rfifind.block_plan")
+    if chunk:
+        line = (f"#\n# lengths: sweep chunk {chunk.get('chunk')} samples x "
+                f"{chunk.get('nchan')} channels (payload "
+                f"{chunk.get('payload')}, overlap {chunk.get('overlap')}; "
+                f"{chunk.get('bound')}: {memory(chunk)})")
+        done = s.counters.get("sweep.chunk_samples")
+        if done:
+            line += (f"  new sky "
+                     f"{100.0 * s.counters.get('sweep.payload_samples', 0) / done:.1f}%"
+                     f" of {_fmt_count(done)} samples transformed")
+        p(line)
+    if block:
+        p(("#\n# lengths: " if not chunk else "#          ")
+          + f"mask block {block.get('intervals')} intervals x "
+            f"{block.get('pts')} samples x {block.get('nchan')} channels "
+            f"({block.get('bound')}: {memory(block)})")
 
 
 def render(s: TraceSummary, file: TextIO, top: int = 20) -> None:
@@ -555,6 +588,10 @@ def render(s: TraceSummary, file: TextIO, top: int = 20) -> None:
                          f"{s.obs_wall[0] / s.obs_wall[1]:.2f}s "
                          f"({s.obs_wall[1]})")
             p(line)
+    # planned lengths: the sweep's chunk and the mask stage's block as
+    # plan/lengths.py decided them, the bound that decided, and the share
+    # of every sample transformed that was new sky
+    _render_lengths(s, p)
     # per-host roll-up (round 18): the multi-host fleet's utilization
     # and membership churn — busy seconds per host from the scheduler's
     # host-stamped stage spans, adoption/cede/strike counts per host

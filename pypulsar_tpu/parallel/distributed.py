@@ -338,7 +338,7 @@ def _time_shard_local_accum(reader, dms, rank, count, nsub, group_size,
     if chunk_payload is None:
         from pypulsar_tpu.parallel.sweep import default_chunk_payload
 
-        chunk_payload = default_chunk_payload(plan.min_overlap)
+        chunk_payload = default_chunk_payload(plan, ndm=ndm)
     payload = min(chunk_payload, T)
     if payload <= plan.min_overlap:
         payload = min(T, 2 * plan.min_overlap + 1)

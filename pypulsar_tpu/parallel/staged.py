@@ -479,10 +479,13 @@ def _run_step(src, dms, factor: int, nsub: int, group_size: int,
         return None
     from pypulsar_tpu.parallel.sweep import (
         choose_group_size,
+        note_chunk_plan,
         padded_group_count,
+        plan_chunk,
+        planned_payload,
     )
 
-    with telemetry.span("sweep.plan", n_trials=len(dms)):
+    with telemetry.span("sweep.plan", n_trials=len(dms)) as plan_span:
         if group_size <= 0:
             group_size = choose_group_size(dms, src.frequencies, dt_eff,
                                            nsub)
@@ -491,21 +494,23 @@ def _run_step(src, dms, factor: int, nsub: int, group_size: int,
         plan = make_sweep_plan(dms, src.frequencies, dt_eff, nsub=nsub,
                                group_size=group_size, widths=widths,
                                pad_groups_to=pad_groups_to)
-    # default payload is BOUNDED (round 5): the previous whole-file
-    # default made a --chunk-less CLI sweep of an hour-scale file try to
-    # build one 2^26-sample chunk (a ~275 GB device buffer) — small data
-    # still runs single-chunk via the min(). tuned=False: the DETECTION
-    # sweep's chunk is part of its results (per-chunk stats, one event
-    # per chunk), so the auto-tuner's overlay must not reach it — only
-    # env/--chunk (explicit, fingerprinted operator choices) move it
-    if chunk_payload is None:
-        from pypulsar_tpu.parallel.sweep import default_chunk_payload
-
-        chunk_payload = default_chunk_payload(plan.min_overlap,
-                                              tuned=False)
-    payload = min(chunk_payload, n_ds)
-    if payload <= plan.min_overlap:
-        payload = min(n_ds, 2 * plan.min_overlap + 1)
+        # default payload is BOUNDED (round 5: the previous whole-file
+        # default made a --chunk-less CLI sweep of an hour-scale file
+        # try to build one 2^26-sample chunk) and PLANNED from the
+        # channel count and the device's memory (plan/lengths.py) —
+        # small data still runs single-chunk via the min(). tuned=False:
+        # the DETECTION sweep's chunk is part of its results (per-chunk
+        # stats, one event per chunk), so the auto-tuner's overlay must
+        # not reach it — only env/--chunk (explicit, fingerprinted
+        # operator choices) move it
+        planned = None
+        if chunk_payload is None:
+            planned = plan_chunk(plan, tuned=False, ndm=ndm)
+            chunk_payload = planned_payload(plan, planned)
+        payload = min(chunk_payload, n_ds)
+        if payload <= plan.min_overlap:
+            payload = min(n_ds, 2 * plan.min_overlap + 1)
+        note_chunk_plan(plan_span, plan, payload, planned)
     if verbose:
         print(f"# {label}downsamp={factor} dt={dt_eff:.3e}s "
               f"DMs {dms[0]:.2f}..{dms[-1]:.2f} "
@@ -685,14 +690,17 @@ def _source_probe(src) -> bytes:
         return b""
 
 
-def _default_fft_len() -> int:
-    # the DETECTION sweep's effective default (env > 2^18, overlays
-    # excluded — see chunk_fft_len): re-setting the env knob must
+def _default_fft_len(nchan: int, nsub: int, trials: int) -> int:
+    # the DETECTION sweep's effective default for this geometry (env >
+    # the length planned from the channel count and the device's memory,
+    # overlays excluded — see chunk_fft_len; its growth for the overlap
+    # follows from the DMs and the band, which the fingerprint holds):
+    # re-setting the env knob, or a device of another memory size, must
     # invalidate default-using checkpoint markers, while auto-tuning
     # (which never reaches the detector) must not
-    from pypulsar_tpu.parallel.sweep import chunk_fft_len
+    from pypulsar_tpu.parallel.sweep import planned_lengths
 
-    return chunk_fft_len(tuned=False)
+    return planned_lengths(nchan, nsub, 0, trials, tuned=False).chunk
 
 
 def _step_fingerprint(src, dms, factor, nsub, group_size, widths,
@@ -708,13 +716,15 @@ def _step_fingerprint(src, dms, factor, nsub, group_size, widths,
                  src.frequencies.tobytes(),
                  np.float64([src.tsamp]).tobytes(),
                  # None resolves through default_chunk_payload, so the
-                 # sentinel is the (negated) DEFAULT_CHUNK_FFT_LEN:
+                 # sentinel is the (negated) planned default length:
                  # retuning the library default invalidates only markers
                  # that actually USED the default (fourier chunk rounding
                  # is chunk-length-dependent); explicit --chunk runs are
                  # untouched by the constant and keep their markers
                  np.int64([src.nsamples, factor, nsub, group_size,
-                           -_default_fft_len() if chunk_payload is None
+                           -_default_fft_len(len(src.frequencies), nsub,
+                                             len(dms))
+                           if chunk_payload is None
                            else chunk_payload]).tobytes(),
                  np.int64(widths).tobytes(),
                  context.encode(), probe):
@@ -1046,7 +1056,7 @@ def dats_geometry(reader, dms, downsamp: int = 1, nsub: int = 64,
     if chunk_payload is None:
         from pypulsar_tpu.parallel.sweep import default_chunk_payload
 
-        chunk_payload = default_chunk_payload(plan.min_overlap)
+        chunk_payload = default_chunk_payload(plan)
     payload = min(chunk_payload, T)
     if payload <= plan.min_overlap:
         payload = min(T, 2 * plan.min_overlap + 1)

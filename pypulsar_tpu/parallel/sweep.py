@@ -55,6 +55,7 @@ from pypulsar_tpu.core import psrmath
 from pypulsar_tpu.ops import transfer
 from pypulsar_tpu.ops.pallas_kernels import boxcar_stats
 from pypulsar_tpu.obs import telemetry
+from pypulsar_tpu.plan import lengths
 from pypulsar_tpu.tune import knobs
 
 DEFAULT_WIDTHS = (1, 2, 4, 8, 16, 32)
@@ -256,23 +257,22 @@ def make_sweep_plan(
 # ---------------------------------------------------------------------------
 
 
-DEFAULT_CHUNK_FFT_LEN = 1 << 18
-# Round-5 chunk-length A/B on v5e (BENCHNOTES): at the bench geometry
-# (1024 chans, 1024 trials) the fourier chunk measures 0.67 G
-# trial-samples/s at n=2^17, 0.95 G at 2^18 (+41%), 0.87 G at 2^19 —
-# the FFT amortizes and the overlap fraction shrinks up to 2^18, then
-# working-set growth wins. 2^18 is the registry default for the
-# PYPULSAR_TPU_SWEEP_CHUNK knob (round 17): anywhere a chunk length is
-# not explicitly given, :func:`chunk_fft_len` resolves env > tuned
-# cache > this constant.
+DEFAULT_CHUNK_FFT_LEN = lengths.DEFAULT_CHUNK
+# The registry default of the PYPULSAR_TPU_SWEEP_CHUNK knob (round 17)
+# and the most plan/lengths.py plans for memory's sake (its comment has
+# the v5e A/B behind 2^18). Anywhere a chunk length is not explicitly
+# given, :func:`plan_chunk` resolves env > tuned cache > the length
+# planned from the channel count and the device's memory, which at 1024
+# channels on a 16 GB chip is this constant.
+
+_CHUNK_KNOB = "PYPULSAR_TPU_SWEEP_CHUNK"
 
 
 def chunk_fft_len(tuned: bool = True) -> int:
-    """The streaming chunk length: the ``PYPULSAR_TPU_SWEEP_CHUNK``
-    knob rounded up to a power of two (the FFT/doubling machinery in
-    :func:`default_chunk_payload` and the checkpoint fingerprints both
-    assume pow2), floored at 2^12 so a typo cannot degenerate the
-    stream to sample-sized dispatches.
+    """The ``PYPULSAR_TPU_SWEEP_CHUNK`` knob rounded up to a power of
+    two (the FFT/doubling machinery in :func:`plan_chunk` and the
+    checkpoint fingerprints both assume pow2), floored at 2^12 so a typo
+    cannot degenerate the stream to sample-sized dispatches.
 
     ``tuned=False`` resolves env > default only, skipping the
     auto-tuning overlays: the single-pulse DETECTION sweep's chunk is
@@ -281,20 +281,99 @@ def chunk_fft_len(tuned: bool = True) -> int:
     the tuner may move the chunk for the byte-invariant series/handoff
     paths but never for the detector. An env var or ``--chunk`` remains
     an explicit operator choice either way."""
-    n = int(knobs.env_int("PYPULSAR_TPU_SWEEP_CHUNK", overlays=tuned))
-    n = max(1 << 12, n)
+    n = int(knobs.env_int(_CHUNK_KNOB, overlays=tuned))
+    n = max(lengths.MIN_CHUNK, n)
     if n & (n - 1):
         n = 1 << n.bit_length()
     return n
 
 
-def default_chunk_payload(min_overlap: int, tuned: bool = True) -> int:
-    """Default streaming chunk payload: :func:`chunk_fft_len` grown (by
-    doubling) until the dedispersion overlap fits in half the FFT."""
+def _operator_chunk(tuned: bool) -> Optional[int]:
+    """:func:`chunk_fft_len` where the environment or a tuning overlay
+    set it; None where the declared default stands, which is the
+    planner's to bound by memory."""
     n = chunk_fft_len(tuned)
-    while min_overlap >= n // 2:
-        n <<= 1
-    return n - min_overlap
+    if knobs.env_raw(_CHUNK_KNOB) is None \
+            and n == knobs.knob(_CHUNK_KNOB).default:
+        return None
+    return n
+
+
+def planned_lengths(nchan: int, nsub: int, max_delay: int, trials: int,
+                    tuned: bool = True) -> lengths.Lengths:
+    """``plan/lengths.plan_lengths`` for this thread's device, with the
+    operator's or the tuner's chunk where one is set."""
+    return lengths.plan_lengths(nchan, nsub, max_delay, trials,
+                                lengths.device_memory(),
+                                chunk=_operator_chunk(tuned))
+
+
+def plan_chunk(plan: "SweepPlan", tuned: bool = True,
+               ndm: int = 1) -> lengths.Lengths:
+    """The streaming FFT chunk for ``plan`` on this thread's device
+    (``plan/lengths.py``): the operator's or the tuner's length where one
+    is set, else 2^18 bounded by the device's memory at the plan's
+    channel count, grown by doubling until the dedispersion overlap fits
+    in half of it. ``ndm`` devices share the trials of a dispatch.
+    Raises :class:`lengths.LengthPlanError`, naming the plan's top DM,
+    where no chunk both holds the overlap and fits."""
+    try:
+        return planned_lengths(
+            len(plan.freqs), plan.nsub, plan.min_overlap,
+            -(-plan.n_trials // max(1, int(ndm))), tuned)
+    except lengths.LengthPlanError as e:
+        raise lengths.LengthPlanError(
+            f"{e} (top DM {float(np.max(plan.dms)):.2f} at "
+            f"{plan.dt * 1e6:.2f} us over {float(np.min(plan.freqs)):.1f}"
+            f"-{float(np.max(plan.freqs)):.1f} MHz)") from None
+
+
+def planned_payload(plan: "SweepPlan", planned: lengths.Lengths) -> int:
+    """The streaming payload of ``plan`` at a planned FFT chunk. At the
+    default length, or an operator's, it is the chunk less the plan's own
+    overlap: what every 1024-channel stream has run, and its bytes depend
+    on it. A chunk cut for memory leaves room for the delay and the
+    widest default boxcar whichever pass asks, so that the detection
+    pass and the series pass of one observation (whose plans differ in
+    their widths alone) stream the same blocks, and a configuration can
+    state the one payload (as ``--chunk`` would)."""
+    if planned.cut:
+        return planned.chunk - plan.max_total_shift - max(
+            max(plan.widths), max(DEFAULT_WIDTHS))
+    return planned.chunk - plan.min_overlap
+
+
+def default_chunk_payload(plan: "SweepPlan", tuned: bool = True,
+                          ndm: int = 1) -> int:
+    """Default streaming chunk payload: :func:`planned_payload` at
+    :func:`plan_chunk`'s FFT length."""
+    return planned_payload(plan, plan_chunk(plan, tuned, ndm))
+
+
+def note_chunk_plan(span, plan: "SweepPlan", payload: int,
+                    planned: Optional[lengths.Lengths] = None) -> None:
+    """What was planned for one observation's stream, on the
+    ``sweep.plan`` span (``span`` may be None) and once as the
+    ``sweep.chunk_plan`` event, which also says which bound decided
+    (``operator``: ``--chunk`` gave the payload)."""
+    if not telemetry.is_active():
+        return
+    from pypulsar_tpu.ops.fourier_dedisperse import fourier_chunk_len
+
+    rec = dict(nchan=len(plan.freqs), overlap=int(plan.min_overlap),
+               payload=int(payload),
+               chunk=fourier_chunk_len(int(payload) + plan.min_overlap))
+    if planned is not None:
+        rec.update(need_bytes=planned.chunk_need,
+                   budget_bytes=-1 if planned.budget is None
+                   else planned.budget)
+    if span is not None:
+        span.set(**rec)
+    telemetry.event(
+        "sweep.chunk_plan", nsub=int(plan.nsub),
+        trials=int(plan.n_trials),
+        bound="operator" if planned is None else planned.chunk_bound,
+        **rec)
 
 
 def _slice_rows(rows, starts, length):
@@ -1010,13 +1089,17 @@ def sweep_stream(
                                   baseline, ckpt_context, n=len(due))
 
     need = out_len + slack2 + plan.max_shift1
+    chunk_len = _transform_len(need, engine)
+    transformed = 0  # samples the chunk programs took in, overlap and pad too
 
     def process(start, data, L):
+        nonlocal transformed
         if L < need:  # end-of-data: pad with zeros (reference pads padval=0)
             data = jnp.pad(data, ((0, 0), (0, need - L)))
         stat_len = min(chunk_payload, L)
         with telemetry.span("dispatch_sweep_chunk"):
             pending.append((start, stat_len, run_chunk(data, stat_len)))
+        transformed += chunk_len
         if telemetry.is_active():
             # one record per streamed chunk: position, payload and the
             # dispatch-pipeline depth at this moment (how far device work
@@ -1082,6 +1165,7 @@ def sweep_stream(
     if telemetry.is_active():
         telemetry.counter("sweep.trials_completed", plan.n_real_trials)
         telemetry.counter("sweep.payload_samples", int(acc.n))
+        telemetry.counter("sweep.chunk_samples", int(transformed))
         telemetry.device_snapshot(tag="sweep_stream_end")
 
     B = float(np.asarray(baseline, dtype=np.float64).sum()) if baseline is not None else 0.0
@@ -1095,6 +1179,17 @@ def sweep_stream(
     with telemetry.span("sweep.finalize", rows=int(plan.n_real_trials)):
         return finalize_sweep(plan, acc.n, acc.s, acc.ss, acc.mb, acc.ab, B,
                               chunk_mb=acc.chunk_mb, chunk_ab=acc.chunk_ab)
+
+
+def _transform_len(need: int, engine: str) -> int:
+    """Samples a chunk program takes in for ``need`` samples of block:
+    the Fourier engine's power-of-two transform length, the block itself
+    for the gather engine."""
+    if engine != "fourier":
+        return int(need)
+    from pypulsar_tpu.ops.fourier_dedisperse import fourier_chunk_len
+
+    return fourier_chunk_len(int(need))
 
 
 def finalize_sweep(plan: SweepPlan, n: int, s, ss, mb, ab,
@@ -1283,6 +1378,8 @@ def sweep_resident(spectra, dms, nsub=64, group_size=32, widths=DEFAULT_WIDTHS,
         telemetry.counter("sweep.chunks", n_chunks)
         telemetry.counter("sweep.trials_completed", plan.n_real_trials)
         telemetry.counter("sweep.payload_samples", int(n_chunks * payload))
+        telemetry.counter("sweep.chunk_samples",
+                          int(n_chunks * _transform_len(need, engine)))
         telemetry.device_snapshot(tag="sweep_resident_end")
     s = np.asarray(s, dtype=np.float64)
     ss = np.asarray(ss, dtype=np.float64)
@@ -1358,8 +1455,7 @@ def _warm_sweep(*, dms, freqs, dt, nsub=64, group_size=0,
     if chunk_payload is None:
         # the staged CLI's bounded default (tuned=False: detection
         # chunks are results, the tuner's overlay must not move them)
-        chunk_payload = default_chunk_payload(plan.min_overlap,
-                                              tuned=False)
+        chunk_payload = default_chunk_payload(plan, tuned=False)
     if n_samples:
         n_ds = int(n_samples) // factor
         chunk_payload = min(int(chunk_payload), n_ds)

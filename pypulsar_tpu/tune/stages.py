@@ -69,8 +69,7 @@ def sweep_measure(nchan: int, nsamp: int, *, ndm: int = 32,
         # dispatch, not a payload-sized one — without the clamp every
         # over-length candidate is charged phantom work it would never
         # do in production, biasing the search against large chunks
-        payload = min(psweep.default_chunk_payload(plan.min_overlap),
-                      int(nsamp))
+        payload = min(psweep.default_chunk_payload(plan), int(nsamp))
         if payload <= plan.min_overlap:
             payload = min(int(nsamp), 2 * plan.min_overlap + 1)
         # hold total work constant across candidates: every config

@@ -105,7 +105,7 @@ def _sweep_geometry(mesh=None):
     plan = make_sweep_plan(
         dms, freqs, TSAMP, nsub=NSUB, group_size=group,
         pad_groups_to=_mesh_pad_groups(len(dms), group, mesh))
-    payload = default_chunk_payload(plan.min_overlap, tuned=False)
+    payload = default_chunk_payload(plan, tuned=False)
     out_len = payload + max(plan.widths)
     need = out_len + plan.max_shift2 + plan.max_shift1
     assert need == 1 << 18  # the default chunk IS the 2^18 FFT
@@ -221,6 +221,96 @@ def test_rfifind_raw_ingest(one_chip):
     out = compiled.memory_analysis().output_size_in_bytes
     assert out == 4 * NCHAN * 16 * pts
     assert temp + args + out < V5E_HBM_BYTES / 4
+
+
+# -- the second geometry: GBNCC at 350 MHz, 4096 channels x 81.92 us -------
+# (benchmark/configs/gbncc-350.json), at the lengths plan/lengths.py gives a
+# 16 GB chip; the 1024-channel constants do not compile there at all
+
+
+def _gbncc_geometry():
+    import json
+    import os
+
+    from pypulsar_tpu.parallel.sweep import (
+        choose_group_size,
+        make_sweep_plan,
+        planned_payload,
+    )
+    from pypulsar_tpu.plan import lengths
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "gbncc-350.json")) as f:
+        cfg = json.load(f)
+    C = cfg["nchan"]
+    freqs = cfg["fch1"] - cfg["bw"] / C * np.arange(C)
+    dms = cfg["dm_lo"] + cfg["dm_step"] * np.arange(cfg["dm_trials"])
+    group = choose_group_size(dms, freqs, cfg["tsamp"], cfg["nsub"])
+    plan = make_sweep_plan(dms, freqs, cfg["tsamp"], nsub=cfg["nsub"],
+                           group_size=group)
+    pts = int(round(cfg["mask_time"] / cfg["tsamp"]))
+    planned = lengths.plan_lengths(C, cfg["nsub"], plan.min_overlap,
+                                   plan.n_trials, V5E_HBM_BYTES,
+                                   interval_samples=pts)
+    payload = planned_payload(plan, planned)
+    assert payload == cfg["chunk"]
+    return cfg, plan, planned, payload, pts
+
+
+@pytest.mark.parametrize("program", ["sweep", "series"])
+def test_gbncc_chunk_program_is_what_the_planner_counted(one_chip, on_tpu,
+                                                         program):
+    """The chunk programs at 4096 channels and the planned 2^16 chunk:
+    argument, temporaries and result fit the planner's count, which adds
+    the stream's other buffers; at the 1024-channel default of 2^18 the
+    block alone is 4.3 GB and its transform's temporaries four times
+    that."""
+    from pypulsar_tpu.parallel.sweep import (dedisperse_series_chunk,
+                                             sweep_chunk)
+    from pypulsar_tpu.plan import lengths
+
+    cfg, plan, planned, payload, _pts = _gbncc_geometry()
+    C, need = cfg["nchan"], payload + plan.min_overlap
+    assert planned.chunk == 1 << 16 and need <= planned.chunk
+    args = (_sds((C, need), jnp.float32, one_chip),
+            _sds(plan.stage1_bins.shape, jnp.int32, one_chip),
+            _sds(plan.stage2_bins.shape, jnp.int32, one_chip))
+    if program == "sweep":
+        compiled = sweep_chunk._jit.lower(
+            *args, cfg["nsub"], payload + max(plan.widths),
+            plan.max_shift2, tuple(plan.widths), payload,
+            engine="fourier").compile()
+    else:
+        compiled = dedisperse_series_chunk._jit.lower(
+            *args, cfg["nsub"], payload, plan.max_shift2,
+            "fourier").compile()
+    temp, argb = _device_bytes(compiled)
+    out = compiled.memory_analysis().output_size_in_bytes
+    # 16 bytes a sample of every channel of temporaries, 4 of argument
+    assert temp <= 1.05 * 16 * C * planned.chunk
+    assert temp + argb + out <= planned.chunk_need <= planned.budget
+    assert planned.chunk_need == lengths.chunk_bytes(
+        C, cfg["nsub"], plan.n_trials, planned.chunk)
+
+
+def test_gbncc_mask_block_is_what_the_planner_counted(one_chip):
+    """The mask stage's block at 4096 channels: 8 one-second intervals,
+    where 16 ask the compiler for 16.00 G of the chip's 15.75 G."""
+    from pypulsar_tpu.ops.fourier_dedisperse import fourier_chunk_len
+    from pypulsar_tpu.ops.rfifind import _block_stats_impl
+
+    cfg, _plan, planned, _payload, pts = _gbncc_geometry()
+    assert planned.mask_intervals == 8
+    compiled = _block_stats_impl.lower(
+        _sds((cfg["nchan"], planned.mask_intervals * pts), jnp.float32,
+             one_chip),
+        pts=pts, n_fft=fourier_chunk_len(pts)).compile()
+    temp, argb = _device_bytes(compiled)
+    packed = cfg["nchan"] * planned.mask_intervals * pts
+    # the count leaves out only the three small result tables
+    assert temp + argb + packed <= 1.001 * planned.mask_need
+    assert planned.mask_need <= planned.budget
 
 
 def _prep_args(batch, sharding, table_sh):

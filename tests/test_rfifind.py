@@ -254,6 +254,56 @@ def test_clip_stats_is_iterative():
     assert not flags[10, 0]
 
 
+def _clip_every_line_every_pass(stats, time_sigma, max_iter=10):
+    """clip_stats as it was written first: every pass judges every line
+    again. The reference for the passes that redo only the lines a new
+    flag fell on; returns (flags, passes)."""
+    import math
+
+    from pypulsar_tpu.ops.fourier_dedisperse import fourier_chunk_len
+    from pypulsar_tpu.ops.rfifind import _robust_center_scale
+
+    B = fourier_chunk_len(stats.ptsperint) // 2
+    q = 0.5 * math.erfc(4.0 / math.sqrt(2.0))
+    flags = stats.maxpow > math.log(B / q)
+    for n in range(1, max_iter + 1):
+        good, new = ~flags, flags.copy()
+        for x in (stats.mean, stats.std):
+            for axis in (0, 1):
+                med, sigma = _robust_center_scale(x, good, axis)
+                new |= np.abs(x - med) > time_sigma * sigma
+        if np.array_equal(new, flags):
+            break
+        flags = new
+    return flags, n
+
+
+@pytest.mark.parametrize("time_sigma", [10.0, 4.0, 2.5])
+@pytest.mark.parametrize("seed", range(6))
+def test_clip_redoes_only_the_lines_a_flag_fell_on(seed, time_sigma):
+    """Tables with outliers that cascade over up to ten passes, a dead
+    channel among them: the same flags, cell for cell, as judging every
+    line again in every pass."""
+    rng = np.random.RandomState(seed)
+    nint, nchan = rng.randint(5, 40), rng.randint(8, 200)
+    mean = (rng.randn(nint, nchan) * 2 + 100).astype(np.float32)
+    std = (np.abs(rng.randn(nint, nchan)) + 50).astype(np.float32)
+    for _ in range(rng.randint(1, 12)):
+        mean[rng.randint(nint), rng.randint(nchan)] += \
+            rng.choice([15, 25, 40, 400]) * rng.choice([-1, 1])
+        std[rng.randint(nint), rng.randint(nchan)] *= rng.choice([1.5, 3, 30])
+    if seed % 2:
+        mean[:, 3] = 7.0  # no scale at all along its timeline
+    maxpow = (rng.exponential(1.0, size=(nint, nchan)) * 3).astype(
+        np.float32)
+    stats = RfiStats(mean=mean, std=std, maxpow=maxpow, ptsperint=64,
+                     dtint=1.0, lofreq=300.0, df=1.0)
+    want, passes = _clip_every_line_every_pass(stats, time_sigma)
+    assert np.array_equal(clip_stats(stats, time_sigma=time_sigma), want)
+    if time_sigma == 2.5:
+        assert passes > 2  # the case the cache exists for
+
+
 # ---------------------------------------------------------------------------
 # raw ingest: a SIGPROC reader's blocks ship as the file holds them and
 # are unpacked / transposed / widened / flipped on the device

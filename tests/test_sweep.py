@@ -793,6 +793,14 @@ def test_cli_engine_validation(bad, capsys):
         assert "did you mean 'fourier'?" in err
 
 
+def _plan_with_delay(dm_hi, nchan=64, dt=64e-6):
+    """A two-trial plan whose top DM sets the overlap."""
+    from pypulsar_tpu.parallel.sweep import make_sweep_plan
+
+    freqs = 1500.0 - (300.0 / nchan) * np.arange(nchan)
+    return make_sweep_plan([0.0, dm_hi], freqs, dt, nsub=8, group_size=2)
+
+
 def test_default_chunk_payload_bounds():
     """Round-5 regression: the streaming default payload is BOUNDED
     (DEFAULT_CHUNK_FFT_LEN-derived) — the old whole-file default made a
@@ -802,8 +810,12 @@ def test_default_chunk_payload_bounds():
     from pypulsar_tpu.parallel.sweep import (DEFAULT_CHUNK_FFT_LEN,
                                              default_chunk_payload)
 
-    p = default_chunk_payload(8122)
-    assert p == DEFAULT_CHUNK_FFT_LEN - 8122
-    big = default_chunk_payload(DEFAULT_CHUNK_FFT_LEN)  # overlap >= n/2
-    assert big > 0 and (big + DEFAULT_CHUNK_FFT_LEN
-                        ) & (big + DEFAULT_CHUNK_FFT_LEN - 1) == 0
+    plan = _plan_with_delay(100.0)
+    assert 0 < plan.min_overlap < DEFAULT_CHUNK_FFT_LEN // 2
+    assert default_chunk_payload(plan) \
+        == DEFAULT_CHUNK_FFT_LEN - plan.min_overlap
+    wide = _plan_with_delay(10000.0)  # overlap >= n/2
+    assert wide.min_overlap >= DEFAULT_CHUNK_FFT_LEN // 2
+    n = default_chunk_payload(wide) + wide.min_overlap
+    assert n > DEFAULT_CHUNK_FFT_LEN and n & (n - 1) == 0
+    assert wide.min_overlap < n // 2

@@ -485,10 +485,10 @@ def test_cli_sweep_consults_cache_at_run_geometry(tmp_path, monkeypatch,
     seen = {}
     orig = psweep.default_chunk_payload
 
-    def spy(min_overlap, **kw):
-        out = orig(min_overlap, **kw)
+    def spy(plan, **kw):
+        out = orig(plan, **kw)
         if kw.get("tuned", True):  # the series/handoff (tuned) path
-            seen["payload"] = out + min_overlap  # the resolved fft len
+            seen["payload"] = out + plan.min_overlap  # the resolved fft len
         return out
 
     monkeypatch.setattr(psweep, "default_chunk_payload", spy)
