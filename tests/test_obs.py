@@ -632,6 +632,32 @@ def test_cli_rfifind_holds_every_leaf_under_its_root(toy_fil, tmp_path):
     assert all(r["attrs"]["bytes"] >= 0 for r in blocks)
 
 
+def test_rfifind_clip_span_says_how_much_work_it_did(tmp_path):
+    """``rfifind.clip`` carries the passes of its loop, the line
+    statistics it computed and the table's cells: one loud block takes a
+    second pass, which redoes that block's lines and no others."""
+    from pypulsar_tpu.cli import rfifind as cli_rfifind
+    from pypulsar_tpu.io.filterbank import write_filterbank
+
+    rng = np.random.RandomState(35)
+    nchan, nint, pts = 16, 8, 512
+    data = (rng.randn(nint * pts, nchan) * 8 + 64).clip(0, 255)
+    data[3 * pts:4 * pts, 5] += 120  # one (interval, channel) block
+    fn = str(tmp_path / "loud.fil")
+    write_filterbank(fn, dict(fch1=1500.0, foff=-4.0, nchans=nchan,
+                              tsamp=1e-3, nbits=8), data.astype(np.uint8))
+    tlm = str(tmp_path / "tlm.jsonl")
+    assert cli_rfifind.main([fn, "-o", str(tmp_path / "loud"),
+                             "-t", "0.512", "--telemetry", tlm]) == 0
+    (clip,) = [r for r in _read_jsonl(tlm) if r["type"] == "span"
+               and r["name"] == "rfifind.clip"]
+    attrs = clip["attrs"]
+    assert attrs["cells"] == nint * nchan
+    every = 2 * (nint + nchan)  # mean and std, along both axes
+    assert attrs["passes"] >= 2
+    assert every < attrs["lines"] < attrs["passes"] * every
+
+
 def test_cli_sweep_holds_every_leaf_under_its_root(toy_fil, tmp_path):
     tlm, _ = _open_by_sweep(toy_fil, tmp_path)
     paths = _span_paths(tlm)

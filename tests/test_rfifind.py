@@ -254,14 +254,67 @@ def test_clip_stats_is_iterative():
     assert not flags[10, 0]
 
 
+def _numpy_center_scale(x, good, axis):
+    """(median, sigma) of every line's good cells by NumPy's own
+    ``nanmedian`` / ``nanpercentile``, one Python call a line: what
+    ``_robust_center_scale`` was, and the definition it is held to."""
+    import warnings
+
+    masked = np.where(good, x, np.nan)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)  # all-NaN slices
+        med = np.nanmedian(masked, axis=axis, keepdims=True)
+        q75 = np.nanpercentile(masked, 75, axis=axis, keepdims=True)
+        q25 = np.nanpercentile(masked, 25, axis=axis, keepdims=True)
+    med = np.where(np.isnan(med), 0.0, med)
+    sigma = (q75 - q25) / 1.349
+    sigma = np.where(np.isnan(sigma) | (sigma <= 0), np.inf, sigma)
+    return med, sigma
+
+
+# (700, 9): NumPy's nanmedian takes another path from 600 cells a line on
+@pytest.mark.parametrize("shape", [(3, 5), (34, 1024), (700, 9), (21, 4096)],
+                         ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("share", [0.0, 0.1, 0.6, 0.97])
+@pytest.mark.parametrize("axis", [0, 1])
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_center_scale_is_numpys_bit_for_bit(dtype, axis, share, shape):
+    """One sort a table and axis gives every line the median and the
+    quartiles that NumPy's per-line calls give, to the bit and in the same
+    dtype: lines with no good cell, with one, two and three, a line of
+    equal values (sigma -> inf), ties, and any share of flags."""
+    from pypulsar_tpu.ops.rfifind import _robust_center_scale
+
+    rng = np.random.RandomState(
+        [shape[0], shape[1], axis, int(100 * share), dtype().itemsize])
+    x = (rng.randn(*shape) * 3 + 100).astype(dtype)
+    x[rng.rand(*shape) < 0.2] = 100  # ties, the middle elements among them
+    good = rng.rand(*shape) >= share
+    xl, gl = np.moveaxis(x, axis, 0), np.moveaxis(good, axis, 0)  # views
+    nlines = xl.shape[1]
+    for j in range(min(4, nlines)):  # line j keeps j good cells (or all)
+        gl[:, j] = np.arange(len(gl)) < j
+    if nlines > 4:
+        xl[:, 4] = 7.0  # no scale at all
+        gl[0, 4] = True
+    got = _robust_center_scale(x, good, axis)
+    want = _numpy_center_scale(x, good, axis)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype == dtype and g.shape == w.shape
+        assert np.array_equal(g, w)
+    med, sigma = got
+    assert med.ravel()[0] == 0 and np.isinf(sigma.ravel()[0])  # none good
+    if nlines > 4:
+        assert med.ravel()[4] == 7.0 and np.isinf(sigma.ravel()[4])
+
+
 def _clip_every_line_every_pass(stats, time_sigma, max_iter=10):
     """clip_stats as it was written first: every pass judges every line
-    again. The reference for the passes that redo only the lines a new
-    flag fell on; returns (flags, passes)."""
+    again, by NumPy's per-line calls. The reference for the passes that
+    redo only the lines a new flag fell on; returns (flags, passes)."""
     import math
 
     from pypulsar_tpu.ops.fourier_dedisperse import fourier_chunk_len
-    from pypulsar_tpu.ops.rfifind import _robust_center_scale
 
     B = fourier_chunk_len(stats.ptsperint) // 2
     q = 0.5 * math.erfc(4.0 / math.sqrt(2.0))
@@ -270,7 +323,7 @@ def _clip_every_line_every_pass(stats, time_sigma, max_iter=10):
         good, new = ~flags, flags.copy()
         for x in (stats.mean, stats.std):
             for axis in (0, 1):
-                med, sigma = _robust_center_scale(x, good, axis)
+                med, sigma = _numpy_center_scale(x, good, axis)
                 new |= np.abs(x - med) > time_sigma * sigma
         if np.array_equal(new, flags):
             break
