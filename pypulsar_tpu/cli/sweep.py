@@ -27,6 +27,9 @@ from pypulsar_tpu.io.opener import open_reader
 from pypulsar_tpu.obs import telemetry
 from pypulsar_tpu.tune import knobs
 
+# ``{outbase}`` + this exists while a flat run may have tmps staged
+RUN_MARKER = ".sweep.inprogress"
+
 
 def _engine_arg(value: str) -> str:
     """argparse validator for ``--engine``: checked against the ENGINES
@@ -770,10 +773,10 @@ def _main_parsed(args, ap):
                 _journal_fingerprint(args, dms, widths, outbase),
                 tool="sweep-accel")
             journal_done = journal.completed()
-        with telemetry.span("sweep.plan", n_trials=len(dms)):
-            # four os.path.exists a trial: 0.65 s a batch at 1024 trials
-            # on the benchmark's host (PERF.md, PR 24)
-            _remove_stale_output_tmps(outbase, dms, args)
+        with telemetry.span("sweep.plan", n_trials=len(dms)) as sp:
+            listed, removed = _clear_killed_run(outbase, dms, args)
+            if sp is not None:
+                sp.set(listed=listed, removed=removed)
         staged = None
         if not args.accel_only:
             if journal is not None and "sweep:cands" in journal_done:
@@ -845,6 +848,8 @@ def _main_parsed(args, ap):
             _write_dats_auto(outbase, reader, dms, args, rfimask=rfimask)
         if journal is not None:
             journal.close()
+        # every writer has renamed its tmp into place
+        os.remove(outbase + RUN_MARKER)
 
     if staged is not None:  # the DDplan path emits at the end
         _emit_sweep_artifacts(staged, outbase, args, None)
@@ -878,21 +883,38 @@ def _journal_fingerprint(args, dms, widths, outbase) -> str:
     return h.hexdigest()
 
 
+def _clear_killed_run(outbase, dms, args):
+    """Remove a killed run's tmp debris and mark this run in progress.
+    Debris outlives only a killed run, and a killed run leaves its marker
+    behind, so a clean run pays one stat whatever the directory holds:
+    four os.path.exists a trial took 0.64-0.84 s a batch at 1024 trials
+    on a v5e host (PERF.md §5). Returns (names tested, files removed)."""
+    marker = outbase + RUN_MARKER
+    if os.path.exists(marker):
+        return _remove_stale_output_tmps(outbase, dms, args)
+    open(marker, "w").close()
+    return 0, 0
+
+
 def _remove_stale_output_tmps(outbase, dms, args):
     """Remove tmp debris a killed run's atomic writers can leave — the
     EXACT derived names only (never a glob: a prefix pattern could match
     unrelated user files): per-DM .dat/.inf staging tmps plus the accel
-    handoff's .cand/.txtcand tmps."""
+    handoff's .cand/.txtcand tmps. Returns (names tested, files removed)."""
     from pypulsar_tpu.parallel.accelpipe import accel_out_names
 
+    listed = removed = 0
     for dm in dms:
         base = f"{outbase}_DM{dm:.2f}"
         stale = [base + ".dat.tmp", base + ".inf.tmp"]
         candfn, txtfn = accel_out_names(base, args.accel_zmax, 0.0)
         stale += [candfn + ".tmp", txtfn + ".tmp"]
+        listed += len(stale)
         for fn in stale:
             if os.path.exists(fn):
                 os.remove(fn)
+                removed += 1
+    return listed, removed
 
 
 def _emit_sweep_artifacts(staged, outbase, args, journal):

@@ -142,6 +142,7 @@ def test_fleet_end_to_end_byte_identical_to_serial_chain(fleet):
     tables and archives are byte-identical to running the serial chain
     per observation, and the SNR summaries carry the same science."""
     from pypulsar_tpu.cli import survey as cli_survey
+    from pypulsar_tpu.cli import sweep as cli_sweep
 
     outdir = str(fleet["root"] / "orch")
     tlmdir = str(fleet["root"] / "tlm")
@@ -164,6 +165,15 @@ def test_fleet_end_to_end_byte_identical_to_serial_chain(fleet):
 
     obs_sum = summarize(load_records(os.path.join(tlmdir, "psr0.jsonl")))
     assert "survey.stage.sweep" in obs_sum.stages
+    # no sweep was killed, so neither tests a tmp name, however many
+    # artifacts the shared output directory holds, and none leaves its
+    # in-progress marker behind
+    cleanups = [
+        r["attrs"]
+        for r in load_records(os.path.join(tlmdir, "fleet.jsonl"))
+        if r.get("name") == "sweep.plan" and "listed" in r.get("attrs", {})]
+    assert [(c["listed"], c["removed"]) for c in cleanups] == [(0, 0)] * 2
+    assert not glob.glob(os.path.join(outdir, "*" + cli_sweep.RUN_MARKER))
     fleet_sum = summarize(load_records(os.path.join(tlmdir,
                                                     "fleet.jsonl")))
     assert fleet_sum.counters.get("survey.stages_run") == 10
